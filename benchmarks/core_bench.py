@@ -55,6 +55,7 @@ from repro.core.tiling import (
 )
 from repro.graphs.generators import erdos_renyi
 from repro.kernels import tc_spmv
+from repro.kernels.ops import resolve_interpret
 
 OUT_PATH = os.environ.get("BENCH_CORE_OUT", "BENCH_core.json")
 STORAGES = ("int8", "bitpack")
@@ -166,7 +167,7 @@ def _bench_pallas_kernel(n: int, T: int) -> list:
             n_tiles=t.n_tiles, us_per_call=round(s * 1e6, 1),
             tile_payload_bytes=t.tile_payload_bytes(),
             gb_per_s=_gb_per_s(t.tile_payload_bytes(), s * 1e6),
-            interpret=jax.default_backend() != "tpu",
+            interpret=resolve_interpret(),
         ))
         emit(f"core.kernel_spmv.{storage}.T{T}", s * 1e6, f"n_tiles={t.n_tiles}")
     return rows

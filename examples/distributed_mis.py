@@ -13,10 +13,6 @@ if "xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", "")
 import jax
 import numpy as np
 
-from repro.dist.compat import install as _install_jax_compat
-
-_install_jax_compat()   # modern sharding API on 0.4.x jax too
-
 from repro.api import PlanCache, Solver, SolveOptions
 from repro.core import is_valid_mis
 from repro.graphs.generators import GRAPH_SUITE

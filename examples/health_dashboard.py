@@ -11,8 +11,9 @@ what an operator would scrape:
                      the service fills in `step()`
   2. drift trend     per-epoch touched tiles, dirty fraction and the
                      tile-locality-decay gauge recorded by `patch_plan`
-  3. roofline        predicted vs measured per-round cost (model error %) —
-                     large on CPU by design; the TREND is the signal
+  3. roofline        predicted vs measured per-round cost (model error %),
+                     recorded only on a chip with a peak entry; the TREND
+                     is the signal
   4. promtext        the full merged snapshot in Prometheus text format,
                      exactly what `--metrics-path` exports for a textfile
                      collector
@@ -95,10 +96,14 @@ def main() -> None:
 
     # -- 4. roofline attribution (predicted vs measured per-round cost) ----
     print("\n== roofline attribution (last solve) ==")
-    print(f"  predicted={snap.get('perf.roofline_predicted_us', 0.0):.1f}us "
-          f"measured={snap.get('perf.roofline_measured_us', 0.0):.1f}us "
-          f"error={snap.get('perf.roofline_error_pct', 0.0):+.1f}%  "
-          f"(CPU error is large by design — trend, not level)")
+    if "perf.roofline_predicted_us" not in snap:
+        print("  not recorded: this device has no peak entry "
+              "(repro.perf.roofline.DEVICE_PEAKS)")
+    else:
+        print(f"  predicted={snap['perf.roofline_predicted_us']:.1f}us "
+              f"measured={snap['perf.roofline_measured_us']:.1f}us "
+              f"error={snap['perf.roofline_error_pct']:+.1f}%  "
+              f"(trend, not level)")
 
     # -- 5. the scrape surface ---------------------------------------------
     print("\n== promtext snapshot (what --metrics-path exports) ==")

@@ -54,6 +54,7 @@ from repro.graphs.graph import Graph
 from repro.obs.metrics import MetricsRegistry
 from repro.obs.rounds import RoundTrace
 from repro.obs.trace import Trace, trace_span
+from repro.perf.roofline import device_peaks, round_cost_attribution
 
 GraphLike = Union[Graph, Plan]
 
@@ -515,7 +516,8 @@ class Solver:
         measured per-round cost from the telemetry dispatch-mix columns.
 
         Telemetry-on only (`rt is None` → no-op, so the telemetry-off path
-        stays bit-identical), eager, and never raises into the solve path.
+        stays bit-identical), eager, and recorded only on a chip with a
+        `DEVICE_PEAKS` entry: a CPU run has no roofline to score against.
         Gauges, not histograms: the operator question is "what is the
         model error NOW / is it trending" — `perf.roofline_error_pct`
         drifting under churn means the dispatch mix no longer matches what
@@ -523,33 +525,30 @@ class Solver:
         """
         if rt is None or not rt.rounds:
             return
-        try:
-            from repro.perf.roofline import round_cost_attribution
-
-            dense = sum(rt.tiles_dense) / rt.rounds if rt.tiles_dense else 0.0
-            if dense <= 0.0 and rt.tiles_total:
-                # engines that don't fill COL_TILES_DENSE (segment): every
-                # non-skipped stored tile went through the one dense path
-                dense = max(
-                    rt.tiles_total - sum(rt.tiles_skipped) / rt.rounds, 0.0
-                )
-            p = tiled.partition
-            # the sentinel-padded COO tail is the per-round sparse stream
-            # length — padding entries are processed too, so they cost
-            sparse = float(p.sp_rows.shape[0]) if p is not None else 0.0
-            att = round_cost_attribution(
-                dense_tiles=dense, sparse_edges=sparse,
-                tile_size=tiled.tile_size, storage=tiled.storage,
-                measured_s=(solve_ms / 1e3) / rt.rounds,
+        peaks = device_peaks()
+        if peaks is None:
+            return
+        dense = sum(rt.tiles_dense) / rt.rounds if rt.tiles_dense else 0.0
+        if dense <= 0.0 and rt.tiles_total:
+            # engines that don't fill COL_TILES_DENSE (segment): every
+            # non-skipped stored tile went through the one dense path
+            dense = max(
+                rt.tiles_total - sum(rt.tiles_skipped) / rt.rounds, 0.0
             )
-            self.metrics.gauge("perf.roofline_predicted_us").set(
-                att["predicted_us"])
-            self.metrics.gauge("perf.roofline_measured_us").set(
-                att["measured_us"])
-            self.metrics.gauge("perf.roofline_error_pct").set(
-                att["error_pct"])
-        except Exception:  # noqa: BLE001
-            pass
+        p = tiled.partition
+        # the sentinel-padded COO tail is the per-round sparse stream
+        # length — padding entries are processed too, so they cost
+        sparse = float(p.sp_rows.shape[0]) if p is not None else 0.0
+        att = round_cost_attribution(
+            dense_tiles=dense, sparse_edges=sparse,
+            tile_size=tiled.tile_size, storage=tiled.storage,
+            measured_s=(solve_ms / 1e3) / rt.rounds, peaks=peaks,
+        )
+        self.metrics.gauge("perf.roofline_predicted_us").set(
+            att["predicted_us"])
+        self.metrics.gauge("perf.roofline_measured_us").set(
+            att["measured_us"])
+        self.metrics.gauge("perf.roofline_error_pct").set(att["error_pct"])
 
     def _solve_local(
         self, plan: Plan, key: jax.Array, trace: Optional[Trace] = None
@@ -640,7 +639,6 @@ class Solver:
         from repro.core.distributed import (
             DistConfig, build_distributed_mis, shard_tiled,
         )
-        from repro.dist import compat
 
         n_dev = jax.device_count()
         run = self._dist_runs.get(plan.key)
@@ -648,9 +646,9 @@ class Solver:
         if run is None:
             self.metrics.counter("solver.compiles").inc()
             with trace_span(trace, "solver.compile", placement="sharded"):
-                axis_type = getattr(jax.sharding, "AxisType", compat._AxisType)
-                mesh = compat.make_mesh(
-                    (n_dev,), ("shard",), axis_types=(axis_type.Auto,)
+                mesh = jax.make_mesh(
+                    (n_dev,), ("shard",),
+                    axis_types=(jax.sharding.AxisType.Auto,),
                 )
                 # documented dense-only fallback (DESIGN.md §16): the
                 # shard_map loop has no sparse-tail seam, so the partition

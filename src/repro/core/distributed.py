@@ -235,10 +235,8 @@ def make_mis_step_fn(
         )
         return in_mis_l, rounds
 
-    from repro.dist.compat import shard_map
-
     shard_spec = P(axis)
-    return shard_map(
+    return jax.shard_map(
         body_fn,
         mesh=mesh,
         in_specs=(shard_spec, shard_spec, shard_spec, P(), P()),

@@ -132,9 +132,11 @@ def _setup(
     )
     bits = None
     if frontier == "bitwise":
-        # plane stacks only where the plane-scan kernel actually runs (real
-        # TPU); everywhere else the clz formulation needs no planes.
-        planes = engine.plane_kernel_nbr_max and jax.default_backend() == "tpu"
+        # plane stacks only where the plane-scan kernel compiles for real
+        # (TPU); in interpret mode the clz formulation needs no planes.
+        from repro.kernels.ops import resolve_interpret
+
+        planes = engine.plane_kernel_nbr_max and not resolve_interpret()
         # hybrid runs walk only the compacted dense partition with the tile
         # machinery — build the sorted-tile / word structures over it, not
         # the full list (the sparse tail never touches them, DESIGN.md §16)

@@ -601,6 +601,26 @@ def pack_frontier_bits(bits: jnp.ndarray, tile_size: int) -> jnp.ndarray:
     return jnp.sum(vals, axis=-1, dtype=jnp.uint32)
 
 
+def pack_frontier_column(bits: jnp.ndarray, tile_size: int) -> jnp.ndarray:
+    """(T, 1) truthy column -> (1, W) uint32 word row, the layout of
+    `pack_frontier_bits`.
+
+    The kernel-body form: inside a Pallas block vertex v of a tile sits on
+    sublane v, while its word sits on a lane.  Packing is a broadcast
+    against a (T, W) weight matrix and an int32 sum down the sublanes, so
+    the TPU compiler never sees the (T, 1) -> (1, T) transpose it refuses.
+    Disjoint bit positions keep the wrapping int32 sum exact."""
+    T = int(tile_size)
+    W = packed_words(T)
+    v = jax.lax.broadcasted_iota(jnp.int32, (T, W), 0)
+    w = jax.lax.broadcasted_iota(jnp.int32, (T, W), 1)
+    weight = jnp.where((v >> 5) == w, 1 << (v & 31), 0)
+    vals = jnp.where(bits != 0, weight, 0)
+    return jax.lax.bitcast_convert_type(
+        jnp.sum(vals, axis=0, keepdims=True), jnp.uint32
+    )
+
+
 def unpack_frontier_bits(words: jnp.ndarray, tile_size: int) -> jnp.ndarray:
     """(..., W) uint32 -> (..., T) bool — inverse of `pack_frontier_bits`.
 

@@ -13,9 +13,12 @@ distribution shape and |E|/|V| — at any scale:
   G7 soc-LiveJournal1  social           -> preferential_attachment (m≈7)
   G8 kron_g500-logn21  Kronecker        -> rmat (Graph500 a,b,c,d)
 
-Wall-clock benchmarks run the *reduced* scale (CPU-tractable); the dry-run /
-roofline path uses the *full* |V|,|E| through shape specs only (no
-allocation).  Generators are numpy, deterministic in ``seed``.
+Wall-clock benchmarks run the *reduced* scale (CPU-tractable).  The
+vectorised generators (G2 `grid2d`, G3 `delaunay_like`) also build the
+*full* published |V| as host arrays — the chip runs solve them; the others
+loop in Python per vertex (or go through networkx), so their full scale
+exists only as dry-run shape specs.  Generators are numpy, deterministic in
+``seed``.
 """
 from __future__ import annotations
 
@@ -178,9 +181,19 @@ class GraphSpec:
     e_full: int            # |E| at paper scale (undirected count)
     n_reduced: int         # CPU-tractable scale for wall-clock benches
     make: Callable[[int, int], Graph]  # (n, seed) -> Graph at requested n
+    vectorised: bool = False   # builds n_full in seconds (no per-vertex loop)
 
     def reduced(self, seed: int = 0) -> Graph:
         return self.make(self.n_reduced, seed)
+
+    def full(self, seed: int = 0) -> Graph:
+        """The published |V| as host arrays — vectorised generators only."""
+        if not self.vectorised:
+            raise ValueError(
+                f"{self.paper_id} ({self.name}) loops in Python per vertex; "
+                f"its full scale is a dry-run spec, not arrays"
+            )
+        return self.make(self.n_full, seed)
 
     @property
     def e_over_v(self) -> float:
@@ -188,8 +201,11 @@ class GraphSpec:
 
 
 def _grid_maker(n: int, seed: int) -> Graph:
-    side = int(np.sqrt(n))
-    return grid2d(side, side, seed=seed)
+    """The smallest near-square lattice with at least `n` vertices whose
+    row count is ⌊√n⌋: 200×200 at n = 40,000, 1044×1045 at roadNet-PA's
+    published 1,090,920."""
+    rows = int(np.sqrt(n))
+    return grid2d(rows, -(-n // rows), seed=seed)
 
 
 GRAPH_SUITE: Dict[str, GraphSpec] = {
@@ -197,9 +213,10 @@ GRAPH_SUITE: Dict[str, GraphSpec] = {
     for s in [
         GraphSpec("amazon0302", "G1", 262_111, 1_234_877, 20_000,
                   lambda n, seed: preferential_attachment(n, m=4, seed=seed)),
-        GraphSpec("roadNet-PA", "G2", 1_090_920, 1_541_898, 40_000, _grid_maker),
+        GraphSpec("roadNet-PA", "G2", 1_090_920, 1_541_898, 40_000, _grid_maker,
+                  vectorised=True),
         GraphSpec("delaunay_n19", "G3", 524_288, 1_572_823, 32_768,
-                  lambda n, seed: delaunay_like(n, seed=seed)),
+                  lambda n, seed: delaunay_like(n, seed=seed), vectorised=True),
         GraphSpec("wiki-Talk", "G4", 2_394_385, 4_659_565, 30_000,
                   lambda n, seed: powerlaw(n, avg_deg=4.0, seed=seed)),
         GraphSpec("web-Google", "G5", 916_428, 4_322_051, 20_000,
@@ -215,9 +232,12 @@ GRAPH_SUITE: Dict[str, GraphSpec] = {
 
 
 def generate(paper_id: str, *, scale: str = "reduced", seed: int = 0) -> Graph:
-    """Materialise one of the paper's graphs. ``scale`` is 'reduced' only —
-    full scale exists as shape specs for the dry-run, never as host arrays."""
+    """Materialise one of the paper's graphs.  ``scale`` is 'reduced' or
+    'full'; 'full' builds the published |V| for the vectorised generators
+    (G2, G3) and refuses the ones that loop in Python."""
     spec = GRAPH_SUITE[paper_id]
-    if scale != "reduced":
-        raise ValueError("full-scale graphs are dry-run specs, not arrays")
-    return spec.reduced(seed)
+    if scale == "reduced":
+        return spec.reduced(seed)
+    if scale == "full":
+        return spec.full(seed)
+    raise ValueError(f"unknown scale {scale!r}; valid: reduced | full")

@@ -39,7 +39,7 @@ def embedding_bag_pallas(
     indices: jnp.ndarray,   # (B, K) int32
     weights: jnp.ndarray,   # (B, K) float
     *,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     """Σ_k weights[b,k] · table[indices[b,k]]  ->  (B, D) float32."""
     B, K = indices.shape

@@ -1,9 +1,12 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-Dispatch policy: on CPU (this container) kernels run `interpret=True`, which
-executes the kernel body in Python per grid step — bit-identical semantics to
-the TPU lowering, minus performance.  On TPU the same call sites compile the
-real Mosaic kernels.  `interpret=None` means "auto by backend".
+Dispatch policy, decided here and nowhere else (`resolve_interpret`): off
+TPU the kernels run `interpret=True`, which executes the kernel body in
+Python per grid step — bit-identical semantics to the TPU lowering, minus
+performance.  On TPU the same call sites compile the real Mosaic kernels.
+`interpret=None` means "auto by backend".  The raw `*_pallas` wrappers take
+`interpret` as a required keyword, so no caller interprets on the chip by
+default.
 
 Storage axis (DESIGN.md §11): `tiled.tiles` is passed to the kernels AS
 STORED — dense int8 or bit-packed uint32 — and never densified here; a
@@ -27,7 +30,8 @@ from repro.kernels.embedding_bag import embedding_bag_pallas
 _NEG = np.int32(-(1 << 30))  # numpy scalar: safe to create at import time under a trace
 
 
-def _auto_interpret(interpret: Optional[bool]) -> bool:
+def resolve_interpret(interpret: Optional[bool] = None) -> bool:
+    """The one interpret-mode decision: explicit wins, None = off TPU."""
     if interpret is None:
         return jax.default_backend() != "tpu"
     return bool(interpret)
@@ -49,7 +53,7 @@ def tc_spmv(
         rhs,
         tiled.n_block_rows,
         col_flags=col_flags,
-        interpret=_auto_interpret(interpret),
+        interpret=resolve_interpret(interpret),
         skip_dma=skip_dma,
     )
 
@@ -69,7 +73,7 @@ def tc_neighbor_max(
         tiled.tile_cols,
         pm,
         tiled.n_block_rows,
-        interpret=_auto_interpret(interpret),
+        interpret=resolve_interpret(interpret),
     )
 
 
@@ -84,7 +88,7 @@ def embedding_bag(
     if weights is None:
         weights = jnp.ones(indices.shape, dtype=jnp.float32)
     return embedding_bag_pallas(
-        table, indices, weights, interpret=_auto_interpret(interpret)
+        table, indices, weights, interpret=resolve_interpret(interpret)
     )
 
 
@@ -112,7 +116,7 @@ def tc_spmv_fused(
         tiled.tiles, tiled.tile_rows, tiled.tile_cols, rhs,
         cand.astype(jnp.int8), alive.astype(jnp.int8), tiled.n_block_rows,
         col_flags=col_flags,
-        interpret=_auto_interpret(interpret),
+        interpret=resolve_interpret(interpret),
         skip_dma=skip_dma,
     )
     # static per-graph coverage: which block-rows own at least one tile
@@ -167,7 +171,7 @@ def tc_spmv_bits(
         _tiles_words(tiled, tiles_words),
         tiled.tile_rows, tiled.tile_cols, rhs_words, tiled.n_block_rows,
         col_flags=col_flags,
-        interpret=_auto_interpret(interpret),
+        interpret=resolve_interpret(interpret),
         skip_dma=skip_dma,
     )
     # uncovered block-rows have no neighbours ⇒ no hits (word 0)
@@ -195,7 +199,7 @@ def tc_neighbor_max_bits(
         tiled.tile_rows, tiled.tile_cols, planes, mask_words,
         tiled.n_block_rows,
         signed=signed,
-        interpret=_auto_interpret(interpret),
+        interpret=resolve_interpret(interpret),
     )
     covered = jnp.repeat(_covered_block_rows(tiled), tiled.tile_size)
     return jnp.where(covered, out, jnp.iinfo(jnp.int32).min)
@@ -219,7 +223,7 @@ def tc_spmv_fused_bits(
         tiled.tile_rows, tiled.tile_cols, cand_words, alive_words,
         tiled.n_block_rows,
         col_flags=col_flags,
-        interpret=_auto_interpret(interpret),
+        interpret=resolve_interpret(interpret),
         skip_dma=skip_dma,
     )
     covered = _covered_block_rows(tiled)[:, None]

@@ -33,34 +33,43 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.tiling import unpack_tile_mask
+from repro.kernels.tile_grid import TILES_PER_LAUNCH, tile_pallas_call
 
 _NEG = -(1 << 30)  # plain int: jnp scalars would be captured as kernel consts
 
 
-def _nbr_max_kernel(rows_ref, cols_ref, tiles_ref, pm_ref, out_ref,
-                    *, packed: bool, tile_size: int):
+def _nbr_max_kernel(rows_ref, cols_ref, flags_ref, tiles_ref, pm_ref, *refs,
+                    packed: bool, tile_size: int, resume: bool):
+    out_ref = refs[-1]
     i = pl.program_id(0)
-    row = rows_ref[i]
-    prev = rows_ref[jnp.maximum(i - 1, 0)]
+    prev, row = rows_ref[i], rows_ref[i + 1]
 
-    @pl.when((i == 0) | (prev != row))
+    @pl.when(prev != row)
     def _init():
         out_ref[...] = jnp.full_like(out_ref, _NEG)
+
+    if resume:                                # row cut by the launch edge
+        @pl.when((i == 0) & (prev == row))
+        def _resume():
+            out_ref[...] = refs[0][...]
 
     tile = tiles_ref[0]                       # (T, T): row v, col u
     if packed:                                # in-VMEM bit→bool, post-DMA
         mask = unpack_tile_mask(tile, tile_size)
     else:
-        mask = tile != 0
-    pm = pm_ref[...]                          # (1, T) masked priorities
+        mask = tile.astype(jnp.int32) != 0
+    pm = pm_ref[0]                            # (1, T) masked priorities
     vals = jnp.where(mask, pm, _NEG)          # broadcast over rows
-    out_ref[...] = jnp.maximum(out_ref[...], vals.max(axis=1, keepdims=True).T)
+    # the row max lands as a (T, 1) column — the output block's layout, so
+    # no (T, 1) -> (1, T) transpose reaches the TPU compiler
+    out_ref[...] = jnp.maximum(out_ref[...], vals.max(axis=1, keepdims=True))
 
 
-@functools.partial(jax.jit, static_argnames=("n_block_rows", "interpret"))
+@functools.partial(
+    jax.jit, static_argnames=("n_block_rows", "interpret", "tiles_per_launch")
+)
 def tc_neighbor_max_pallas(
     tiles: jnp.ndarray,       # (nt, T, T) int8 | (nt, T, W) uint32, row-major
     tile_rows: jnp.ndarray,   # (nt,) int32, non-decreasing
@@ -68,33 +77,31 @@ def tc_neighbor_max_pallas(
     pm: jnp.ndarray,          # (nbc*T,) int32 — priorities, _NEG where masked
     n_block_rows: int,
     *,
-    interpret: bool = True,
+    interpret: bool,
+    tiles_per_launch: int = TILES_PER_LAUNCH,
 ) -> jnp.ndarray:
-    """Max_Np over BSR tiles. Returns (n_block_rows*T,) int32 (_NEG = none)."""
-    nt, T, tw = tiles.shape
-    packed = tiles.dtype == jnp.uint32
-    pm2 = pm.reshape(-1, T)
+    """Max_Np over BSR tiles. Returns (n_block_rows*T,) int32 (_NEG = none).
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(nt,),
-        in_specs=[
-            pl.BlockSpec((1, T, tw), lambda i, rows, cols: (i, 0, 0)),
-            pl.BlockSpec((1, T), lambda i, rows, cols: (cols[i], 0)),
-        ],
-        out_specs=pl.BlockSpec((1, T), lambda i, rows, cols: (rows[i], 0)),
+    Priorities enter as (nbc, 1, T) lane rows and the max leaves as a
+    (n_block_rows·T, 1) column: both blocks end in dims the TPU compiler
+    accepts, (1, T) and (T, 1) equal to the array's own."""
+    T = tiles.shape[1]
+    (out,) = tile_pallas_call(
+        functools.partial(
+            _nbr_max_kernel, packed=tiles.dtype == jnp.uint32, tile_size=T
+        ),
+        tiles=tiles, tile_rows=tile_rows, tile_cols=tile_cols, col_flags=None,
+        inputs=[(pm.reshape(-1, 1, T), (1, 1, T), "col")],
+        outputs=[(jax.ShapeDtypeStruct((n_block_rows * T, 1), jnp.int32),
+                  (T, 1))],
+        carried=[0], interpret=interpret, tiles_per_launch=tiles_per_launch,
     )
-    out = pl.pallas_call(
-        functools.partial(_nbr_max_kernel, packed=packed, tile_size=T),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_block_rows, T), jnp.int32),
-        interpret=interpret,
-    )(tile_rows, tile_cols, tiles, pm2)
     return out.reshape(n_block_rows * T)
 
 
-def _nbr_max_bits_kernel(rows_ref, cols_ref, tiles_ref, planes_ref, mask_ref,
-                         out_ref, *, n_bits: int, signed: bool, tile_size: int):
+def _nbr_max_bits_kernel(rows_ref, cols_ref, flags_ref, tiles_ref, planes_ref,
+                         mask_ref, *refs, n_bits: int, signed: bool,
+                         tile_size: int, resume: bool):
     """Priority-plane scan over one packed tile (DESIGN.md §13).
 
     `cur` tracks the surviving neighbour set per tile row; plane b (static
@@ -102,34 +109,48 @@ def _nbr_max_bits_kernel(rows_ref, cols_ref, tiles_ref, planes_ref, mask_ref,
     A nonempty intersection fixes bit b of the max and narrows `cur`; empty
     leaves both.  After all planes `maxv` IS the masked max — never any
     priority value materialised per column, only word AND/OR."""
+    out_ref = refs[-1]
     i = pl.program_id(0)
-    row = rows_ref[i]
-    prev = rows_ref[jnp.maximum(i - 1, 0)]
+    prev, row = rows_ref[i], rows_ref[i + 1]
 
-    @pl.when((i == 0) | (prev != row))
+    @pl.when(prev != row)
     def _init():
         out_ref[...] = jnp.full_like(out_ref, _NEG)
 
+    if resume:                                # row cut by the launch edge
+        @pl.when((i == 0) & (prev == row))
+        def _resume():
+            out_ref[...] = refs[0][...]
+
+    def any_word(x):                          # (T, W) -> (T, 1) bool
+        return jnp.max((x != 0).astype(jnp.int32), axis=1, keepdims=True) > 0
+
     a = tiles_ref[0]                          # (T, W) uint32: row v's words
-    cur = a & mask_ref[...]                   # (T, W), mask (1, W) broadcast
-    nonempty = jnp.any(cur != 0, axis=1)      # (T,)
-    maxv = jnp.zeros((tile_size,), jnp.uint32)
+    planes = planes_ref[0]                    # (n_bits, W): this column's planes
+    cur = a & mask_ref[0]                     # (T, W), mask (1, W) broadcast
+    nonempty = any_word(cur)                  # (T, 1)
+    maxv = jnp.zeros((tile_size, 1), jnp.int32)
     for b in range(n_bits - 1, -1, -1):
-        inter = cur & planes_ref[b]           # (T, W) ∩ plane b's (1, W)
-        has = jnp.any(inter != 0, axis=1)
-        maxv = maxv | (has.astype(jnp.uint32) << b)
-        cur = jnp.where(has[:, None], inter, cur)
+        inter = cur & planes[b:b + 1, :]      # (T, W) ∩ plane b's (1, W)
+        has = any_word(inter)
+        # bit b of the max; b = 31 lands in the sign bit, which is what the
+        # bitcast of the biased uint32 max needs
+        maxv = maxv | (has.astype(jnp.int32) << b)
+        cur = jnp.where(has, inter, cur)
     if signed:
         # planes were sign-biased (int32 ^ 0x80000000) so bit-serial max is
         # order-correct for negative priorities; un-bias on the way out.
-        vals = jax.lax.bitcast_convert_type(maxv ^ jnp.uint32(0x80000000), jnp.int32)
+        vals = maxv ^ (-(1 << 31))
     else:
-        vals = maxv.astype(jnp.int32)
+        vals = maxv
     vals = jnp.where(nonempty, vals, _NEG)
-    out_ref[...] = jnp.maximum(out_ref[...], vals[None, :])
+    out_ref[...] = jnp.maximum(out_ref[...], vals)
 
 
-@functools.partial(jax.jit, static_argnames=("n_block_rows", "signed", "interpret"))
+@functools.partial(
+    jax.jit,
+    static_argnames=("n_block_rows", "signed", "interpret", "tiles_per_launch"),
+)
 def tc_neighbor_max_bits_pallas(
     tiles_words: jnp.ndarray,  # (nt, T, W) uint32 — standard bit layout
     tile_rows: jnp.ndarray,    # (nt,) int32, non-decreasing
@@ -139,7 +160,8 @@ def tc_neighbor_max_bits_pallas(
     n_block_rows: int,
     *,
     signed: bool = False,
-    interpret: bool = True,
+    interpret: bool,
+    tiles_per_launch: int = TILES_PER_LAUNCH,
 ) -> jnp.ndarray:
     """Bitwise Max_Np: plane-scan form.  Returns (n_block_rows*T,) int32.
 
@@ -147,31 +169,27 @@ def tc_neighbor_max_bits_pallas(
     `core.tiling.pack_priority_planes` (`signed=True` iff the planes were
     sign-biased there).  Per grid step the DMA moves one packed tile, the
     block-column's plane column and its mask word — all uint32 words; no
-    dense frontier or priority vector ever crosses HBM."""
+    dense frontier or priority vector ever crosses HBM.  The kernel reads
+    the stack block-column-major, (nbc, n_bits, W), so that one column's
+    planes form a block the TPU compiler accepts."""
     if tiles_words.dtype != jnp.uint32:
         raise ValueError(
             f"tc_neighbor_max_bits_pallas needs packed uint32 tiles, got "
             f"{tiles_words.dtype} (convert via tiling.tiles_as_words)"
         )
-    nt, T, W = tiles_words.shape
+    _, T, W = tiles_words.shape
     n_bits = int(planes.shape[0])
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(nt,),
-        in_specs=[
-            pl.BlockSpec((1, T, W), lambda i, rows, cols: (i, 0, 0)),
-            pl.BlockSpec((n_bits, 1, W), lambda i, rows, cols: (0, cols[i], 0)),
-            pl.BlockSpec((1, W), lambda i, rows, cols: (cols[i], 0)),
-        ],
-        out_specs=pl.BlockSpec((1, T), lambda i, rows, cols: (rows[i], 0)),
-    )
-    out = pl.pallas_call(
+    nbc = int(mask_words.shape[0])
+    (out,) = tile_pallas_call(
         functools.partial(
             _nbr_max_bits_kernel, n_bits=n_bits, signed=signed, tile_size=T
         ),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_block_rows, T), jnp.int32),
-        interpret=interpret,
-    )(tile_rows, tile_cols, tiles_words, planes, mask_words)
+        tiles=tiles_words, tile_rows=tile_rows, tile_cols=tile_cols,
+        col_flags=None,
+        inputs=[(jnp.transpose(planes, (1, 0, 2)), (1, n_bits, W), "col"),
+                (mask_words.reshape(nbc, 1, W), (1, 1, W), "col")],
+        outputs=[(jax.ShapeDtypeStruct((n_block_rows * T, 1), jnp.int32),
+                  (T, 1))],
+        carried=[0], interpret=interpret, tiles_per_launch=tiles_per_launch,
+    )
     return out.reshape(n_block_rows * T)

@@ -25,6 +25,11 @@ TPU-native replacements for the paper's GPU mechanics (DESIGN.md §2):
     skippable by pointing the index_map at the previous block — that variant
     is `skip_dma=True` (hill-climb knob; both validated against the oracle).
 
+The per-tile schedule (block row, block column, column flag) is scalar-
+prefetched into SMEM; `kernels.tile_grid.tile_pallas_call` runs a tile list
+longer than SMEM holds as several launches, resuming a block row cut by a
+launch edge.
+
 Storage axis (DESIGN.md §11): tiles arrive either dense int8 (T, T) or
 bit-packed uint32 (T, W) with W = max(T//32, 1).  Packed tiles are unpacked
 IN VMEM inside the kernel body, right after the DMA — HBM only ever carries
@@ -46,22 +51,29 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.tiling import pack_frontier_bits, unpack_tile_mask
+from repro.core.tiling import pack_frontier_column, unpack_tile_mask
+from repro.kernels.tile_grid import TILES_PER_LAUNCH, tile_pallas_call
+
+_STATIC = ("n_block_rows", "interpret", "skip_dma", "tiles_per_launch")
 
 
-def _spmv_kernel(rows_ref, cols_ref, flags_ref, tiles_ref, rhs_ref, out_ref,
-                 *, packed: bool, tile_size: int):
+def _spmv_kernel(rows_ref, cols_ref, flags_ref, tiles_ref, rhs_ref, *refs,
+                 packed: bool, tile_size: int, resume: bool):
+    out_ref = refs[-1]
     i = pl.program_id(0)
-    row = rows_ref[i]
-    prev = rows_ref[jnp.maximum(i - 1, 0)]
+    prev, row = rows_ref[i], rows_ref[i + 1]
 
-    @pl.when((i == 0) | (prev != row))
+    @pl.when(prev != row)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    @pl.when(flags_ref[cols_ref[i]] != 0)
+    if resume:                                     # row cut by the launch edge
+        @pl.when((i == 0) & (prev == row))
+        def _resume():
+            out_ref[...] = refs[0][...]
+
+    @pl.when(flags_ref[i] != 0)
     def _mma():
         a = tiles_ref[0]                           # (T, T) i8 | (T, W) u32
         if packed:                                 # in-VMEM bit→f32, post-DMA
@@ -72,9 +84,7 @@ def _spmv_kernel(rows_ref, cols_ref, flags_ref, tiles_ref, rhs_ref, out_ref,
         out_ref[...] += jnp.dot(a, b, preferred_element_type=jnp.float32)
 
 
-@functools.partial(
-    jax.jit, static_argnames=("n_block_rows", "interpret", "skip_dma")
-)
+@functools.partial(jax.jit, static_argnames=_STATIC)
 def tc_spmv_pallas(
     tiles: jnp.ndarray,       # (nt, T, T) int8, block-row-major
     tile_rows: jnp.ndarray,   # (nt,) int32, non-decreasing
@@ -83,48 +93,29 @@ def tc_spmv_pallas(
     n_block_rows: int,
     *,
     col_flags: jnp.ndarray | None = None,  # (nbc,) int32; None = all active
-    interpret: bool = True,
+    interpret: bool,
     skip_dma: bool = False,
+    tiles_per_launch: int = TILES_PER_LAUNCH,
 ) -> jnp.ndarray:
     """N = A @ rhs over BSR tiles. Returns (n_block_rows*T, L) float32.
 
     `tiles` may be dense int8 (nt, T, T) or bit-packed uint32 (nt, T, W) —
     the packed form DMAs 8× fewer bytes and unpacks in VMEM."""
-    nt, T, tw = tiles.shape
-    packed = tiles.dtype == jnp.uint32
+    T = tiles.shape[1]
     L = rhs.shape[-1]
-    nbc = rhs.shape[0] // T
-    if col_flags is None:
-        col_flags = jnp.ones((nbc,), dtype=jnp.int32)
-
-    if skip_dma:
-        # point the RHS DMA at block 0 when the slab is empty — the MXU op is
-        # predicated off anyway, so correctness is unchanged but the HBM read
-        # is saved on TPU.  (Interpret mode validates the indexing only.)
-        def rhs_index(i, rows, cols, flags):
-            c = cols[i]
-            return (jnp.where(flags[c] != 0, c, 0), 0)
-    else:
-        def rhs_index(i, rows, cols, flags):
-            return (cols[i], 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(nt,),
-        in_specs=[
-            pl.BlockSpec((1, T, tw), lambda i, rows, cols, flags: (i, 0, 0)),
-            pl.BlockSpec((T, L), rhs_index),
-        ],
-        out_specs=pl.BlockSpec(
-            (T, L), lambda i, rows, cols, flags: (rows[i], 0)
+    (out,) = tile_pallas_call(
+        functools.partial(
+            _spmv_kernel, packed=tiles.dtype == jnp.uint32, tile_size=T
         ),
+        tiles=tiles, tile_rows=tile_rows, tile_cols=tile_cols,
+        col_flags=col_flags,
+        inputs=[(rhs, (T, L), "col")],
+        outputs=[(jax.ShapeDtypeStruct((n_block_rows * T, L), jnp.float32),
+                  (T, L))],
+        carried=[0], interpret=interpret, skip_dma=skip_dma,
+        tiles_per_launch=tiles_per_launch,
     )
-    return pl.pallas_call(
-        functools.partial(_spmv_kernel, packed=packed, tile_size=T),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_block_rows * T, L), jnp.float32),
-        interpret=interpret,
-    )(tile_rows, tile_cols, col_flags, tiles, rhs)
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -136,19 +127,22 @@ def tc_spmv_pallas(
 
 def _spmv_fused_kernel(
     rows_ref, cols_ref, flags_ref, tiles_ref, rhs_ref, cand_ref, alive_ref,
-    nc_ref, alive_out_ref, mis_out_ref, *, packed: bool, tile_size: int,
+    *refs, packed: bool, tile_size: int, resume: bool,
 ):
+    nc_ref, alive_out_ref, mis_out_ref = refs[-3:]
     i = pl.program_id(0)
-    nt = pl.num_programs(0)
-    row = rows_ref[i]
-    prev = rows_ref[jnp.maximum(i - 1, 0)]
-    nxt = rows_ref[jnp.minimum(i + 1, nt - 1)]
+    prev, row, nxt = rows_ref[i], rows_ref[i + 1], rows_ref[i + 2]
 
-    @pl.when((i == 0) | (prev != row))
+    @pl.when(prev != row)
     def _init():
         nc_ref[...] = jnp.zeros_like(nc_ref)
 
-    @pl.when(flags_ref[cols_ref[i]] != 0)
+    if resume:                                     # row cut by the launch edge
+        @pl.when((i == 0) & (prev == row))
+        def _resume():
+            nc_ref[...] = refs[0][...]
+
+    @pl.when(flags_ref[i] != 0)
     def _mma():
         a = tiles_ref[0]
         if packed:                                 # in-VMEM bit→f32, post-DMA
@@ -158,7 +152,7 @@ def _spmv_fused_kernel(
         b = rhs_ref[...].astype(jnp.float32)
         nc_ref[...] += jnp.dot(a, b, preferred_element_type=jnp.float32)
 
-    @pl.when((i == nt - 1) | (nxt != row))
+    @pl.when(nxt != row)
     def _epilogue():
         # phase ③, paper's three rules — lock-free: own row block only
         cand = cand_ref[...] != 0                      # (T, 1) lanes
@@ -168,9 +162,7 @@ def _spmv_fused_kernel(
         alive_out_ref[...] = (alive & ~cand & ~hit).astype(jnp.int8)
 
 
-@functools.partial(
-    jax.jit, static_argnames=("n_block_rows", "interpret", "skip_dma")
-)
+@functools.partial(jax.jit, static_argnames=_STATIC)
 def tc_spmv_fused_pallas(
     tiles: jnp.ndarray,
     tile_rows: jnp.ndarray,
@@ -181,57 +173,31 @@ def tc_spmv_fused_pallas(
     n_block_rows: int,
     *,
     col_flags: jnp.ndarray | None = None,
-    interpret: bool = True,
+    interpret: bool,
     skip_dma: bool = False,
+    tiles_per_launch: int = TILES_PER_LAUNCH,
 ):
     """Fused phase ②+③: returns (n_c (nbr*T, L) f32, new_alive i8, mis_add i8).
 
     Storage-polymorphic like the split kernel: bit-packed uint32 tiles DMA
     8× fewer bytes and unpack in VMEM inside the kernel body."""
-    nt, T, tw = tiles.shape
-    packed = tiles.dtype == jnp.uint32
+    T = tiles.shape[1]
     L = rhs.shape[-1]
-    nbc = rhs.shape[0] // T
-    if col_flags is None:
-        col_flags = jnp.ones((nbc,), dtype=jnp.int32)
-
-    if skip_dma:
-        # same trick as the split kernel: an empty-C slab's DMA is retargeted
-        # at block 0 — the MXU op is predicated off, the HBM read is saved.
-        def rhs_index(i, rows, cols, flags):
-            c = cols[i]
-            return (jnp.where(flags[c] != 0, c, 0), 0)
-    else:
-        def rhs_index(i, rows, cols, flags):
-            return (cols[i], 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(nt,),
-        in_specs=[
-            pl.BlockSpec((1, T, tw), lambda i, rows, cols, flags: (i, 0, 0)),
-            pl.BlockSpec((T, L), rhs_index),
-            pl.BlockSpec((T, 1), lambda i, rows, cols, flags: (rows[i], 0)),
-            pl.BlockSpec((T, 1), lambda i, rows, cols, flags: (rows[i], 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((T, L), lambda i, rows, cols, flags: (rows[i], 0)),
-            pl.BlockSpec((T, 1), lambda i, rows, cols, flags: (rows[i], 0)),
-            pl.BlockSpec((T, 1), lambda i, rows, cols, flags: (rows[i], 0)),
-        ],
-    )
-    n_c, new_alive, mis_add = pl.pallas_call(
-        functools.partial(_spmv_fused_kernel, packed=packed, tile_size=T),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((n_block_rows * T, L), jnp.float32),
-            jax.ShapeDtypeStruct((n_block_rows * T, 1), jnp.int8),
-            jax.ShapeDtypeStruct((n_block_rows * T, 1), jnp.int8),
-        ],
-        interpret=interpret,
-    )(
-        tile_rows, tile_cols, col_flags, tiles, rhs,
-        cand.reshape(-1, 1), alive.reshape(-1, 1),
+    n = n_block_rows * T
+    n_c, new_alive, mis_add = tile_pallas_call(
+        functools.partial(
+            _spmv_fused_kernel, packed=tiles.dtype == jnp.uint32, tile_size=T
+        ),
+        tiles=tiles, tile_rows=tile_rows, tile_cols=tile_cols,
+        col_flags=col_flags,
+        inputs=[(rhs, (T, L), "col"),
+                (cand.reshape(-1, 1), (T, 1), "row"),
+                (alive.reshape(-1, 1), (T, 1), "row")],
+        outputs=[(jax.ShapeDtypeStruct((n, L), jnp.float32), (T, L)),
+                 (jax.ShapeDtypeStruct((n, 1), jnp.int8), (T, 1)),
+                 (jax.ShapeDtypeStruct((n, 1), jnp.int8), (T, 1))],
+        carried=[0], interpret=interpret, skip_dma=skip_dma,
+        tiles_per_launch=tiles_per_launch,
     )
     return n_c, new_alive[:, 0], mis_add[:, 0]
 
@@ -241,31 +207,43 @@ def tc_spmv_fused_pallas(
 # contraction.  Per grid step the DMA moves one (T, W) tile and one (1, W)
 # candidate word row — 32× less RHS traffic than the lane-packed f32 slab —
 # and the "matmul" is popcount(AND) != 0 folded straight to a result bit.
+# Word vectors ride as (nb, 1, W): the TPU compiler takes a block whose last
+# two dims are (8k, 128k) or the array's own, so a one-row block of words
+# needs the unit middle axis.
 # ---------------------------------------------------------------------------
 
-def _spmv_bits_kernel(rows_ref, cols_ref, flags_ref, tiles_ref, rhs_ref,
-                      out_ref, *, tile_size: int):
-    i = pl.program_id(0)
-    row = rows_ref[i]
-    prev = rows_ref[jnp.maximum(i - 1, 0)]
+def _word_blocks(words: jnp.ndarray) -> jnp.ndarray:
+    """(nb, W) frontier words -> (nb, 1, W), blocked (1, 1, W)."""
+    return words.reshape(words.shape[0], 1, words.shape[1])
 
-    @pl.when((i == 0) | (prev != row))
+
+def _spmv_bits_kernel(rows_ref, cols_ref, flags_ref, tiles_ref, rhs_ref,
+                      *refs, tile_size: int, resume: bool):
+    out_ref = refs[-1]
+    i = pl.program_id(0)
+    prev, row = rows_ref[i], rows_ref[i + 1]
+
+    @pl.when(prev != row)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    @pl.when(flags_ref[cols_ref[i]] != 0)
+    if resume:                                     # row cut by the launch edge
+        @pl.when((i == 0) & (prev == row))
+        def _resume():
+            out_ref[...] = refs[0][...]
+
+    @pl.when(flags_ref[i] != 0)
     def _and():
+        # row v of the (T, W) tile is hit iff any word of tile[v] & C is
+        # nonzero; the test reduces along lanes to a (T, 1) column, which
+        # `pack_frontier_column` folds into a (1, W) word row
         a = tiles_ref[0]                          # (T, W) u32: row v's words
-        c = rhs_ref[...]                          # (1, W) candidate words
-        hit = jnp.any(jax.lax.population_count(a & c) != 0, axis=1)  # (T,)
-        out_ref[...] |= pack_frontier_bits(
-            hit[None, :].astype(jnp.uint32), tile_size
-        )
+        c = rhs_ref[0]                            # (1, W) candidate words
+        hit = jnp.max(((a & c) != 0).astype(jnp.int32), axis=1, keepdims=True)
+        out_ref[0] |= pack_frontier_column(hit, tile_size)
 
 
-@functools.partial(
-    jax.jit, static_argnames=("n_block_rows", "interpret", "skip_dma")
-)
+@functools.partial(jax.jit, static_argnames=_STATIC)
 def tc_spmv_bits_pallas(
     tiles_words: jnp.ndarray,  # (nt, T, W) uint32, block-row-major
     tile_rows: jnp.ndarray,    # (nt,) int32, non-decreasing
@@ -274,8 +252,9 @@ def tc_spmv_bits_pallas(
     n_block_rows: int,
     *,
     col_flags: jnp.ndarray | None = None,
-    interpret: bool = True,
+    interpret: bool,
     skip_dma: bool = False,
+    tiles_per_launch: int = TILES_PER_LAUNCH,
 ) -> jnp.ndarray:
     """hit = (A @ C) > 0 on packed words.  Returns (n_block_rows, W) uint32.
 
@@ -286,73 +265,54 @@ def tc_spmv_bits_pallas(
             f"tc_spmv_bits_pallas needs packed uint32 tiles, got "
             f"{tiles_words.dtype} (convert via tiling.tiles_as_words)"
         )
-    nt, T, W = tiles_words.shape
-    nbc = rhs_words.shape[0]
-    if col_flags is None:
-        col_flags = jnp.ones((nbc,), dtype=jnp.int32)
-
-    if skip_dma:
-        # empty-C word row: retarget the DMA at block 0 — the AND is
-        # predicated off, the (tiny) HBM read is saved on TPU.
-        def rhs_index(i, rows, cols, flags):
-            c = cols[i]
-            return (jnp.where(flags[c] != 0, c, 0), 0)
-    else:
-        def rhs_index(i, rows, cols, flags):
-            return (cols[i], 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(nt,),
-        in_specs=[
-            pl.BlockSpec((1, T, W), lambda i, rows, cols, flags: (i, 0, 0)),
-            pl.BlockSpec((1, W), rhs_index),
-        ],
-        out_specs=pl.BlockSpec((1, W), lambda i, rows, cols, flags: (rows[i], 0)),
-    )
-    return pl.pallas_call(
+    _, T, W = tiles_words.shape
+    (out,) = tile_pallas_call(
         functools.partial(_spmv_bits_kernel, tile_size=T),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_block_rows, W), jnp.uint32),
-        interpret=interpret,
-    )(tile_rows, tile_cols, col_flags, tiles_words, rhs_words)
+        tiles=tiles_words, tile_rows=tile_rows, tile_cols=tile_cols,
+        col_flags=col_flags,
+        inputs=[(_word_blocks(rhs_words), (1, 1, W), "col")],
+        outputs=[(jax.ShapeDtypeStruct((n_block_rows, 1, W), jnp.uint32),
+                  (1, 1, W))],
+        carried=[0], interpret=interpret, skip_dma=skip_dma,
+        tiles_per_launch=tiles_per_launch,
+    )
+    return out.reshape(n_block_rows, W)
 
 
 def _spmv_fused_bits_kernel(
     rows_ref, cols_ref, flags_ref, tiles_ref, rhs_ref, cand_ref, alive_ref,
-    hit_ref, alive_out_ref, mis_out_ref, *, tile_size: int,
+    *refs, tile_size: int, resume: bool,
 ):
+    hit_ref, alive_out_ref, mis_out_ref = refs[-3:]
     i = pl.program_id(0)
-    nt = pl.num_programs(0)
-    row = rows_ref[i]
-    prev = rows_ref[jnp.maximum(i - 1, 0)]
-    nxt = rows_ref[jnp.minimum(i + 1, nt - 1)]
+    prev, row, nxt = rows_ref[i], rows_ref[i + 1], rows_ref[i + 2]
 
-    @pl.when((i == 0) | (prev != row))
+    @pl.when(prev != row)
     def _init():
         hit_ref[...] = jnp.zeros_like(hit_ref)
 
-    @pl.when(flags_ref[cols_ref[i]] != 0)
+    if resume:                                     # row cut by the launch edge
+        @pl.when((i == 0) & (prev == row))
+        def _resume():
+            hit_ref[...] = refs[0][...]
+
+    @pl.when(flags_ref[i] != 0)
     def _and():
         a = tiles_ref[0]
-        c = rhs_ref[...]
-        hit = jnp.any(jax.lax.population_count(a & c) != 0, axis=1)
-        hit_ref[...] |= pack_frontier_bits(
-            hit[None, :].astype(jnp.uint32), tile_size
-        )
+        c = rhs_ref[0]
+        hit = jnp.max(((a & c) != 0).astype(jnp.int32), axis=1, keepdims=True)
+        hit_ref[0] |= pack_frontier_column(hit, tile_size)
 
-    @pl.when((i == nt - 1) | (nxt != row))
+    @pl.when(nxt != row)
     def _epilogue():
         # phase ③ as word logic — 32 vertices per op, own row block only
-        cand = cand_ref[...]                      # (1, W)
-        alive = alive_ref[...]
-        mis_out_ref[...] = cand
-        alive_out_ref[...] = alive & ~cand & ~hit_ref[...]
+        cand = cand_ref[0]                        # (1, W)
+        alive = alive_ref[0]
+        mis_out_ref[0] = cand
+        alive_out_ref[0] = alive & ~cand & ~hit_ref[0]
 
 
-@functools.partial(
-    jax.jit, static_argnames=("n_block_rows", "interpret", "skip_dma")
-)
+@functools.partial(jax.jit, static_argnames=_STATIC)
 def tc_spmv_fused_bits_pallas(
     tiles_words: jnp.ndarray,  # (nt, T, W) uint32
     tile_rows: jnp.ndarray,
@@ -362,8 +322,9 @@ def tc_spmv_fused_bits_pallas(
     n_block_rows: int,
     *,
     col_flags: jnp.ndarray | None = None,
-    interpret: bool = True,
+    interpret: bool,
     skip_dma: bool = False,
+    tiles_per_launch: int = TILES_PER_LAUNCH,
 ):
     """Fused ②+③ on packed words.
 
@@ -376,45 +337,19 @@ def tc_spmv_fused_bits_pallas(
             f"tc_spmv_fused_bits_pallas needs packed uint32 tiles, got "
             f"{tiles_words.dtype} (convert via tiling.tiles_as_words)"
         )
-    nt, T, W = tiles_words.shape
-    nbc = cand_words.shape[0]
-    if col_flags is None:
-        col_flags = jnp.ones((nbc,), dtype=jnp.int32)
-
-    if skip_dma:
-        def rhs_index(i, rows, cols, flags):
-            c = cols[i]
-            return (jnp.where(flags[c] != 0, c, 0), 0)
-    else:
-        def rhs_index(i, rows, cols, flags):
-            return (cols[i], 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(nt,),
-        in_specs=[
-            pl.BlockSpec((1, T, W), lambda i, rows, cols, flags: (i, 0, 0)),
-            pl.BlockSpec((1, W), rhs_index),
-            pl.BlockSpec((1, W), lambda i, rows, cols, flags: (rows[i], 0)),
-            pl.BlockSpec((1, W), lambda i, rows, cols, flags: (rows[i], 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, W), lambda i, rows, cols, flags: (rows[i], 0)),
-            pl.BlockSpec((1, W), lambda i, rows, cols, flags: (rows[i], 0)),
-            pl.BlockSpec((1, W), lambda i, rows, cols, flags: (rows[i], 0)),
-        ],
-    )
-    hit, new_alive, mis_add = pl.pallas_call(
+    _, T, W = tiles_words.shape
+    cand3 = _word_blocks(cand_words)
+    words = (jax.ShapeDtypeStruct((n_block_rows, 1, W), jnp.uint32), (1, 1, W))
+    outs = tile_pallas_call(
         functools.partial(_spmv_fused_bits_kernel, tile_size=T),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((n_block_rows, W), jnp.uint32),
-            jax.ShapeDtypeStruct((n_block_rows, W), jnp.uint32),
-            jax.ShapeDtypeStruct((n_block_rows, W), jnp.uint32),
-        ],
-        interpret=interpret,
-    )(
-        tile_rows, tile_cols, col_flags, tiles_words,
-        cand_words, cand_words, alive_words,   # C twice: RHS role + own-row role
+        tiles=tiles_words, tile_rows=tile_rows, tile_cols=tile_cols,
+        col_flags=col_flags,
+        inputs=[(cand3, (1, 1, W), "col"),      # C twice: RHS + own-row role
+                (cand3, (1, 1, W), "row"),
+                (_word_blocks(alive_words), (1, 1, W), "row")],
+        outputs=[words] * 3,
+        carried=[0], interpret=interpret, skip_dma=skip_dma,
+        tiles_per_launch=tiles_per_launch,
     )
+    hit, new_alive, mis_add = (o.reshape(n_block_rows, W) for o in outs)
     return hit, new_alive, mis_add

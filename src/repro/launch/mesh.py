@@ -10,15 +10,15 @@ jax device state (the dry-run pins the device count before first jax init).
 """
 from __future__ import annotations
 
-from repro.dist.compat import make_mesh
+import jax
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes)
 
 
 def make_host_mesh(shape=(2, 4), axes=("data", "model")):
     """Small mesh over whatever devices exist (tests / examples)."""
-    return make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes)

@@ -53,7 +53,7 @@ DEPRECATED_SYMBOLS = ("tc_mis", "run_phases", "TCMISConfig")
 DEPRECATED_SOURCES = ("repro.core", "repro.core.tc_mis")
 DEPRECATION_EXEMPT = ("repro.core.tc_mis", "repro.core")
 KERNEL_CALL_ALLOWLIST = frozenset(
-    TILE_UNPACKS + ("pack_frontier_bits", "pack_sorted_frontier_bits")
+    TILE_UNPACKS + ("pack_frontier_column",)
 )
 KERNEL_PY_BUILTINS = frozenset(
     {"range", "len", "min", "max", "abs", "int", "float", "bool",

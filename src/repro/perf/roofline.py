@@ -12,8 +12,12 @@ collective bytes are parsed from the compiled HLO text: the output payload of
 every all-gather / all-reduce / reduce-scatter / all-to-all /
 collective-permute (async -start forms counted once, -done skipped).
 
-Hardware model: TPU v5e — 197e12 bf16 FLOP/s, 819e9 B/s HBM, ~50e9 B/s ICI
-per link (constants from the assignment).
+Hardware model: `DEVICE_PEAKS`, one entry per chip keyed by
+`jax.Device.device_kind`, each with its published source.  A TPU kind that
+is not in the table is an error (`device_peaks`), never a silent default.
+The module constants `PEAK_FLOPS` / `HBM_BW` / `ICI_BW` are the TPU v5e
+entry — the chip the dry-run cost models and the analytic hybrid threshold
+are sized for.
 
 The same hardware constants drive the hybrid tile-routing threshold
 (DESIGN.md §16): a dense tile pays a fixed cost regardless of occupancy,
@@ -26,11 +30,58 @@ from __future__ import annotations
 
 import dataclasses
 import re
-from typing import Dict
+from typing import Dict, Optional
 
-PEAK_FLOPS = 197e12      # bf16 per chip
-HBM_BW = 819e9           # bytes/s per chip
-ICI_BW = 50e9            # bytes/s per link
+import jax
+
+
+@dataclasses.dataclass(frozen=True)
+class DevicePeaks:
+    """Published per-chip peaks of one device kind."""
+    flops_bf16: float    # FLOP/s, bf16 matrix unit
+    hbm_bw: float        # HBM bytes/s
+    ici_bw: float        # chip-to-chip bytes/s per link
+    source: str
+
+
+_V5E_SOURCE = (
+    "Google Cloud documentation, 'TPU v5e': 197 TFLOP/s bf16, "
+    "16 GB HBM at 819 GB/s, 1,600 Gbit/s ICI over 4 links"
+)
+
+# keyed by `jax.Device.device_kind` as JAX reports it
+DEVICE_PEAKS: Dict[str, DevicePeaks] = {
+    "TPU v5 lite": DevicePeaks(
+        flops_bf16=197e12, hbm_bw=819e9, ici_bw=1600e9 / 8 / 4,
+        source=_V5E_SOURCE,
+    ),
+}
+
+V5E = DEVICE_PEAKS["TPU v5 lite"]
+PEAK_FLOPS = V5E.flops_bf16     # bf16 per chip
+HBM_BW = V5E.hbm_bw             # bytes/s per chip
+ICI_BW = V5E.ici_bw             # bytes/s per link
+
+
+def peaks_for_kind(device_kind: str) -> DevicePeaks:
+    """Peaks of one chip by `device_kind`; an unlisted kind is an error."""
+    try:
+        return DEVICE_PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; "
+            f"known: {sorted(DEVICE_PEAKS)}"
+        ) from None
+
+
+def device_peaks(device=None) -> Optional[DevicePeaks]:
+    """Peaks of `device` (default: the first JAX device).  None off TPU —
+    a CPU run has no roofline; an unlisted TPU kind raises."""
+    d = jax.devices()[0] if device is None else device
+    if d.platform != "tpu":
+        return None
+    return peaks_for_kind(d.device_kind)
+
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "f16": 2, "bf16": 2,
@@ -153,7 +204,10 @@ def roofline_from_compiled(
 _SPARSE_BYTES_PER_EDGE = 16
 
 
-def dense_tile_cost_s(tile_size: int, storage: str = "int8", lanes: int = 8) -> float:
+def dense_tile_cost_s(
+    tile_size: int, storage: str = "int8", lanes: int = 8,
+    peaks: DevicePeaks = V5E,
+) -> float:
     """Roofline cost of pushing ONE tile through the dense path, any occupancy.
 
     A dense T×T tile costs the same whether it holds 1 nnz or T² — that
@@ -172,14 +226,14 @@ def dense_tile_cost_s(tile_size: int, storage: str = "int8", lanes: int = 8) -> 
         payload = t * t
     rhs_bytes = t * lanes * 4
     out_bytes = t * lanes * 4
-    compute_s = flops / PEAK_FLOPS
-    memory_s = (payload + rhs_bytes + out_bytes) / HBM_BW
+    compute_s = flops / peaks.flops_bf16
+    memory_s = (payload + rhs_bytes + out_bytes) / peaks.hbm_bw
     return max(compute_s, memory_s)
 
 
-def sparse_edge_cost_s() -> float:
+def sparse_edge_cost_s(peaks: DevicePeaks = V5E) -> float:
     """Roofline cost of ONE nnz on the COO/segment path (pure gather/scatter)."""
-    return _SPARSE_BYTES_PER_EDGE / HBM_BW
+    return _SPARSE_BYTES_PER_EDGE / peaks.hbm_bw
 
 
 def predicted_round_cost_s(
@@ -189,6 +243,7 @@ def predicted_round_cost_s(
     tile_size: int,
     storage: str = "int8",
     lanes: int = 8,
+    peaks: DevicePeaks = V5E,
 ) -> float:
     """Model-predicted cost of ONE solver round (seconds).
 
@@ -200,8 +255,8 @@ def predicted_round_cost_s(
     """
     dense = max(float(dense_tiles), 0.0)
     edges = max(float(sparse_edges), 0.0)
-    return (dense * dense_tile_cost_s(tile_size, storage, lanes)
-            + edges * sparse_edge_cost_s())
+    return (dense * dense_tile_cost_s(tile_size, storage, lanes, peaks)
+            + edges * sparse_edge_cost_s(peaks))
 
 
 def round_cost_attribution(
@@ -212,19 +267,21 @@ def round_cost_attribution(
     storage: str,
     measured_s: float,
     lanes: int = 8,
+    peaks: DevicePeaks = V5E,
 ) -> Dict[str, float]:
     """Predicted-vs-measured per-round cost: the model-error gauge.
 
     Following HC-SpMM's practice of continuously scoring its hybrid-core
     cost model, this closes the loop on the router's pricing: `error_pct`
-    = (measured − predicted) / predicted × 100.  Large positive error on
-    a CPU backend is EXPECTED (the constants model a TPU v5e roofline) —
-    the signal is the trend, not the absolute: a drifting error under
-    churn means the dispatch mix no longer matches what the plan priced.
+    = (measured − predicted) / predicted × 100.  The signal is the trend,
+    not the absolute: a drifting error under churn means the dispatch mix
+    no longer matches what the plan priced.  `peaks` is the measuring
+    chip's entry (`device_peaks`); the Solver records the gauge only on a
+    chip that has one.
     """
     predicted = predicted_round_cost_s(
         dense_tiles, sparse_edges,
-        tile_size=tile_size, storage=storage, lanes=lanes,
+        tile_size=tile_size, storage=storage, lanes=lanes, peaks=peaks,
     )
     measured = max(float(measured_s), 0.0)
     error_pct = (

@@ -9,16 +9,12 @@ import pytest
 
 
 def run_multidevice(script: str, n_devices: int = 8, timeout: int = 600) -> str:
-    """Run `script` in a fresh python with n fake devices; return stdout.
-
-    Scripts are written against the modern jax sharding API; the preamble
-    backfills it on older jax (repro.dist.compat)."""
-    preamble = "from repro.dist.compat import install as _i; _i()\n"
+    """Run `script` in a fresh python with n fake devices; return stdout."""
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
     proc = subprocess.run(
-        [sys.executable, "-c", preamble + textwrap.dedent(script)],
+        [sys.executable, "-c", textwrap.dedent(script)],
         capture_output=True, text=True, timeout=timeout, env=env,
         cwd=os.path.join(os.path.dirname(__file__), ".."),
     )

@@ -97,3 +97,73 @@ def test_spmv_matches_segment_path():
     out_tiled = tc_spmv(tiled, xp[:, None])[: g.n_nodes, 0]
     out_seg = neighbor_sum_segment(g, x)
     assert_allclose(np.asarray(out_tiled), np.asarray(out_seg), rtol=1e-4, atol=1e-4)
+
+
+# ---------------------------------------------------------------------------
+# tile lists split over several launches (kernels/tile_grid.py): a row cut
+# by a launch edge resumes from the partial result the previous launch left
+# ---------------------------------------------------------------------------
+
+def _launch_cases(T=16, seed=13):
+    from repro.core.tiling import pack_frontier_words, pack_priority_planes
+    from repro.kernels.tc_neighbor_max import (
+        tc_neighbor_max_bits_pallas, tc_neighbor_max_pallas,
+    )
+    from repro.kernels.tc_spmv import (
+        tc_spmv_bits_pallas, tc_spmv_fused_bits_pallas,
+        tc_spmv_fused_pallas, tc_spmv_pallas,
+    )
+
+    g, t8 = _tiled(100, 4.0, T, seed)
+    tb = t8.to_storage("bitpack")
+    n, nbr = t8.n_padded, t8.n_block_rows
+    k = jax.random.split(jax.random.key(seed), 4)
+    rhs = jax.random.normal(k[0], (n, 2), jnp.float32)
+    cand = jax.random.uniform(k[1], (n,)) > 0.6
+    alive = (jax.random.uniform(k[2], (n,)) > 0.2) | cand
+    p = jax.random.randint(k[3], (n,), 0, 1 << 20, dtype=jnp.int32)
+    flags = (jnp.arange(t8.n_block_cols) % 3 != 0).astype(jnp.int32)
+    cw, aw = pack_frontier_words(cand, T), pack_frontier_words(alive, T)
+    sched = lambda t: (t.tiles, t.tile_rows, t.tile_cols)
+    return {
+        "spmv_int8": lambda **kw: tc_spmv_pallas(
+            *sched(t8), rhs, nbr, col_flags=flags, **kw),
+        "spmv_bitpack": lambda **kw: tc_spmv_pallas(
+            *sched(tb), rhs, nbr, col_flags=flags, skip_dma=True, **kw),
+        "fused_int8": lambda **kw: tc_spmv_fused_pallas(
+            *sched(t8), rhs, cand.astype(jnp.int8), alive.astype(jnp.int8),
+            nbr, **kw),
+        "fused_bits": lambda **kw: tc_spmv_fused_bits_pallas(
+            *sched(tb), cw, aw, nbr, col_flags=flags, **kw),
+        "spmv_bits": lambda **kw: tc_spmv_bits_pallas(
+            *sched(tb), cw, nbr, **kw),
+        "nbr_max_bitpack": lambda **kw: tc_neighbor_max_pallas(
+            *sched(tb), jnp.where(alive, p, _NEG), nbr, **kw),
+        "plane_resolve": lambda **kw: tc_neighbor_max_bits_pallas(
+            *sched(tb), pack_priority_planes(-p - 1, T, 32, signed=True),
+            aw, nbr, signed=True, **kw),
+    }, t8
+
+
+@pytest.mark.parametrize("tiles_per_launch", [10, 24])
+@pytest.mark.parametrize("name", [
+    "spmv_int8", "spmv_bitpack", "fused_int8", "fused_bits", "spmv_bits",
+    "nbr_max_bitpack", "plane_resolve",
+])
+def test_kernel_split_over_launches_matches_one_launch(name, tiles_per_launch):
+    """Every BSR kernel gives the same result whether its tile list runs
+    as one launch or as several short ones, which cut block rows apart."""
+    cases, t = _launch_cases()
+    run = cases[name]
+    assert t.n_tiles > tiles_per_launch         # at least two launches
+    whole = jax.tree_util.tree_leaves(run(interpret=True))
+    split = jax.tree_util.tree_leaves(
+        run(interpret=True, tiles_per_launch=tiles_per_launch)
+    )
+    # rows no tile visits are never written (the ops wrappers patch them)
+    covered = np.asarray(t.row_starts[1:] > t.row_starts[:-1])
+    for a, b in zip(whole, split):
+        a, b = np.asarray(a), np.asarray(b)
+        rows = covered if a.shape[0] == covered.shape[0] \
+            else np.repeat(covered, t.tile_size)
+        np.testing.assert_array_equal(a[rows], b[rows])

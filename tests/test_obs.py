@@ -551,9 +551,14 @@ def test_promtext_rendering_and_atomic_write(tmp_path):
 # §17: service health (SLO histograms, gauges, span stages) + drift + roofline
 # --------------------------------------------------------------------------
 
-def test_service_health_drift_and_attribution(tmp_path):
+def test_service_health_drift_and_attribution(tmp_path, monkeypatch):
+    from repro.api import solver as solver_mod
     from repro.dyngraph import random_delta
+    from repro.perf.roofline import V5E
 
+    # the roofline gauges are recorded only on a chip with a peak entry:
+    # steer the device lookup to the v5e entry so this CPU run scores them
+    monkeypatch.setattr(solver_mod, "device_peaks", lambda: V5E)
     before_epochs = REGISTRY.snapshot().get("dyngraph.epochs", 0)
     svc = MISService(ServeConfig(
         engine="tiled_ref", max_batch=2, repair="incremental",

@@ -97,3 +97,49 @@ def test_affine_extrapolation_math():
     assert out["flops"] == pytest.approx(10 + 3 * 8)       # +3/layer × 8 layers
     assert out["bytes_accessed"] == pytest.approx(100 + 15 * 8)
     assert out["collectives"]["all-reduce"] == 4 + 3 * 8
+
+
+def test_peaks_are_keyed_by_device_kind_with_a_source():
+    from repro.perf.roofline import DEVICE_PEAKS, V5E, peaks_for_kind
+
+    assert peaks_for_kind("TPU v5 lite") is V5E
+    assert (V5E.flops_bf16, V5E.hbm_bw, V5E.ici_bw) == (197e12, 819e9, 50e9)
+    assert (PEAK_FLOPS, HBM_BW, ICI_BW) == (197e12, 819e9, 50e9)
+    for kind, peaks in DEVICE_PEAKS.items():
+        assert kind.startswith("TPU") and peaks.source
+
+
+def test_unknown_device_kind_is_an_error():
+    from repro.perf.roofline import peaks_for_kind
+
+    with pytest.raises(ValueError, match="no published peaks"):
+        peaks_for_kind("TPU v99")
+
+
+def test_device_peaks_off_tpu_is_none_and_unknown_tpu_raises():
+    from types import SimpleNamespace
+
+    from repro.perf.roofline import V5E, device_peaks
+
+    assert device_peaks(SimpleNamespace(platform="cpu", device_kind="cpu")) \
+        is None
+    v5e = SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")
+    assert device_peaks(v5e) is V5E
+    with pytest.raises(ValueError):
+        device_peaks(SimpleNamespace(platform="tpu", device_kind="TPU v99"))
+
+
+def test_roofline_gauges_absent_off_chip(monkeypatch):
+    """The Solver scores its roofline gauges only on a chip with a peak
+    entry; a solve with telemetry on, on a device without one, records
+    none."""
+    from repro.api import SolveOptions, Solver
+    from repro.api import solver as solver_mod
+    from repro.graphs.generators import erdos_renyi
+
+    monkeypatch.setattr(solver_mod, "device_peaks", lambda: None)
+    solver = Solver(SolveOptions(engine="tiled_ref", telemetry=True))
+    res = solver.solve(erdos_renyi(96, avg_deg=4.0, seed=3))
+    assert res.telemetry is not None and res.telemetry.rounds > 0
+    snap = solver.metrics.snapshot()
+    assert not any(k.startswith("perf.roofline") for k in snap)

@@ -83,3 +83,38 @@ def test_train_loop_end_to_end_lm(tmp_path):
     assert np.isfinite(res["metrics"]["loss"])
     # copy-structure stream is learnable: loss must drop below uniform
     assert res["metrics"]["loss"] < float(np.log(cfg.vocab)) - 0.3
+
+
+def test_generate_builds_vectorised_graphs_at_full_scale():
+    """G2 and G3 build at their published |V|; the per-vertex Python
+    generators refuse full scale instead of running for hours."""
+    from repro.graphs.generators import GRAPH_SUITE, generate
+
+    g2 = generate("G2", scale="full", seed=0)
+    assert g2.n_nodes == 1044 * 1045 >= GRAPH_SUITE["G2"].n_full
+    assert generate("G2").n_nodes == 200 * 200   # reduced scale unchanged
+    for pid in ("G1", "G5", "G7"):
+        with pytest.raises(ValueError, match="dry-run spec"):
+            generate(pid, scale="full")
+    with pytest.raises(ValueError, match="unknown scale"):
+        generate("G2", scale="half")
+
+
+def test_compile_cache_honours_env_else_fixed_checkout_path(monkeypatch):
+    """The entry points' compile-cache helper: the env variable wins and
+    nothing is set; otherwise the fixed `.jax_cache/` of the checkout.
+    `jax.config.update` is captured, so the test turns no cache on."""
+    from repro.launch import compile_cache as cc
+
+    updates = []
+    monkeypatch.setattr(cc.jax.config, "update",
+                        lambda k, v: updates.append((k, v)))
+    monkeypatch.setenv(cc.CACHE_ENV, "/some/cache")
+    assert cc.enable_compile_cache() == "/some/cache"
+    assert updates == []
+    monkeypatch.delenv(cc.CACHE_ENV)
+    path = cc.enable_compile_cache()
+    assert path == str(cc.DEFAULT_CACHE_DIR)
+    assert path.endswith(".jax_cache")
+    assert (cc.DEFAULT_CACHE_DIR.parent / "src" / "repro").is_dir()
+    assert updates == [("jax_compilation_cache_dir", path)]
