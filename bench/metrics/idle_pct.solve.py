@@ -1,0 +1,6 @@
+"""Share of the traced window in which no operation ran on the device
+(1 - busy union / window), from the profiler trace."""
+
+
+def read(run):
+    return None if run.trace is None else run.trace.idle_pct
