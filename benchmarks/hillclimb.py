@@ -152,31 +152,6 @@ def qwen3_dots_remat():
     _measure(_lm_train_cell("qwen3-dots", cfg, "train_4k"))
 
 
-def tcmis_engine(engine="fused_pallas", skip_dma=False):
-    """H-A iter: live round-engine sweep — per-phase wall-clock of one engine
-    (vs the tiled_ref oracle) on a reduced suite graph.  Unlike the dry-run
-    experiments this MEASURES the engine registry end-to-end, col_flags
-    skipping included.
-
-        PYTHONPATH=src python -m benchmarks.hillclimb tcmis_engine --engine fused_pallas
-    """
-    import json as _json
-
-    from benchmarks.common import suite_graphs
-    from repro.api import PlanCache, Solver, SolveOptions
-
-    gid, (spec, g) = next(iter(suite_graphs(scale_div=8).items()))
-    plans = PlanCache(tile_size=64)   # shared: one BSR build, two engines
-    out = {}
-    for name in ("tiled_ref", engine):
-        opts = SolveOptions(engine=name, phase1="tiled", skip_dma=skip_dma,
-                            tile_size=64)
-        _, t = Solver(opts, plans=plans).profile(g)
-        out[name] = {k: round(v, 5) for k, v in t.items()}
-    n_tiles = plans.plan(g, tile_size=64)[0].tiled.n_tiles
-    print(_json.dumps(dict(graph=gid, tiles=n_tiles, **out), indent=1))
-
-
 def tcmis_g3_rcm(rcm=True):
     """H-A iter 3: RCM-informed tiling on delaunay (G3)."""
     import repro.configs.tcmis as tc
@@ -187,7 +162,6 @@ def tcmis_g3_rcm(rcm=True):
 
 
 EXPERIMENTS = {
-    "tcmis_engine": tcmis_engine,
     "tcmis_g3_rcm": tcmis_g3_rcm,
     "qwen3_dots_remat": qwen3_dots_remat,
     "qwen3_baseline": qwen3_baseline,
@@ -206,15 +180,11 @@ def main():
     p.add_argument("--tile", type=int, default=None)
     p.add_argument("--lanes", type=int, default=None)
     p.add_argument("--cf", type=float, default=None)
-    p.add_argument("--engine", type=str, default="fused_pallas")
-    p.add_argument("--skip-dma", action="store_true")
     args = p.parse_args()
     fn = EXPERIMENTS[args.experiment]
     kw = {}
     if args.experiment == "tcmis_g8":
         kw = dict(tile=args.tile, lanes=args.lanes)
-    if args.experiment == "tcmis_engine":
-        kw = dict(engine=args.engine, skip_dma=args.skip_dma)
     if args.experiment == "deepseek_capacity" and args.cf:
         kw = dict(cf=args.cf)
     if args.experiment == "tcmis_g3_rcm":

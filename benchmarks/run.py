@@ -19,7 +19,6 @@ def main() -> None:
         ("core", "benchmarks.core_bench"),
         ("mem", "benchmarks.memory_footprint"),
         ("fig3", "benchmarks.fig3_quality"),
-        ("fig1", "benchmarks.fig1_phase_profile"),
         ("fig4", "benchmarks.fig4_runtime"),
         ("kernel", "benchmarks.kernel_bench"),
         ("hybrid", "benchmarks.hybrid_bench"),

@@ -1,12 +1,11 @@
 """The front door in one screen: `Plan` / `SolveOptions` / `Solver`.
 
 Every MIS execution path of this repo — single graphs, batched serving
-workloads, profiled engine runs, and (on multi-device hosts) the sharded
-path — is reached through the same three nouns (DESIGN.md §10).
+workloads, and (on multi-device hosts) the sharded path — is reached
+through the same three nouns (DESIGN.md §10).
 
     PYTHONPATH=src python examples/solver_quickstart.py
 """
-import numpy as np
 
 from repro.api import Plan, Solver, SolveOptions, choose_tile_size
 from repro.graphs.generators import erdos_renyi, grid2d, powerlaw
@@ -40,13 +39,10 @@ def main() -> None:
     print(f"Plan.build:  key={plan.key[:12]}… T={plan.tile_size} "
           f"tiles={plan.tiled.n_tiles} |MIS|={again.mis_size}")
 
-    # -- the profiler twin returns the SAME set with per-phase timers ------
-    prof, times = solver.profile(g)
-    assert bool(np.all(prof.in_mis == res.in_mis))
-    share = {k: round(1e3 * times[k], 2) for k in ("phase1", "phase2", "phase3")}
-    print(f"profile:     bit-identical to solve; ms/phase={share} "
-          f"rounds={times['rounds']}")
-
+    # -- the compiled program names its phases (DESIGN.md §14) -------------
+    scopes = solver.program_scopes(g)            # op name -> mis.* scope
+    print(f"scopes:      {len(scopes)} ops in "
+          f"{sorted(set(scopes.values()))}")
 
 if __name__ == "__main__":
     main()

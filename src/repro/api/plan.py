@@ -35,6 +35,7 @@ import dataclasses
 import functools
 import hashlib
 import os
+import time
 import uuid
 import warnings
 from collections import OrderedDict
@@ -53,6 +54,7 @@ from repro.core.tiling import (
 )
 from repro.graphs.graph import Graph, from_edges
 from repro.obs.metrics import MetricsRegistry
+from repro.obs.trace import Trace, trace_span
 
 # the PlanCache's legacy stats spelling, now a view over its metrics
 # registry (repro.obs; DESIGN.md §14)
@@ -460,10 +462,13 @@ def build_plan(
     storage: str = "int8",
     hybrid: str = "off",
     hybrid_threshold: int = 0,
+    trace: Optional[Trace] = None,
 ) -> Plan:
     """The cache-miss path: (optional) RCM + BSR tiling + (optional) tile
     partition, no caching.  `hybrid_threshold` arrives already resolved
-    (`resolve_hybrid_threshold`) — this function never invents policy."""
+    (`resolve_hybrid_threshold`) — this function never invents policy.
+    The tiling and the partition run under `plan.tiles` / `plan.partition`
+    spans."""
     perm = inv = None
     if reorder == "rcm":
         perm = np.asarray(rcm_ordering(g))
@@ -474,11 +479,13 @@ def build_plan(
         g = from_edges(inv[s], inv[r], g.n_nodes)
     elif reorder is not None:
         raise ValueError(f"unknown reorder {reorder!r} (None or 'rcm')")
-    tiled = build_block_tiles(g, tile_size=tile_size, storage=storage)
+    with trace_span(trace, "plan.tiles"):
+        tiled = build_block_tiles(g, tile_size=tile_size, storage=storage)
     if hybrid != "off":
-        tiled = attach_partition(
-            tiled, mode=hybrid, threshold=int(hybrid_threshold)
-        )
+        with trace_span(trace, "plan.partition"):
+            tiled = attach_partition(
+                tiled, mode=hybrid, threshold=int(hybrid_threshold)
+            )
     from repro.dyngraph.drift import tile_occupancy
 
     return Plan(g=g, tiled=tiled, key=key, perm=perm, inv=inv,
@@ -559,8 +566,13 @@ class PlanCache:
         storage: Optional[str] = None,
         hybrid: Optional[str] = None,
         hybrid_threshold: Optional[int] = None,
+        trace: Optional[Trace] = None,
     ) -> Tuple[Plan, str]:
-        """Return (plan, status) with status ∈ {'mem', 'disk', 'built'}."""
+        """Return (plan, status) with status ∈ {'mem', 'disk', 'built'}.
+
+        A build (status 'built') records its stages into `trace`:
+        `plan.key` (the content hash), `plan.tiles`, `plan.partition`; a
+        hit records none, its cost being the hash alone."""
         T = self.tile_size if tile_size is None else int(tile_size)
         ro = self.reorder if reorder is None else reorder
         st = resolve_storage(
@@ -573,7 +585,10 @@ class PlanCache:
             self.hybrid_threshold if hybrid_threshold is None
             else hybrid_threshold,
         )
-        key = plan_cache_key(g, T, ro, st, hy, thr)
+        t_key = time.perf_counter()
+        with trace_span(None, "plan.key"):   # into `trace` on a miss only
+            key = plan_cache_key(g, T, ro, st, hy, thr)
+        key_end = time.perf_counter()
         hit = self._mem.get(key)
         if hit is not None:
             self._count("mem_hits")
@@ -600,8 +615,11 @@ class PlanCache:
                     self._path(plan_cache_key(g, T, ro, st))
                 )
         self._count("misses")
+        if trace is not None:
+            trace.note("plan.key", (key_end - t_key) * 1e3, end=key_end)
         plan = build_plan(
-            g, T, ro, key, storage=st, hybrid=hy, hybrid_threshold=thr
+            g, T, ro, key, storage=st, hybrid=hy, hybrid_threshold=thr,
+            trace=trace,
         )
         self._remember(key, plan)
         if self.cache_dir:

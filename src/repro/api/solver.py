@@ -17,8 +17,6 @@ one level up).  Routing (DESIGN.md §10):
                          tile-size group, members bit-identical to solo
                          runs; sharded-routed members peel off to their
                          own dispatch
-    profile(graph)       the python-stepped profiler twin with per-phase
-                         timers (same engine round body as solve)
     update(prior, delta) dynamic graphs (DESIGN.md §12): patch the plan
                          tile-locally through the cache, then repair the
                          solution per `options.repair` — warm-started
@@ -49,11 +47,11 @@ from repro.api.plan import Plan, PlanCache, choose_tile_size, resolve_storage
 from repro.core.engine import get_engine, resolve_frontier
 from repro.core.heuristics import make_priorities
 from repro.core.luby import MISResult
-from repro.core.tc_mis import _run_phases_impl, _tc_mis_impl
+from repro.core.tc_mis import _tc_mis_impl
 from repro.graphs.graph import Graph
 from repro.obs.metrics import MetricsRegistry
 from repro.obs.rounds import RoundTrace
-from repro.obs.trace import Trace, trace_span
+from repro.obs.trace import Trace, hlo_scopes, trace_span
 from repro.perf.roofline import device_peaks, round_cost_attribution
 
 GraphLike = Union[Graph, Plan]
@@ -170,11 +168,18 @@ class Solver:
 
     # -- planning ----------------------------------------------------------
 
-    def plan(self, graph: GraphLike) -> Plan:
+    def plan(self, graph: GraphLike, *, trace: Optional[Trace] = None) -> Plan:
         """Plan a graph through the content-addressed cache (a `Plan` passes
         through untouched).  Auto-T applies when `options.tile_size` is
         None; `options.storage='auto'` resolves per graph (bitpack once the
-        estimated tile payload crosses the threshold, DESIGN.md §11)."""
+        estimated tile payload crosses the threshold, DESIGN.md §11).
+
+        Runs under a `solver.plan` span; a cache miss records the build's
+        stages inside it (`plan.key`, `plan.tiles`, `plan.partition`)."""
+        with trace_span(trace, "solver.plan"):
+            return self._plan(graph, trace)
+
+    def _plan(self, graph: GraphLike, trace: Optional[Trace]) -> Plan:
         if isinstance(graph, Plan):
             return graph
         tile_size = self.options.tile_size or choose_tile_size(
@@ -192,6 +197,7 @@ class Solver:
         plan, _ = self.plans.plan(
             graph, tile_size=tile_size, storage=storage,
             hybrid=hybrid, hybrid_threshold=self.options.hybrid_threshold,
+            trace=trace,
         )
         return plan
 
@@ -214,6 +220,20 @@ class Solver:
             return "sharded"
         return "local"
 
+    def program_scopes(self, graph: GraphLike) -> Dict[str, str]:
+        """`{op name: "mis.*" scope path}` for the compiled program
+        `solve(graph)` runs (`repro.obs.trace.hlo_scopes`): what splits a
+        device trace of the solve by phase and path, since the trace names
+        each op by its instruction name alone.  Lowers the same jit wrapper
+        with the plan's own argument shapes, so after a warm solve under a
+        persistent compile cache this is a cache load, not a compile."""
+        plan = self.plan(graph)
+        if self.route(plan) != "local":
+            raise ValueError("only the local solve program carries mis.* scopes")
+        key = jax.random.key(self.options.seed)
+        compiled = self._jit_single.lower(plan.g, plan.tiled, key).compile()
+        return hlo_scopes(compiled.as_text())
+
     # -- execution ---------------------------------------------------------
 
     def solve(
@@ -230,8 +250,7 @@ class Solver:
         compiled ahead-of-time so `compile_ms` and `execute_ms` are measured
         separately instead of conflated into `solve_ms`."""
         with trace_span(trace, "solver.solve"):
-            with trace_span(trace, "solver.plan"):
-                plan = self.plan(graph)
+            plan = self.plan(graph, trace=trace)
             if key is None:
                 key = jax.random.key(self.options.seed)
             if self.route(plan) == "sharded":
@@ -255,7 +274,7 @@ class Solver:
         input order.
         """
         with trace_span(trace, "solver.plan"):
-            plans = [self.plan(g) for g in graphs]
+            plans = [self._plan(g, trace) for g in graphs]
         if not plans:
             return []
         # the priority cache is keyed by plan content under the DEFAULT
@@ -380,19 +399,7 @@ class Solver:
         return self._wrap(plan2, result, "local", dict(
             compile=compile_stat, batch_size=1,
             repair="incremental", **timing, **extra,
-        ), telemetry=rt)
-
-    def profile(self, graph: GraphLike, *, key: Optional[jax.Array] = None):
-        """The instrumented twin: python-stepped rounds with per-phase wall
-        clocks.  Returns `(SolveResult, times)` with times keyed phase1/
-        phase2/phase3/rounds; the result bit-matches `solve` on the same
-        graph and key (same engine round body)."""
-        plan = self.plan(graph)
-        if key is None:
-            key = jax.random.key(self.options.seed)
-        result, times = _run_phases_impl(plan.g, plan.tiled, key, self.options)
-        self.metrics.counter("solver.solves").inc()
-        return self._wrap(plan, result, "local", dict(times)), times
+        ), telemetry=rt, trace=trace)
 
     # -- the three execution paths ----------------------------------------
 
@@ -403,12 +410,16 @@ class Solver:
         placement: str,
         stats: Dict,
         telemetry: Optional[RoundTrace] = None,
+        trace: Optional[Trace] = None,
     ) -> SolveResult:
-        in_mis_plan = np.asarray(result.in_mis).astype(bool)
+        with trace_span(trace, "solver.fetch"):
+            in_mis_plan = np.asarray(result.in_mis).astype(bool)
+            in_mis = plan.to_original(in_mis_plan).astype(bool)
+            rounds, converged = int(result.rounds), bool(result.converged)
         return SolveResult(
-            in_mis=plan.to_original(in_mis_plan).astype(bool),
-            rounds=int(result.rounds),
-            converged=bool(result.converged),
+            in_mis=in_mis,
+            rounds=rounds,
+            converged=converged,
             placement=placement,
             plan=plan,
             stats=stats,
@@ -440,8 +451,8 @@ class Solver:
     def _dispatch(self, jit_fn, sig, compile_stat, trace, *args):
         """One compiled-program dispatch → (output, timing stats dict).
 
-        Untraced (the default): call the jit wrapper, book the conflated
-        wall clock as `solve_ms` — byte-identical behaviour to pre-obs.
+        Untraced (the default): call the jit wrapper under a `solver.execute`
+        profiler annotation, book the conflated wall clock as `solve_ms`.
         Traced: on a cold signature, lower + compile AHEAD of time under a
         `solver.compile` span (program kept in the bounded `_aot` cache,
         keyed by the same signature as the compile stat), then run under
@@ -450,8 +461,9 @@ class Solver:
         """
         t0 = time.perf_counter()
         if trace is None:
-            out = jit_fn(*args)
-            jax.block_until_ready(out)
+            with trace_span(None, "solver.execute"):
+                out = jit_fn(*args)
+                jax.block_until_ready(out)
             return out, {"solve_ms": round((time.perf_counter() - t0) * 1e3, 3)}
         timing = {}
         compiled = self._aot.get(sig)
@@ -568,7 +580,7 @@ class Solver:
         self._note_attribution(plan.tiled, rt, timing["solve_ms"])
         return self._wrap(plan, result, "local", dict(
             compile=compile_stat, batch_size=1, **timing,
-        ), telemetry=rt)
+        ), telemetry=rt, trace=trace)
 
     def _solve_batched(
         self,

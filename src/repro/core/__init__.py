@@ -12,7 +12,7 @@ from repro.core.engine import (
 from repro.core.heuristics import HEURISTICS, Priorities, make_priorities
 from repro.core.luby import MISResult, luby_mis
 from repro.core.ecl_mis import ecl_mis
-from repro.core.tc_mis import TCMISConfig, tc_mis, run_phases
+from repro.core.tc_mis import TCMISConfig, tc_mis
 from repro.core.tiling import (
     STORAGES,
     BlockTiledGraph,
@@ -48,7 +48,7 @@ __all__ = [
     "block_col_flags", "engine_names", "get_engine", "register_engine",
     "HEURISTICS", "Priorities", "make_priorities",
     "MISResult", "luby_mis", "ecl_mis",
-    "TCMISConfig", "tc_mis", "run_phases",
+    "TCMISConfig", "tc_mis",
     "STORAGES", "BlockTiledGraph", "TilePartition", "attach_partition",
     "build_block_tiles", "gather_frontier_bits", "pack_tile_bits",
     "pack_vertex_vector", "packed_words", "partition_tiles", "tile_nnz",

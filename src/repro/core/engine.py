@@ -37,6 +37,7 @@ This module also owns the raw-array tile operators (`tile_spmv`,
 from __future__ import annotations
 
 import dataclasses
+import functools
 import warnings
 from typing import Any, Dict, NamedTuple, Optional, Tuple
 
@@ -58,6 +59,7 @@ from repro.core.tiling import (
     tiles_as_words,
 )
 from repro.graphs.graph import Graph
+from repro.obs.trace import PATH_EDGE, PATH_TILE, SCOPE_P1, SCOPE_P2, SCOPE_P3
 
 # Round-telemetry buffer columns (DESIGN.md §14).  obs.rounds is the owner
 # of the layout and is deliberately numpy-only, so this import cannot cycle
@@ -73,6 +75,24 @@ from repro.obs.rounds import (
 )
 
 _NEG = np.int32(-(1 << 30))  # numpy scalar: safe to create at import time under a trace
+
+
+def _on_path(path: str):
+    """Run a substrate function under its path sub-scope (`edge` for
+    per-edge gather/scatter, `tile` for the tile schedule).  The round
+    bodies open the phase scopes around it, so an op's name reads
+    `mis.p2/edge/...` (DESIGN.md §14); scopes are op metadata only."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def scoped(*args, **kwargs):
+            with jax.named_scope(path):
+                return fn(*args, **kwargs)
+        return scoped
+    return wrap
+
+
+_edge = _on_path(PATH_EDGE)
+_tile = _on_path(PATH_TILE)
 
 
 # --------------------------------------------------------------------------
@@ -508,6 +528,7 @@ class RoundEngine:
         return pending & (pri.resolve > max_res)
 
     # -- per-round metadata -----------------------------------------------
+    @_tile
     def col_flags(
         self, ctx: EngineContext, cand: jnp.ndarray, alive: jnp.ndarray
     ) -> Optional[jnp.ndarray]:
@@ -562,7 +583,7 @@ class RoundEngine:
             f"(supports_bitwise={self.supports_bitwise})"
         )
 
-    # -- the round body (shared by tc_mis AND run_phases) ------------------
+    # -- the round body ----------------------------------------------------
     def step(
         self, ctx: EngineContext, pri, state: MISRoundState
     ) -> MISRoundState:
@@ -572,18 +593,25 @@ class RoundEngine:
             return self.step_hybrid(ctx, pri, state)
         if ctx.frontier == "bitwise":
             return self.step_bits(ctx, pri, state)
-        cand = self.phase1_candidates(ctx, pri, state.alive)
-        flags = self.col_flags(ctx, cand, state.alive)
-        inc = round_increment(state)
+        with jax.named_scope(SCOPE_P1):
+            cand = self.phase1_candidates(ctx, pri, state.alive)
+        with jax.named_scope(SCOPE_P2):
+            flags = self.col_flags(ctx, cand, state.alive)
+        with jax.named_scope(SCOPE_P3):
+            inc = round_increment(state)
         if self.fused:
-            new_alive, mis_add = self.fused_step(ctx, cand, state.alive, flags)
-            return MISRoundState(
-                alive=new_alive,
-                in_mis=state.in_mis | mis_add,
-                rnd=state.rnd + inc,
-            )
-        n_c = self.phase2_counts(ctx, cand, state.alive, flags)
-        return phase3_update(state, cand, n_c, inc)
+            with jax.named_scope(SCOPE_P2):
+                new_alive, mis_add = self.fused_step(ctx, cand, state.alive, flags)
+            with jax.named_scope(SCOPE_P3):
+                return MISRoundState(
+                    alive=new_alive,
+                    in_mis=state.in_mis | mis_add,
+                    rnd=state.rnd + inc,
+                )
+        with jax.named_scope(SCOPE_P2):
+            n_c = self.phase2_counts(ctx, cand, state.alive, flags)
+        with jax.named_scope(SCOPE_P3):
+            return phase3_update(state, cand, n_c, inc)
 
     # -- the instrumented round body (telemetry runs only) -----------------
     def _step_bits_with_stats(
@@ -608,29 +636,38 @@ class RoundEngine:
             return self._step_hybrid_with_stats(ctx, pri, state)
         if ctx.frontier == "bitwise":
             return self._step_bits_with_stats(ctx, pri, state)
-        alive_count = _count(state.alive)
-        cand = self.phase1_candidates(ctx, pri, state.alive)
-        flags = self.col_flags(ctx, cand, state.alive)
-        inc = round_increment(state)
+        with jax.named_scope(SCOPE_P3):   # telemetry counts: ③ bookkeeping
+            alive_count = _count(state.alive)
+        with jax.named_scope(SCOPE_P1):
+            cand = self.phase1_candidates(ctx, pri, state.alive)
+        with jax.named_scope(SCOPE_P2):
+            flags = self.col_flags(ctx, cand, state.alive)
+        with jax.named_scope(SCOPE_P3):
+            inc = round_increment(state)
         if self.fused:
-            new_alive, mis_add = self.fused_step(ctx, cand, state.alive, flags)
-            new = MISRoundState(
-                alive=new_alive,
-                in_mis=state.in_mis | mis_add,
-                rnd=state.rnd + inc,
-            )
+            with jax.named_scope(SCOPE_P2):
+                new_alive, mis_add = self.fused_step(ctx, cand, state.alive, flags)
+            with jax.named_scope(SCOPE_P3):
+                new = MISRoundState(
+                    alive=new_alive,
+                    in_mis=state.in_mis | mis_add,
+                    rnd=state.rnd + inc,
+                )
         else:
-            n_c = self.phase2_counts(ctx, cand, state.alive, flags)
-            new = phase3_update(state, cand, n_c, inc)
-        skipped = _tiles_skipped(ctx, flags)
-        row = _telemetry_row(
-            alive_count,
-            _count(cand),
-            _count(new.in_mis) - _count(state.in_mis),
-            skipped,
-            _tiles_routed_dense(ctx, skipped, flags),
-            jnp.int32(0),
-        )
+            with jax.named_scope(SCOPE_P2):
+                n_c = self.phase2_counts(ctx, cand, state.alive, flags)
+            with jax.named_scope(SCOPE_P3):
+                new = phase3_update(state, cand, n_c, inc)
+        with jax.named_scope(SCOPE_P3):
+            skipped = _tiles_skipped(ctx, flags)
+            row = _telemetry_row(
+                alive_count,
+                _count(cand),
+                _count(new.in_mis) - _count(state.in_mis),
+                skipped,
+                _tiles_routed_dense(ctx, skipped, flags),
+                jnp.int32(0),
+            )
         return new, row
 
 
@@ -676,6 +713,7 @@ def engine_names() -> Tuple[str, ...]:
 # the four engines
 # --------------------------------------------------------------------------
 
+@_edge
 def _segment_nbr_max(ctx: EngineContext, p, mask) -> jnp.ndarray:
     from repro.core.spmv import neighbor_max_segment
 
@@ -695,6 +733,7 @@ class SegmentEngine(RoundEngine):
     def col_flags(self, ctx, cand, alive):
         return None   # no tiles, nothing to skip
 
+    @_edge
     def phase2_counts(self, ctx, cand, alive, col_flags=None):
         from repro.core.spmv import neighbor_sum_segment
 
@@ -703,6 +742,7 @@ class SegmentEngine(RoundEngine):
         return pack_vertex_vector(n_c, ctx.tiled)
 
 
+@_edge
 def _segment_nbr_max_bits_oracle(ctx: EngineContext, p, mask_words) -> jnp.ndarray:
     """Phase ① for bitwise runs that pin `phase1="segment"`: the edge-list
     substrate has no word form, so the pending mask densifies here — the
@@ -731,6 +771,7 @@ class _TiledEngine(RoundEngine):
     supports_bitwise = True
     supports_hybrid = True
 
+    @_tile
     def _tiled_nbr_max(self, ctx, p, mask) -> jnp.ndarray:
         t = ctx.tiled
         return tile_neighbor_max(
@@ -744,6 +785,7 @@ class _TiledEngine(RoundEngine):
         return self._tiled_nbr_max(ctx, p, mask)
 
     # -- packed-frontier round body (DESIGN.md §13) ------------------------
+    @_tile
     def _nbr_max_bits(
         self, ctx, st: SortedPriorityTiles, planes, mask_words
     ) -> jnp.ndarray:
@@ -780,6 +822,7 @@ class _TiledEngine(RoundEngine):
             max_res = self._nbr_max_bits(ctx, b.resolve, b.resolve_planes, pending)
         return pack_frontier_words(pri.resolve > max_res, T) & pending
 
+    @_tile
     def col_flags_bits(self, ctx, cand_words) -> jnp.ndarray:
         """Active block-column flags straight from the words — a column is
         live iff any of its W candidate words is nonzero (no densify)."""
@@ -797,51 +840,65 @@ class _TiledEngine(RoundEngine):
         raise NotImplementedError(f"{self.name} is a split engine")
 
     def step_bits(self, ctx, pri, state: MISRoundState) -> MISRoundState:
-        cand_w = self.phase1_candidates_bits(ctx, pri, state.alive)
-        flags = self.col_flags_bits(ctx, cand_w)
+        with jax.named_scope(SCOPE_P1):
+            cand_w = self.phase1_candidates_bits(ctx, pri, state.alive)
+        with jax.named_scope(SCOPE_P2):
+            flags = self.col_flags_bits(ctx, cand_w)
         inc = round_increment(state)   # scalar: bitwise excludes member_rounds
         if self.fused:
-            new_alive, mis_add = self.fused_step_bits(
-                ctx, cand_w, state.alive, flags
-            )
-            return MISRoundState(
-                alive=new_alive,
-                in_mis=state.in_mis | mis_add,
-                rnd=state.rnd + inc,
-            )
-        hit_w = self.phase2_hits(ctx, cand_w, state.alive, flags)
-        return phase3_update_bits(state, cand_w, hit_w, inc)
+            with jax.named_scope(SCOPE_P2):
+                new_alive, mis_add = self.fused_step_bits(
+                    ctx, cand_w, state.alive, flags
+                )
+            with jax.named_scope(SCOPE_P3):
+                return MISRoundState(
+                    alive=new_alive,
+                    in_mis=state.in_mis | mis_add,
+                    rnd=state.rnd + inc,
+                )
+        with jax.named_scope(SCOPE_P2):
+            hit_w = self.phase2_hits(ctx, cand_w, state.alive, flags)
+        with jax.named_scope(SCOPE_P3):
+            return phase3_update_bits(state, cand_w, hit_w, inc)
 
     def _step_bits_with_stats(
         self, ctx, pri, state: MISRoundState
     ) -> Tuple[MISRoundState, jnp.ndarray]:
         """`step_bits` + telemetry row; the counts are word popcounts
         (`jax.lax.population_count`) — the frontier never densifies."""
-        alive_count = _popcount_words(state.alive)
-        cand_w = self.phase1_candidates_bits(ctx, pri, state.alive)
-        flags = self.col_flags_bits(ctx, cand_w)
+        with jax.named_scope(SCOPE_P3):   # telemetry counts: ③ bookkeeping
+            alive_count = _popcount_words(state.alive)
+        with jax.named_scope(SCOPE_P1):
+            cand_w = self.phase1_candidates_bits(ctx, pri, state.alive)
+        with jax.named_scope(SCOPE_P2):
+            flags = self.col_flags_bits(ctx, cand_w)
         inc = round_increment(state)
         if self.fused:
-            new_alive, mis_add = self.fused_step_bits(
-                ctx, cand_w, state.alive, flags
-            )
-            new = MISRoundState(
-                alive=new_alive,
-                in_mis=state.in_mis | mis_add,
-                rnd=state.rnd + inc,
-            )
+            with jax.named_scope(SCOPE_P2):
+                new_alive, mis_add = self.fused_step_bits(
+                    ctx, cand_w, state.alive, flags
+                )
+            with jax.named_scope(SCOPE_P3):
+                new = MISRoundState(
+                    alive=new_alive,
+                    in_mis=state.in_mis | mis_add,
+                    rnd=state.rnd + inc,
+                )
         else:
-            hit_w = self.phase2_hits(ctx, cand_w, state.alive, flags)
-            new = phase3_update_bits(state, cand_w, hit_w, inc)
-        skipped = _tiles_skipped(ctx, flags)
-        row = _telemetry_row(
-            alive_count,
-            _popcount_words(cand_w),
-            _popcount_words(new.in_mis) - _popcount_words(state.in_mis),
-            skipped,
-            _tiles_routed_dense(ctx, skipped, flags),
-            jnp.int32(0),
-        )
+            with jax.named_scope(SCOPE_P2):
+                hit_w = self.phase2_hits(ctx, cand_w, state.alive, flags)
+            with jax.named_scope(SCOPE_P3):
+                new = phase3_update_bits(state, cand_w, hit_w, inc)
+        with jax.named_scope(SCOPE_P3):
+            skipped = _tiles_skipped(ctx, flags)
+            row = _telemetry_row(
+                alive_count,
+                _popcount_words(cand_w),
+                _popcount_words(new.in_mis) - _popcount_words(state.in_mis),
+                skipped,
+                _tiles_routed_dense(ctx, skipped, flags),
+                jnp.int32(0),
+            )
         return new, row
 
     # -- hybrid round bodies (DESIGN.md §16) -------------------------------
@@ -853,6 +910,7 @@ class _TiledEngine(RoundEngine):
     # dropped segment row, so padding contributes nothing — the same
     # convention as the Graph sentinel edges.
 
+    @_tile
     def _dense_phase2(self, ctx, cand, alive, col_flags):
         """Split-② over the dense partition, masked to covered rows (the
         Pallas kernel leaves unvisited output blocks uninitialised — see
@@ -866,6 +924,7 @@ class _TiledEngine(RoundEngine):
         commit phase ③ before the sparse hits can merge in."""
         return self.phase2_counts(ctx, cand, alive, col_flags)
 
+    @_edge
     def _sparse_nbr_max(self, ctx, p, mask) -> jnp.ndarray:
         """① over the COO tail: masked priority gather at the senders,
         segment max at the receivers.  Empty segments come back at the
@@ -877,6 +936,7 @@ class _TiledEngine(RoundEngine):
             num_segments=ctx.tiled.n_padded + 1,
         )[:-1]
 
+    @_edge
     def _sparse_counts(self, ctx, cand) -> jnp.ndarray:
         """② over the COO tail: candidate gather + segment sum — the exact
         nnz-wise slice of N_c the dense partition no longer covers."""
@@ -886,15 +946,21 @@ class _TiledEngine(RoundEngine):
             num_segments=ctx.tiled.n_padded + 1,
         )[:-1]
 
-    def _hybrid_nbr_max(self, ctx, dctx, p, mask) -> jnp.ndarray:
-        if ctx.cfg.phase1 != "tiled":
-            # the segment phase ① already covers the WHOLE graph — no merge
-            return _segment_nbr_max(ctx, p, mask)
-        dense_mx = jnp.where(
+    @_tile
+    def _dense_nbr_max(self, dctx, p, mask) -> jnp.ndarray:
+        """① over the dense partition, floored to `_NEG` on uncovered rows
+        (same uninitialised-output hazard as `_dense_phase2`)."""
+        return jnp.where(
             _covered_vertices(dctx.tiled),
             self._tiled_nbr_max(dctx, p, mask),
             _NEG,
         )
+
+    def _hybrid_nbr_max(self, ctx, dctx, p, mask) -> jnp.ndarray:
+        if ctx.cfg.phase1 != "tiled":
+            # the segment phase ① already covers the WHOLE graph — no merge
+            return _segment_nbr_max(ctx, p, mask)
+        dense_mx = self._dense_nbr_max(dctx, p, mask)
         return jnp.maximum(dense_mx, self._sparse_nbr_max(ctx, p, mask))
 
     def _hybrid_candidates(self, ctx, dctx, pri, alive) -> jnp.ndarray:
@@ -907,37 +973,49 @@ class _TiledEngine(RoundEngine):
 
     def step_hybrid(self, ctx, pri, state: MISRoundState) -> MISRoundState:
         dctx = dataclasses.replace(ctx, tiled=ctx.tiled.partition.dense)
-        cand = self._hybrid_candidates(ctx, dctx, pri, state.alive)
-        flags = self.col_flags(dctx, cand, state.alive)
-        inc = round_increment(state)
-        n_c = self._dense_phase2(dctx, cand, state.alive, flags)
-        n_c = n_c + self._sparse_counts(ctx, cand)
-        return phase3_update(state, cand, n_c, inc)
+        with jax.named_scope(SCOPE_P1):
+            cand = self._hybrid_candidates(ctx, dctx, pri, state.alive)
+        with jax.named_scope(SCOPE_P2):
+            flags = self.col_flags(dctx, cand, state.alive)
+        with jax.named_scope(SCOPE_P3):
+            inc = round_increment(state)
+        with jax.named_scope(SCOPE_P2):
+            n_c = self._dense_phase2(dctx, cand, state.alive, flags)
+            n_c = n_c + self._sparse_counts(ctx, cand)
+        with jax.named_scope(SCOPE_P3):
+            return phase3_update(state, cand, n_c, inc)
 
     def _step_hybrid_with_stats(
         self, ctx, pri, state: MISRoundState
     ) -> Tuple[MISRoundState, jnp.ndarray]:
         dctx = dataclasses.replace(ctx, tiled=ctx.tiled.partition.dense)
-        alive_count = _count(state.alive)
-        cand = self._hybrid_candidates(ctx, dctx, pri, state.alive)
-        flags = self.col_flags(dctx, cand, state.alive)
-        inc = round_increment(state)
-        n_c = self._dense_phase2(dctx, cand, state.alive, flags)
-        n_c = n_c + self._sparse_counts(ctx, cand)
-        new = phase3_update(state, cand, n_c, inc)
-        skipped = _tiles_skipped(dctx, flags)
-        row = _telemetry_row(
-            alive_count,
-            _count(cand),
-            _count(new.in_mis) - _count(state.in_mis),
-            skipped,
-            _tiles_routed_dense(dctx, skipped, flags),
-            jnp.int32(ctx.tiled.partition.n_sparse_tiles),
-        )
+        with jax.named_scope(SCOPE_P3):   # telemetry counts: ③ bookkeeping
+            alive_count = _count(state.alive)
+        with jax.named_scope(SCOPE_P1):
+            cand = self._hybrid_candidates(ctx, dctx, pri, state.alive)
+        with jax.named_scope(SCOPE_P2):
+            flags = self.col_flags(dctx, cand, state.alive)
+        with jax.named_scope(SCOPE_P3):
+            inc = round_increment(state)
+        with jax.named_scope(SCOPE_P2):
+            n_c = self._dense_phase2(dctx, cand, state.alive, flags)
+            n_c = n_c + self._sparse_counts(ctx, cand)
+        with jax.named_scope(SCOPE_P3):
+            new = phase3_update(state, cand, n_c, inc)
+            skipped = _tiles_skipped(dctx, flags)
+            row = _telemetry_row(
+                alive_count,
+                _count(cand),
+                _count(new.in_mis) - _count(state.in_mis),
+                skipped,
+                _tiles_routed_dense(dctx, skipped, flags),
+                jnp.int32(ctx.tiled.partition.n_sparse_tiles),
+            )
         return new, row
 
     # -- hybrid, packed frontiers ------------------------------------------
 
+    @_edge
     def _sparse_nbr_max_bits(self, ctx, p, mask_words) -> jnp.ndarray:
         """① tail on packed frontiers: a single-bit gather per nnz
         (`gather_frontier_bits` — shift-and-mask, not a densify), then the
@@ -951,6 +1029,7 @@ class _TiledEngine(RoundEngine):
             pm, part.sp_rows, num_segments=ctx.tiled.n_padded + 1
         )[:-1]
 
+    @_edge
     def _sparse_hits_bits(self, ctx, cand_words) -> jnp.ndarray:
         """② tail on packed frontiers: candidate-bit gather, segment max
         (any-hit), repacked to (nbc, W) words for the `|` merge."""
@@ -963,20 +1042,25 @@ class _TiledEngine(RoundEngine):
         )[:-1]
         return pack_frontier_words(hit, T)
 
+    @_tile
     def _dense_hits_bits(self, dctx, cand_words, alive_words, flags) -> jnp.ndarray:
         """② hit words over the dense partition, masked to covered rows
         (same uninitialised-output hazard as `_dense_phase2`)."""
         hit_w = self.phase2_hits(dctx, cand_words, alive_words, flags)
         return jnp.where(_covered_rows(dctx.tiled)[:, None], hit_w, jnp.uint32(0))
 
-    def _hybrid_nbr_max_bits(
-        self, ctx, dctx, st, planes, p, mask_words
-    ) -> jnp.ndarray:
-        dense_mx = jnp.where(
+    @_tile
+    def _dense_nbr_max_bits(self, dctx, st, planes, mask_words) -> jnp.ndarray:
+        return jnp.where(
             _covered_vertices(dctx.tiled),
             self._nbr_max_bits(dctx, st, planes, mask_words),
             _NEG,
         )
+
+    def _hybrid_nbr_max_bits(
+        self, ctx, dctx, st, planes, p, mask_words
+    ) -> jnp.ndarray:
+        dense_mx = self._dense_nbr_max_bits(dctx, st, planes, mask_words)
         return jnp.maximum(dense_mx, self._sparse_nbr_max_bits(ctx, p, mask_words))
 
     def _hybrid_candidates_bits(self, ctx, dctx, pri, alive_words) -> jnp.ndarray:
@@ -1005,33 +1089,42 @@ class _TiledEngine(RoundEngine):
 
     def step_bits_hybrid(self, ctx, pri, state: MISRoundState) -> MISRoundState:
         dctx = dataclasses.replace(ctx, tiled=ctx.tiled.partition.dense)
-        cand_w = self._hybrid_candidates_bits(ctx, dctx, pri, state.alive)
-        flags = self.col_flags_bits(ctx, cand_w)
+        with jax.named_scope(SCOPE_P1):
+            cand_w = self._hybrid_candidates_bits(ctx, dctx, pri, state.alive)
+        with jax.named_scope(SCOPE_P2):
+            flags = self.col_flags_bits(ctx, cand_w)
         inc = round_increment(state)
-        hit_w = self._dense_hits_bits(dctx, cand_w, state.alive, flags)
-        hit_w = hit_w | self._sparse_hits_bits(ctx, cand_w)
-        return phase3_update_bits(state, cand_w, hit_w, inc)
+        with jax.named_scope(SCOPE_P2):
+            hit_w = self._dense_hits_bits(dctx, cand_w, state.alive, flags)
+            hit_w = hit_w | self._sparse_hits_bits(ctx, cand_w)
+        with jax.named_scope(SCOPE_P3):
+            return phase3_update_bits(state, cand_w, hit_w, inc)
 
     def _step_bits_hybrid_with_stats(
         self, ctx, pri, state: MISRoundState
     ) -> Tuple[MISRoundState, jnp.ndarray]:
         dctx = dataclasses.replace(ctx, tiled=ctx.tiled.partition.dense)
-        alive_count = _popcount_words(state.alive)
-        cand_w = self._hybrid_candidates_bits(ctx, dctx, pri, state.alive)
-        flags = self.col_flags_bits(ctx, cand_w)
+        with jax.named_scope(SCOPE_P3):   # telemetry counts: ③ bookkeeping
+            alive_count = _popcount_words(state.alive)
+        with jax.named_scope(SCOPE_P1):
+            cand_w = self._hybrid_candidates_bits(ctx, dctx, pri, state.alive)
+        with jax.named_scope(SCOPE_P2):
+            flags = self.col_flags_bits(ctx, cand_w)
         inc = round_increment(state)
-        hit_w = self._dense_hits_bits(dctx, cand_w, state.alive, flags)
-        hit_w = hit_w | self._sparse_hits_bits(ctx, cand_w)
-        new = phase3_update_bits(state, cand_w, hit_w, inc)
-        skipped = _tiles_skipped(dctx, flags)
-        row = _telemetry_row(
-            alive_count,
-            _popcount_words(cand_w),
-            _popcount_words(new.in_mis) - _popcount_words(state.in_mis),
-            skipped,
-            _tiles_routed_dense(dctx, skipped, flags),
-            jnp.int32(ctx.tiled.partition.n_sparse_tiles),
-        )
+        with jax.named_scope(SCOPE_P2):
+            hit_w = self._dense_hits_bits(dctx, cand_w, state.alive, flags)
+            hit_w = hit_w | self._sparse_hits_bits(ctx, cand_w)
+        with jax.named_scope(SCOPE_P3):
+            new = phase3_update_bits(state, cand_w, hit_w, inc)
+            skipped = _tiles_skipped(dctx, flags)
+            row = _telemetry_row(
+                alive_count,
+                _popcount_words(cand_w),
+                _popcount_words(new.in_mis) - _popcount_words(state.in_mis),
+                skipped,
+                _tiles_routed_dense(dctx, skipped, flags),
+                jnp.int32(ctx.tiled.partition.n_sparse_tiles),
+            )
         return new, row
 
 
@@ -1040,6 +1133,7 @@ class TiledRefEngine(_TiledEngine):
 
     name = "tiled_ref"
 
+    @_tile
     def phase2_counts(self, ctx, cand, alive, col_flags=None):
         t = ctx.tiled
         out = tile_spmv(
@@ -1049,6 +1143,7 @@ class TiledRefEngine(_TiledEngine):
         )
         return out[:, 0]
 
+    @_tile
     def phase2_hits(self, ctx, cand_words, alive_words, col_flags):
         t = ctx.tiled
         return tile_spmv_bits(
@@ -1063,11 +1158,13 @@ class TiledPallasEngine(_TiledEngine):
     name = "tiled_pallas"
     plane_kernel_nbr_max = True
 
+    @_tile
     def _tiled_nbr_max(self, ctx, p, mask):
         from repro.kernels.ops import tc_neighbor_max
 
         return tc_neighbor_max(ctx.tiled, p, mask)
 
+    @_tile
     def phase2_counts(self, ctx, cand, alive, col_flags=None):
         from repro.kernels.ops import tc_spmv
 
@@ -1077,6 +1174,7 @@ class TiledPallasEngine(_TiledEngine):
         )
         return out[:, 0]
 
+    @_tile
     def _nbr_max_bits(self, ctx, st, planes, mask_words):
         # The plane-scan kernel runs only when a plane stack was built (real
         # TPU — `make_bitwise_context(planes=True)`); otherwise the clz jnp
@@ -1088,6 +1186,7 @@ class TiledPallasEngine(_TiledEngine):
         signed = planes.shape[0] == _RESOLVE_PLANE_BITS
         return tc_neighbor_max_bits(ctx.tiled, planes, mask_words, signed=signed)
 
+    @_tile
     def phase2_hits(self, ctx, cand_words, alive_words, col_flags):
         from repro.kernels.ops import tc_spmv_bits
 
@@ -1115,6 +1214,7 @@ class FusedPallasEngine(TiledPallasEngine):
         # not overridden.
         return super().phase2_counts(ctx, cand, alive, col_flags)
 
+    @_tile
     def fused_step(self, ctx, cand, alive, col_flags=None):
         from repro.kernels.ops import tc_spmv_fused
 
@@ -1124,6 +1224,7 @@ class FusedPallasEngine(TiledPallasEngine):
         )
         return new_alive, mis_add
 
+    @_tile
     def fused_step_bits(self, ctx, cand_words, alive_words, col_flags):
         from repro.kernels.ops import tc_spmv_fused_bits
 

@@ -16,21 +16,20 @@ Per round:
      INSIDE the kernel epilogue by the `fused_pallas` engine.
 
 How a round executes is delegated to a `RoundEngine` (core.engine): the
-`backend` config field names an engine from the registry.  Both drivers here
-— the jitted `lax.while_loop` production entry and the python-stepped
-profiler twin — run the SAME engine round body.
+`backend` config field names an engine from the registry.  The jitted
+`lax.while_loop` driver here owns only the convergence loop; the program
+names its parts with `jax.named_scope` — `mis.init`, the round body's
+`mis.p1`/`mis.p2`/`mis.p3`, `mis.result` (DESIGN.md §14) — so a device
+trace splits by phase without a second, host-stepped driver.
 
 **Public entry points live in `repro.api`** (DESIGN.md §10): `Solver.solve`
-wraps `_tc_mis_impl`, `Solver.profile` wraps `_run_phases_impl`.  The
-module-level `tc_mis` / `run_phases` and `TCMISConfig` remain as thin
-deprecated shims for pre-API callers.
+wraps `_tc_mis_impl`.  The module-level `tc_mis` and `TCMISConfig` remain
+as thin deprecated shims for pre-API callers.
 """
 from __future__ import annotations
 
 import dataclasses
-import time
 import warnings
-from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -40,10 +39,7 @@ from repro.core.engine import (
     MISRoundState,
     get_engine,
     make_bitwise_context,
-    phase3_update,
-    phase3_update_bits,
     resolve_frontier,
-    round_increment,
 )
 from repro.core.heuristics import Priorities, make_priorities
 from repro.core.luby import MISResult
@@ -56,6 +52,7 @@ from repro.core.tiling import (
 )
 from repro.graphs.graph import Graph
 from repro.obs.rounds import TELEMETRY_COLS, TELEMETRY_FILL
+from repro.obs.trace import SCOPE_INIT, SCOPE_P3, SCOPE_RESULT
 
 # back-compat alias: the round state now lives with the engine layer
 TCMISState = MISRoundState
@@ -65,7 +62,7 @@ TCMISState = MISRoundState
 class TCMISConfig:
     """DEPRECATED algorithm-knob bundle — superseded by
     `repro.api.SolveOptions` (which adds preprocessing + placement policy).
-    Kept as the shim config for `tc_mis`/`run_phases` callers."""
+    Kept as the shim config for `tc_mis` callers."""
     heuristic: str = "h3"        # h1 | h2 | h3 | ecl
     lanes: int = 8               # RHS lane count (128 on TPU; 8 keeps CPU cheap)
     backend: str = "ref"         # engine name: segment | tiled_ref |
@@ -215,10 +212,11 @@ def _tc_mis_impl(
     seam (`repro.dyngraph.repair`): an already-converged warm state runs
     ZERO rounds — the while_loop condition fails on entry.
     """
-    engine, ctx, pri, state0 = _setup(
-        g, tiled, key, config, priorities, alive0, col_gate, member_rounds,
-        in_mis0,
-    )
+    with jax.named_scope(SCOPE_INIT):
+        engine, ctx, pri, state0 = _setup(
+            g, tiled, key, config, priorities, alive0, col_gate, member_rounds,
+            in_mis0,
+        )
 
     def cond(state: MISRoundState):
         return jnp.any(state.alive) & (jnp.max(state.rnd) < config.max_rounds)
@@ -227,7 +225,8 @@ def _tc_mis_impl(
         final = jax.lax.while_loop(
             cond, lambda s: engine.step(ctx, pri, s), state0
         )
-        return _result(final, g, tiled)
+        with jax.named_scope(SCOPE_RESULT):
+            return _result(final, g, tiled)
 
     # Telemetry run (SolveOptions.telemetry; the deprecated TCMISConfig
     # never sets it): the loop carries a fixed-shape (max_rounds, K) int32
@@ -236,9 +235,10 @@ def _tc_mis_impl(
     # caller materialises the buffer at the epilogue (RoundTrace.from_buffer).
     # The flag is static under jit, so the telemetry-off program above stays
     # the byte-exact pre-telemetry while_loop (DESIGN.md §14).
-    buf0 = jnp.full(
-        (int(config.max_rounds), TELEMETRY_COLS), TELEMETRY_FILL, jnp.int32
-    )
+    with jax.named_scope(SCOPE_INIT):
+        buf0 = jnp.full(
+            (int(config.max_rounds), TELEMETRY_COLS), TELEMETRY_FILL, jnp.int32
+        )
 
     def body(carry):
         s, buf = carry
@@ -248,148 +248,14 @@ def _tc_mis_impl(
         # currently-alive vertex has incremented in every prior round (alive
         # is monotone per vertex), so the max over vertices is the round
         # index while anything is alive — which `cond` guarantees here.
-        return new, buf.at[jnp.max(s.rnd)].set(row)
+        with jax.named_scope(SCOPE_P3):
+            return new, buf.at[jnp.max(s.rnd)].set(row)
 
     final, buf = jax.lax.while_loop(
         lambda c: cond(c[0]), body, (state0, buf0)
     )
-    return _result(final, g, tiled), buf
-
-
-# --------------------------------------------------------------------------
-# instrumented twin (python-stepped) for the Fig.-1 phase profiler
-# --------------------------------------------------------------------------
-
-def _run_phases_impl(  # repro-lint: disable=RPR010,RPR011 host-stepped profiler twin: per-phase wall timing requires sync
-    g: Graph,
-    tiled: BlockTiledGraph,
-    key: jax.Array,
-    config,
-    warmup: bool = True,
-    *,
-    priorities: Priorities | None = None,
-    alive0: jnp.ndarray | None = None,
-    col_gate: jnp.ndarray | None = None,
-    member_rounds: bool = False,
-) -> Tuple[MISResult, Dict[str, float]]:
-    """Same engine round body, stepped from python with per-phase timers.
-
-    The driver behind `repro.api.Solver.profile` — benchmarks only; the
-    jitted `_tc_mis_impl` is the production entry.
-    Returns (result, {"phase1": s, "phase2": s, "phase3": s, "rounds": k}).
-    For fused engines the ②+③ kernel pass is charged to phase2 and the
-    residual state merge to phase3.
-    """
-    engine, ctx, pri, state0 = _setup(
-        g, tiled, key, config, priorities, alive0, col_gate, member_rounds
-    )
-
-    # Hybrid runs always profile as SPLIT ②+③: fused engines demote under a
-    # partition (the in-kernel ③ cannot merge the sparse-tail hits), exactly
-    # like the production `step_hybrid` path.
-    hybrid = engine.supports_hybrid and ctx.tiled.partition is not None
-    fused_call = engine.fused and not hybrid
-    if hybrid:
-        dctx = dataclasses.replace(ctx, tiled=ctx.tiled.partition.dense)
-    if ctx.frontier == "bitwise":
-        # the packed-frontier round body, split at the same phase seams
-        if hybrid:
-            p1 = jax.jit(
-                lambda alive: engine._hybrid_candidates_bits(ctx, dctx, pri, alive)
-            )
-            p2 = jax.jit(
-                lambda cand, alive: engine._dense_hits_bits(
-                    dctx, cand, alive, engine.col_flags_bits(ctx, cand)
-                )
-                | engine._sparse_hits_bits(ctx, cand)
-            )
-            p3 = jax.jit(phase3_update_bits)
-        elif fused_call:
-            p1 = jax.jit(lambda alive: engine.phase1_candidates_bits(ctx, pri, alive))
-            p2 = jax.jit(
-                lambda cand, alive: engine.fused_step_bits(
-                    ctx, cand, alive, engine.col_flags_bits(ctx, cand)
-                )
-            )
-            p3 = jax.jit(
-                lambda state, out, inc: MISRoundState(
-                    alive=out[0], in_mis=state.in_mis | out[1], rnd=state.rnd + inc
-                )
-            )
-        else:
-            p1 = jax.jit(lambda alive: engine.phase1_candidates_bits(ctx, pri, alive))
-            p2 = jax.jit(
-                lambda cand, alive: engine.phase2_hits(
-                    ctx, cand, alive, engine.col_flags_bits(ctx, cand)
-                )
-            )
-            p3 = jax.jit(phase3_update_bits)
-    else:
-        if hybrid:
-            p1 = jax.jit(
-                lambda alive: engine._hybrid_candidates(ctx, dctx, pri, alive)
-            )
-            p2 = jax.jit(
-                lambda cand, alive: engine._dense_phase2(
-                    dctx, cand, alive, engine.col_flags(dctx, cand, alive)
-                )
-                + engine._sparse_counts(ctx, cand)
-            )
-            p3 = jax.jit(phase3_update)
-        elif fused_call:
-            p1 = jax.jit(lambda alive: engine.phase1_candidates(ctx, pri, alive))
-            p2 = jax.jit(
-                lambda cand, alive: engine.fused_step(
-                    ctx, cand, alive, engine.col_flags(ctx, cand, alive)
-                )
-            )
-            p3 = jax.jit(
-                lambda state, out, inc: MISRoundState(
-                    alive=out[0], in_mis=state.in_mis | out[1], rnd=state.rnd + inc
-                )
-            )
-        else:
-            p1 = jax.jit(lambda alive: engine.phase1_candidates(ctx, pri, alive))
-            p2 = jax.jit(
-                lambda cand, alive: engine.phase2_counts(
-                    ctx, cand, alive, engine.col_flags(ctx, cand, alive)
-                )
-            )
-            p3 = jax.jit(phase3_update)
-
-    def advance(state, cand, out):
-        inc = round_increment(state)
-        return p3(state, out, inc) if fused_call else p3(state, cand, out, inc)
-
-    if warmup:  # compile outside the timers
-        c = p1(state0.alive)
-        out = p2(c, state0.alive)
-        step = advance(state0, c, out)
-        step.alive.block_until_ready()
-
-    state = state0
-    times = {"phase1": 0.0, "phase2": 0.0, "phase3": 0.0}
-    rounds = 0
-    while bool(jnp.any(state.alive)) and rounds < config.max_rounds:
-        t0 = time.perf_counter()
-        cand = p1(state.alive)
-        cand.block_until_ready()
-        t1 = time.perf_counter()
-        out = p2(cand, state.alive)
-        jax.block_until_ready(out)
-        t2 = time.perf_counter()
-        state = advance(state, cand, out)
-        state.alive.block_until_ready()
-        t3 = time.perf_counter()
-        times["phase1"] += t1 - t0
-        times["phase2"] += t2 - t1
-        times["phase3"] += t3 - t2
-        rounds += 1
-    times["rounds"] = rounds
-    result = _result(state, g, tiled)
-    if not member_rounds:
-        result = result._replace(rounds=jnp.int32(rounds))
-    return result, times
+    with jax.named_scope(SCOPE_RESULT):
+        return _result(final, g, tiled), buf
 
 
 # --------------------------------------------------------------------------
@@ -423,28 +289,4 @@ def tc_mis(
         g, tiled, key, config,
         priorities=priorities, alive0=alive0, col_gate=col_gate,
         member_rounds=member_rounds,
-    )
-
-
-def run_phases(
-    g: Graph,
-    tiled: BlockTiledGraph,
-    key: jax.Array,
-    config: TCMISConfig = TCMISConfig(),
-    warmup: bool = True,
-    *,
-    priorities: Priorities | None = None,
-    alive0: jnp.ndarray | None = None,
-    col_gate: jnp.ndarray | None = None,
-) -> Tuple[MISResult, Dict[str, float]]:
-    """DEPRECATED: use `repro.api.Solver.profile(graph)`."""
-    warnings.warn(
-        "run_phases(...) is deprecated; use repro.api: "
-        "Solver(SolveOptions(engine=...)).profile(graph)",
-        DeprecationWarning,
-        stacklevel=2,
-    )
-    return _run_phases_impl(
-        g, tiled, key, config, warmup,
-        priorities=priorities, alive0=alive0, col_gate=col_gate,
     )

@@ -10,7 +10,7 @@ a real analysis pass (DESIGN.md §15):
     grandfathered findings, and text/JSON/SARIF emitters so CI renders
     findings as GitHub annotations;
   * an interprocedural hot-path reachability analysis: the call graph is
-    seeded at the jitted entry points (`_tc_mis_impl`, `_run_phases_impl`,
+    seeded at the jitted entry points (`_tc_mis_impl`,
     engine `step*` bodies, Pallas `*_kernel` functions, `repair_mis`) and
     the hot-path rules apply to every statically reachable function,
     regardless of which module it lives in — a host sync smuggled in via a

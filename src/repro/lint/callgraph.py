@@ -50,7 +50,6 @@ from repro.lint.analysis import (
 SEED_FUNCTIONS = frozenset(
     {
         "_tc_mis_impl",
-        "_run_phases_impl",
         "repair_mis",
         # jitted helpers reached from the warm-start / validation paths —
         # seeded so hot-path reachability covers them even when the round

@@ -49,7 +49,7 @@ LOOP_GROWING = (
     "concatenate", "append", "hstack", "vstack", "dstack",
     "column_stack", "insert", "resize",
 )
-DEPRECATED_SYMBOLS = ("tc_mis", "run_phases", "TCMISConfig")
+DEPRECATED_SYMBOLS = ("tc_mis", "TCMISConfig")
 DEPRECATED_SOURCES = ("repro.core", "repro.core.tc_mis")
 DEPRECATION_EXEMPT = ("repro.core.tc_mis", "repro.core")
 KERNEL_CALL_ALLOWLIST = frozenset(
@@ -631,8 +631,7 @@ ALL_RULES: Tuple[Rule, ...] = (
         summary=".item/.tolist/np.*/float(jnp...) in jit-reachable code",
         rationale="a host sync anywhere in the reachable set of a jitted "
                   "entry point blocks dispatch, wherever the helper lives",
-        escapes="suppress on the def line for host-stepped drivers "
-                "(e.g. the _run_phases_impl profiler twin)",
+        escapes="suppress on the def line for a host-stepped driver",
         check=_check_host_sync,
     ),
     Rule(
@@ -662,7 +661,7 @@ ALL_RULES: Tuple[Rule, ...] = (
     ),
     Rule(
         id="RPR014", name="deprecated-shim", severity=Severity.ERROR,
-        summary="internal import/call of tc_mis/run_phases/TCMISConfig",
+        summary="internal import/call of tc_mis/TCMISConfig",
         rationale="the repro.api front door owns routing, caching and "
                   "batching; shim callers bypass all three",
         escapes="the shim modules themselves (core/tc_mis.py, "

@@ -291,7 +291,7 @@ class MISService:
         # one Trace per worker step, created only when a sink is configured
         # — tr=None keeps the Solver on its untraced (pre-obs) dispatch path
         tr = (
-            Trace(f"step-{self._steps}", profiler=False)
+            Trace(f"step-{self._steps}")
             if self._trace_writer is not None else None
         )
         self._steps += 1

@@ -2,8 +2,7 @@
 
 Acceptance contract of the API redesign: for each routing target (local,
 batched, sharded) `Solver.solve` returns a bit-identical `in_mis` to the
-pre-redesign direct call on the same graph/seed; the profiler twin matches
-the jitted path for EVERY registered engine; `solve_many` never builds a
+pre-redesign direct call on the same graph/seed; `solve_many` never builds a
 bucket for nothing/a singleton; and the legacy entry points warn but keep
 working.
 """
@@ -29,7 +28,6 @@ from repro.core import (
     engine_names,
     get_engine,
     is_valid_mis,
-    run_phases,
     tc_mis,
 )
 from repro.graphs.generators import erdos_renyi, grid2d, powerlaw
@@ -224,21 +222,6 @@ def test_solve_sharded_bit_identical_to_direct_call():
 
 
 # --------------------------------------------------------------------------
-# profiler twin parity — EVERY registered engine
-# --------------------------------------------------------------------------
-
-@pytest.mark.parametrize("engine", engine_names())
-def test_profile_matches_solve_for_every_registered_engine(engine):
-    g = erdos_renyi(70, avg_deg=4.0, seed=1)
-    solver = Solver(SolveOptions(engine=engine, tile_size=16))
-    want = solver.solve(g)
-    got, times = solver.profile(g)
-    np.testing.assert_array_equal(got.in_mis, want.in_mis)
-    assert times["rounds"] == want.rounds
-    assert set(times) == {"phase1", "phase2", "phase3", "rounds"}
-
-
-# --------------------------------------------------------------------------
 # Plan + auto-T policy
 # --------------------------------------------------------------------------
 
@@ -297,8 +280,6 @@ def test_legacy_entry_points_emit_deprecation_warnings():
     tiled = build_block_tiles(g, tile_size=8)
     with pytest.warns(DeprecationWarning, match="repro.api"):
         tc_mis(g, tiled, jax.random.key(0), TCMISConfig(backend="tiled_ref"))
-    with pytest.warns(DeprecationWarning, match="profile"):
-        run_phases(g, tiled, jax.random.key(0), TCMISConfig(backend="tiled_ref"))
     with pytest.warns(DeprecationWarning, match="tiled_ref"):
         get_engine("ref")
     with pytest.warns(DeprecationWarning, match="tiled_pallas"):

@@ -13,7 +13,6 @@ from repro.core import (
     get_engine,
     is_valid_mis,
     tc_mis,
-    run_phases,
 )
 from repro.core.engine import EngineContext, block_col_flags
 from repro.core.tiling import pack_vertex_vector
@@ -159,21 +158,6 @@ def test_skip_dma_and_tiled_phase1_equivalent(backend):
     )
     assert is_valid_mis(g, got.in_mis)
     assert bool(jnp.all(got.in_mis == ref.in_mis))
-
-
-def test_run_phases_matches_while_loop_driver():
-    """The profiler twin drives the same engine round body — identical sets,
-    fused and split."""
-    g = _random_graph(200, 0.05, 3)
-    tiled = build_block_tiles(g, tile_size=32)
-    key = jax.random.key(3)
-    want = tc_mis(g, tiled, key, TCMISConfig(heuristic="h3"))
-    for backend in ("segment", "tiled_ref", "fused_pallas"):
-        res, times = run_phases(
-            g, tiled, key, TCMISConfig(heuristic="h3", backend=backend)
-        )
-        assert bool(jnp.all(res.in_mis == want.in_mis)), backend
-        assert times["rounds"] == int(want.rounds), backend
 
 
 def test_isolated_vertices_all_selected():

@@ -137,10 +137,9 @@ def test_profile_bit_parity():
     g = grid2d(8, 10)
     out = {}
     for storage in ("int8", "bitpack"):
-        r, _ = Solver(SolveOptions(
+        out[storage] = Solver(SolveOptions(
             engine="tiled_ref", tile_size=8, storage=storage,
-        )).profile(g)
-        out[storage] = r
+        )).solve(g)
     np.testing.assert_array_equal(out["int8"].in_mis, out["bitpack"].in_mis)
 
 
