@@ -38,11 +38,15 @@ def neighbor_sum_segment(g: Graph, x: jnp.ndarray) -> jnp.ndarray:
 def neighbor_max_segment(
     g: Graph, p: jnp.ndarray, mask: jnp.ndarray
 ) -> jnp.ndarray:
-    """Max_Np(v) = max_{u∈N(v), mask(u)} p(u); −inf-like where no live nbr."""
-    contrib = jnp.where(g.edge_mask & mask[g.senders], p[g.senders], _NEG)
-    return jax.ops.segment_max(contrib, g.receivers, num_segments=g.n_nodes + 1)[
-        : g.n_nodes
-    ]
+    """Max_Np(v) = max_{u∈N(v), mask(u)} p(u); −inf-like where no live nbr.
+
+    The mask folds into the priority at the vertex, so a pass gathers one
+    value per half-edge, not a mask bit and a priority.  The dummy slot
+    `n_nodes` holds `_NEG`, so sentinel half-edges contribute nothing."""
+    pm = jnp.where(jnp.append(mask, False), jnp.append(p, _NEG), _NEG)
+    return jax.ops.segment_max(
+        pm[g.senders], g.receivers, num_segments=g.n_nodes + 1
+    )[: g.n_nodes]
 
 
 def neighbor_any_segment(g: Graph, flag: jnp.ndarray) -> jnp.ndarray:
