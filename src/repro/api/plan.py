@@ -41,16 +41,23 @@ import warnings
 from collections import OrderedDict
 from typing import Callable, Optional, Tuple, Union
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core.tiling import (
     STORAGES as TILE_STORAGES,
     BlockTiledGraph,
+    TileCells,
     attach_partition,
     build_block_tiles,
+    coo_tail,
+    full_tiling,
     next_pow2,
+    partition_pays,
+    partitioned_tiling,
     rcm_ordering,
+    tiles_from_cells,
 )
 from repro.graphs.graph import Graph, from_edges
 from repro.obs.metrics import MetricsRegistry
@@ -76,14 +83,18 @@ _PLAN_STAT_KEYS = ("mem_hits", "disk_hits", "misses", "evicted_stale")
 # resolved nnz threshold) joins the meta record and, for hybrid != 'off',
 # the cache key (`|h{mode}:{threshold}` tail; 'off' keys are unchanged so
 # off-mode requests land on the v2 paths and the version check retires the
-# old layout in place).  The partition ARRAYS are deliberately not
-# persisted: `partition_tiles` is deterministic in (tiles, threshold), so
-# `_load` re-attaches from the stored policy — disk entries stay exactly as
-# big as v2 and can never desynchronise from their tiles.
-_PLAN_VERSION = 3
+# old layout in place).
+#
+# v4: a partitioned plan holds no full tile list (DESIGN.md §16), so its
+# entry persists the partition itself: `tiles`/`tile_rows`/`tile_cols`/
+# `row_starts` are the dense sub-tiling's, `sp_rows`/`sp_cols` the COO
+# tail, and the meta record gains the dense tile count and the tail's real
+# entry count.  An unpartitioned entry keeps the v3 arrays (dense count
+# -1, no tail arrays).
+_PLAN_VERSION = 4
 # n_nodes, n_edges, n_tiles, tile_size, nbr, nbc, version, storage,
-# hybrid mode, hybrid threshold
-_META_LEN = 10
+# hybrid mode, hybrid threshold, dense tiles (-1: unpartitioned), tail nnz
+_META_LEN = 12
 
 # partition policy axis, in meta-index order (0 = off keeps the v2 keys)
 HYBRID_MODES = ("off", "auto", "forced")
@@ -246,6 +257,16 @@ class Plan:
     def storage(self) -> str:
         """Tile storage format this plan was built with (DESIGN.md §11)."""
         return self.tiled.storage
+
+    @property
+    def device_bytes(self) -> int:
+        """Bytes of the device arrays the round program takes: the edge
+        list and every array of the tiling (for a partitioned plan, the
+        dense sub-tiling and the COO tail; no full tile list)."""
+        return sum(
+            int(x.size) * x.dtype.itemsize
+            for x in jax.tree_util.tree_leaves((self.g, self.tiled))
+        )
 
     @functools.cached_property
     def graph_key(self) -> str:
@@ -416,6 +437,7 @@ def patch_plan(plan: Plan, delta) -> Plan:
     mapped = delta if plan.inv is None else delta.mapped(plan.inv)
     g2 = apply_graph_delta(plan.g, mapped)
     tiled2 = apply_tiled_delta(plan.tiled, mapped)
+    part = tiled2.partition
     # drift telemetry (DESIGN.md §17): this is the ONE funnel every actual
     # patch event passes through — cache mem/disk hits replay a patch that
     # was recorded when it happened, so each epoch counts exactly once.
@@ -440,11 +462,12 @@ def patch_plan(plan: Plan, delta) -> Plan:
         # but only the PLAN knows the auto policy: a delta can push the
         # graph across the auto gate in either direction, so re-run it
         # (forced/off plans need nothing — present stays present, absent
-        # stays absent)
-        tiled2 = attach_partition(
-            dataclasses.replace(tiled2, partition=None),
-            mode="auto", threshold=plan.hybrid_threshold,
-        )
+        # stays absent).  A partition carries its own tile counts.
+        if part is None:
+            tiled2 = attach_partition(
+                tiled2, mode="auto", threshold=plan.hybrid_threshold)
+        elif not partition_pays("auto", tiled2.n_tiles, part.n_sparse_tiles):
+            tiled2 = full_tiling(tiled2)
     return dataclasses.replace(
         plan,
         g=g2,
@@ -467,8 +490,13 @@ def build_plan(
     """The cache-miss path: (optional) RCM + BSR tiling + (optional) tile
     partition, no caching.  `hybrid_threshold` arrives already resolved
     (`resolve_hybrid_threshold`) — this function never invents policy.
-    The tiling and the partition run under `plan.tiles` / `plan.partition`
-    spans."""
+
+    With the hybrid policy on, the build is sized by edges: the nonzero
+    cells and per-tile counts come first (`TileCells`), then only the
+    tiles at or above the threshold are packed, under `plan.tiles`, and
+    the rest become the COO tail straight from their cells, under
+    `plan.tail` — no full tile list is built.  Where 'auto' declines, the
+    same cells give the full tiling, as `build_block_tiles` would."""
     perm = inv = None
     if reorder == "rcm":
         perm = np.asarray(rcm_ordering(g))
@@ -479,13 +507,26 @@ def build_plan(
         g = from_edges(inv[s], inv[r], g.n_nodes)
     elif reorder is not None:
         raise ValueError(f"unknown reorder {reorder!r} (None or 'rcm')")
+    thr = int(hybrid_threshold)
+    dense = None
     with trace_span(trace, "plan.tiles"):
-        tiled = build_block_tiles(g, tile_size=tile_size, storage=storage)
-    if hybrid != "off":
-        with trace_span(trace, "plan.partition"):
-            tiled = attach_partition(
-                tiled, mode=hybrid, threshold=int(hybrid_threshold)
+        if hybrid == "off":
+            tiled = build_block_tiles(g, tile_size=tile_size, storage=storage)
+        else:
+            cells = TileCells.from_edges(
+                np.asarray(g.senders)[: g.n_edges],
+                np.asarray(g.receivers)[: g.n_edges],
+                tile_size, -(-g.n_nodes // int(tile_size)),
             )
+            dense = cells.counts >= thr
+            n_sparse = cells.n_tiles - int(np.count_nonzero(dense))
+            if not partition_pays(hybrid, cells.n_tiles, n_sparse):
+                dense = None
+            tiled = tiles_from_cells(cells, g.n_nodes, storage, select=dense)
+    if dense is not None:
+        with trace_span(trace, "plan.tail"):
+            tiled = partitioned_tiling(
+                tiled, coo_tail(cells, dense, tiled.n_padded), thr, n_sparse)
     from repro.dyngraph.drift import tile_occupancy
 
     return Plan(g=g, tiled=tiled, key=key, perm=perm, inv=inv,
@@ -571,8 +612,8 @@ class PlanCache:
         """Return (plan, status) with status ∈ {'mem', 'disk', 'built'}.
 
         A build (status 'built') records its stages into `trace`:
-        `plan.key` (the content hash), `plan.tiles`, `plan.partition`; a
-        hit records none, its cost being the hash alone."""
+        `plan.key` (the content hash), `plan.tiles`, `plan.tail` (hybrid
+        plans); a hit records none, its cost being the hash alone."""
         T = self.tile_size if tile_size is None else int(tile_size)
         ro = self.reorder if reorder is None else reorder
         st = resolve_storage(
@@ -696,23 +737,31 @@ class PlanCache:
 
     def _store(self, plan: Plan) -> None:
         g, t = plan.g, plan.tiled
+        part = t.partition
         # tiles persist AS STORED — a bitpack plan's disk entry is the same
-        # 8× smaller than its int8 twin as its HBM copy
+        # 8× smaller than its int8 twin as its HBM copy; a partitioned
+        # plan's tile arrays are its dense sub-tiling's
+        d = t if part is None else part.dense
         arrays = dict(
             senders=np.asarray(g.senders)[: g.n_edges],
             receivers=np.asarray(g.receivers)[: g.n_edges],
-            tiles=np.asarray(t.tiles),
-            tile_rows=np.asarray(t.tile_rows),
-            tile_cols=np.asarray(t.tile_cols),
-            row_starts=np.asarray(t.row_starts),
+            tiles=np.asarray(d.tiles),
+            tile_rows=np.asarray(d.tile_rows),
+            tile_cols=np.asarray(d.tile_cols),
+            row_starts=np.asarray(d.row_starts),
             meta=np.asarray(
                 [g.n_nodes, g.n_edges, t.n_tiles, t.tile_size,
                  t.n_block_rows, t.n_block_cols,
                  _PLAN_VERSION, TILE_STORAGES.index(t.storage),
-                 HYBRID_MODES.index(plan.hybrid), plan.hybrid_threshold],
+                 HYBRID_MODES.index(plan.hybrid), plan.hybrid_threshold,
+                 -1 if part is None else part.n_dense_tiles,
+                 0 if part is None else part.sp_nnz],
                 dtype=np.int64,
             ),
         )
+        if part is not None:
+            arrays["sp_rows"] = np.asarray(part.sp_rows)
+            arrays["sp_cols"] = np.asarray(part.sp_cols)
         if plan.perm is not None:
             arrays["perm"] = plan.perm
         if plan.epoch:
@@ -753,7 +802,7 @@ class PlanCache:
         try:
             with np.load(path) as z:
                 meta = z["meta"]
-                if meta.shape[0] < _META_LEN:
+                if meta.shape[0] <= 6:
                     self._evict_stale(path, "pre-versioned entry (v1 layout)")
                     return None
                 if int(meta[6]) != _PLAN_VERSION:
@@ -765,6 +814,7 @@ class PlanCache:
                 storage = TILE_STORAGES[int(meta[7])]
                 hybrid = HYBRID_MODES[int(meta[8])]
                 hybrid_threshold = int(meta[9])
+                n_dense, sp_nnz = int(meta[10]), int(meta[11])
                 g = Graph(
                     senders=jnp.asarray(z["senders"]),
                     receivers=jnp.asarray(z["receivers"]),
@@ -776,28 +826,27 @@ class PlanCache:
                     tile_rows=jnp.asarray(z["tile_rows"]),
                     tile_cols=jnp.asarray(z["tile_cols"]),
                     row_starts=jnp.asarray(z["row_starts"]),
-                    n_tiles=n_tiles,
+                    n_tiles=n_tiles if n_dense < 0 else n_dense,
                     n_nodes=n_nodes,
                     tile_size=tile_size,
                     n_block_rows=nbr,
                     n_block_cols=nbc,
                     storage=storage,
                 )
+                if n_dense >= 0:
+                    tiled = partitioned_tiling(
+                        tiled, (z["sp_rows"], z["sp_cols"], sp_nnz),
+                        hybrid_threshold, n_tiles - n_dense,
+                    )
                 perm = np.asarray(z["perm"]) if "perm" in z.files else None
                 epoch = int(z["epoch"][0]) if "epoch" in z.files else 0
-            if hybrid != "off":
-                # the partition is policy, not payload: deterministic in
-                # (tiles, threshold), so re-attach instead of persisting
-                tiled = attach_partition(
-                    tiled, mode=hybrid, threshold=hybrid_threshold
-                )
             inv = None
             if perm is not None:
                 inv = np.empty_like(perm)
                 inv[perm] = np.arange(n_nodes)
             from repro.dyngraph.drift import tile_occupancy
 
-            # occupancy0 is not persisted (the npz layout is frozen at v3):
+            # occupancy0 is not persisted (the npz layout is frozen at v4):
             # a disk-loaded plan re-baselines locality decay at its load
             # state — exact for epoch-0 entries, a documented reset for
             # patched lineages (DESIGN.md §17)

@@ -48,6 +48,7 @@ from repro.core.engine import get_engine, resolve_frontier
 from repro.core.heuristics import make_priorities
 from repro.core.luby import MISResult
 from repro.core.tc_mis import _tc_mis_impl
+from repro.core.tiling import full_tiling
 from repro.graphs.graph import Graph
 from repro.obs.metrics import MetricsRegistry
 from repro.obs.rounds import RoundTrace
@@ -175,7 +176,8 @@ class Solver:
         estimated tile payload crosses the threshold, DESIGN.md §11).
 
         Runs under a `solver.plan` span; a cache miss records the build's
-        stages inside it (`plan.key`, `plan.tiles`, `plan.partition`)."""
+        stages inside it (`plan.key`, `plan.tiles`, `plan.tail`).  Sets the
+        `plan.*` gauges (`_note_plan`) for the plan it returns."""
         with trace_span(trace, "solver.plan"):
             return self._plan(graph, trace)
 
@@ -199,7 +201,23 @@ class Solver:
             hybrid=hybrid, hybrid_threshold=self.options.hybrid_threshold,
             trace=trace,
         )
+        self._note_plan(plan)
         return plan
+
+    def _note_plan(self, plan: Plan) -> None:
+        """Gauges of a plan's shape: `plan.dense_tiles` (tiles the tile
+        schedule walks: the dense sub-tiling's, or every tile without a
+        partition), `plan.tail_entries` / `plan.tail_capacity` (the COO
+        tail's real and padded entries, 0 without one) and
+        `plan.device_bytes` (`Plan.device_bytes`)."""
+        part = plan.tiled.partition
+        m = self.metrics
+        m.gauge("plan.dense_tiles").set(
+            plan.tiled.n_tiles if part is None else part.n_dense_tiles)
+        m.gauge("plan.tail_entries").set(0 if part is None else part.sp_nnz)
+        m.gauge("plan.tail_capacity").set(
+            0 if part is None else int(part.sp_rows.shape[0]))
+        m.gauge("plan.device_bytes").set(plan.device_bytes)
 
     def request_key(self, plan: Plan) -> jax.Array:
         """The content-derived per-graph key batched members are solved
@@ -516,9 +534,11 @@ class Solver:
         )
         if batch_size > 1:
             meta["batch_size"] = batch_size
+        # the tiles the schedule walks: a partition's dense sub-tiling
+        walked = tiled if tiled.partition is None else tiled.partition.dense
         rt = RoundTrace.from_buffer(
             np.asarray(buf), rounds,
-            tiles_total=int(tiled.tile_cols.shape[0]), meta=meta,
+            tiles_total=int(walked.tile_cols.shape[0]), meta=meta,
         )
         return result, rt
 
@@ -663,9 +683,9 @@ class Solver:
                     axis_types=(jax.sharding.AxisType.Auto,),
                 )
                 # documented dense-only fallback (DESIGN.md §16): the
-                # shard_map loop has no sparse-tail seam, so the partition
-                # is stripped rather than half-honoured
-                tiled_full = dataclasses.replace(plan.tiled, partition=None)
+                # shard_map loop has no sparse-tail seam, so it takes the
+                # full tile list, rebuilt from the partition
+                tiled_full = full_tiling(plan.tiled)
                 sharded = shard_tiled(tiled_full, n_shards=n_dev)
                 run = build_distributed_mis(sharded, mesh, DistConfig(
                     max_rounds=self.options.max_rounds,

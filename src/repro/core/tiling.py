@@ -28,6 +28,7 @@ the tile dtype, so raw-array call sites stay storage-polymorphic.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional, Tuple
 
 import jax
@@ -174,10 +175,13 @@ class BlockTiledGraph:
                   format; raw-array consumers detect it from the dtype).
       partition:  optional hybrid routing split (DESIGN.md §16): a
                   `TilePartition` whose compacted dense sub-tiling and
-                  COO sparse tail the hybrid engines dispatch instead of
-                  `tiles`.  The FULL tile list above stays authoritative —
-                  repair, retiling and sharding operate on it; the
-                  partition is a derived, rebuildable view.
+                  COO sparse tail the hybrid engines dispatch.  A
+                  partitioned tiling holds NO full tile list: `tiles`,
+                  `tile_rows`, `tile_cols` and `row_starts` are empty
+                  (length 0) and `n_tiles` counts the real tiles of both
+                  halves, so nothing of the full list reaches the device.
+                  `tiling_cells` / `full_tiling` recover the full list
+                  from the partition where a caller needs it.
     """
     tiles: jnp.ndarray
     tile_rows: jnp.ndarray
@@ -202,7 +206,10 @@ class BlockTiledGraph:
 
     def nnz(self) -> int:
         """Edge count over stored tiles, computed ON DEVICE — only the
-        scalar crosses to host (bitpack counts bits via popcount)."""
+        scalar crosses to host (bitpack counts bits via popcount).  A
+        partitioned tiling counts its dense sub-tiling and its tail."""
+        if self.partition is not None:
+            return self.partition.dense.nnz() + self.partition.sp_nnz
         if self.n_tiles == 0:
             return 0
         t = self.tiles[: self.n_tiles]
@@ -221,42 +228,48 @@ class BlockTiledGraph:
 
     def tile_payload_bytes(self) -> int:
         """Bytes of stored tile payload alone (the HBM/DMA term the storage
-        axis shrinks 8×)."""
-        return self.tiles.size * self.tiles.dtype.itemsize
+        axis shrinks 8×): a partitioned tiling stores only its dense
+        sub-tiling's."""
+        own = self.tiles.size * self.tiles.dtype.itemsize
+        if self.partition is not None:
+            own += self.partition.dense.tile_payload_bytes()
+        return own
 
     def memory_bytes(self) -> int:
-        """HBM footprint of the tiled representation (payload + indices)."""
-        return (
-            self.tile_payload_bytes()
-            + self.tile_rows.size * 4
-            + self.tile_cols.size * 4
-            + self.row_starts.size * 4
+        """HBM footprint of the tiled representation: every device array
+        it holds (payload + indices, and a partition's dense sub-tiling and
+        COO tail)."""
+        return sum(
+            int(x.size) * x.dtype.itemsize
+            for x in jax.tree_util.tree_leaves(self)
         )
 
     def to_storage(self, storage: str) -> "BlockTiledGraph":
-        """Convert between tile storage formats (host-side, exact)."""
+        """Convert between tile storage formats (host-side, exact).  A
+        partitioned tiling converts its dense sub-tiling; the tail holds
+        vertex ids, not tiles, and the threshold stays as resolved."""
         if storage not in STORAGES:
             raise ValueError(
                 f"unknown storage {storage!r}; valid: {STORAGES}"
             )
         if storage == self.storage:
             return self
+        if self.partition is not None:
+            part = self.partition
+            dense = part.dense.to_storage(storage)
+            return dataclasses.replace(
+                self,
+                tiles=jnp.zeros((0,) + dense.tiles.shape[1:], dense.tiles.dtype),
+                storage=storage,
+                partition=dataclasses.replace(part, dense=dense),
+            )
         if storage == "bitpack":
             tiles = jnp.asarray(pack_tile_bits(np.asarray(self.tiles)))
         else:
             tiles = jnp.asarray(
                 np.asarray(unpack_tile_bits(self.tiles, self.tile_size))
             )
-        out = dataclasses.replace(
-            self, tiles=tiles, storage=storage, partition=None
-        )
-        if self.partition is not None:
-            # the partition's dense sub-tiling must share the new storage —
-            # rebuild it (deterministic, so bit-identical up to format)
-            out = dataclasses.replace(
-                out, partition=partition_tiles(out, self.partition.threshold)
-            )
-        return out
+        return dataclasses.replace(self, tiles=tiles, storage=storage)
 
 
 @jax.tree_util.register_dataclass
@@ -298,7 +311,8 @@ class TilePartition:
 def tile_nnz(tiled: BlockTiledGraph) -> np.ndarray:
     """Per-tile nnz over the stored tile list, computed ON DEVICE — one
     (n_tiles_pad,) int32 transfer (bitpack counts bits via popcount;
-    padding tiles are all-zero so their entries read 0)."""
+    padding tiles are all-zero so their entries read 0).  A partitioned
+    tiling stores no full list: count `full_tiling(tiled)` instead."""
     t = tiled.tiles
     if tiled.storage == "bitpack":
         counts = jnp.sum(
@@ -321,13 +335,278 @@ def _host_unpack_tile_bits(packed: np.ndarray, tile_size: int) -> np.ndarray:
     return (full[..., : int(tile_size)] != 0).astype(np.int8)
 
 
+# --------------------------------------------------------------------------
+# the edge-sized build (DESIGN.md §16): everything derives from the sorted
+# nonzero CELLS of the adjacency, one int64 key per half-edge, so the host
+# work and memory are sized by entries; only tiles that are actually stored
+# as tiles ever get a T×T (or T×W) payload.
+# --------------------------------------------------------------------------
+
+
+def cell_keys(rows, cols, tile_size: int, n_block_cols: int) -> np.ndarray:
+    """int64 cell key of each (row, col) vertex pair: `(tile_key · T +
+    row % T) · T + col % T`, `tile_key = (row // T) · nb + col // T`."""
+    T = int(tile_size)
+    r = np.asarray(rows).astype(np.int64)
+    c = np.asarray(cols).astype(np.int64)
+    return ((r // T) * n_block_cols + c // T) * (T * T) + (r % T) * T + c % T
+
+
+@dataclasses.dataclass(frozen=True)
+class TileCells:
+    """The nonzero cells of a tiled adjacency, host-side, sized by entries.
+
+    `cells` holds one sorted, unique int64 key per nonzero cell
+    (`cell_keys`) — so the order is the BSR tile order, then row, then
+    column, within each tile.  `first[i]` is the
+    index of tile i's first cell; tiles with no cell do not exist here.
+    """
+    cells: np.ndarray
+    first: np.ndarray
+    tile_size: int
+    n_block_cols: int
+
+    @classmethod
+    def from_cells(cls, cells: np.ndarray, tile_size: int,
+                   n_block_cols: int) -> "TileCells":
+        """From sorted unique cell keys."""
+        T2 = int(tile_size) * int(tile_size)
+        tk = cells // T2
+        first = np.flatnonzero(np.concatenate([[True], tk[1:] != tk[:-1]])) \
+            if cells.size else np.zeros(0, np.int64)
+        return cls(cells, first, int(tile_size), int(n_block_cols))
+
+    @classmethod
+    def from_edges(cls, senders: np.ndarray, receivers: np.ndarray,
+                   tile_size: int, n_block_cols: int) -> "TileCells":
+        """Cells of half-edges (row = sender, column = receiver);
+        duplicates collapse to one cell, as in a 0/1 tile."""
+        keys = cell_keys(senders, receivers, tile_size, n_block_cols)
+        return cls.from_cells(np.unique(keys), tile_size, n_block_cols)
+
+    @property
+    def n_tiles(self) -> int:
+        return int(self.first.shape[0])
+
+    @functools.cached_property
+    def counts(self) -> np.ndarray:
+        """(n_tiles,) int64 — nonzero cells per tile."""
+        return np.diff(np.append(self.first, self.cells.shape[0]))
+
+    @functools.cached_property
+    def keys(self) -> np.ndarray:
+        """(n_tiles,) int64 — tile keys `block_row · nb + block_col`."""
+        T = self.tile_size
+        return self.cells[self.first] // (T * T)
+
+
+def _checked_build(tile_size: int, storage: str) -> int:
+    T = int(tile_size)
+    if T < 8 or (T & (T - 1)):
+        raise ValueError(f"tile_size must be a power of two >= 8, got {T}")
+    if storage not in STORAGES:
+        raise ValueError(f"unknown storage {storage!r}; valid: {STORAGES}")
+    return T
+
+
+def _pack_cells(cells: np.ndarray, tidx: np.ndarray, n_out: int,
+                tile_size: int, storage: str) -> np.ndarray:
+    """Payload of `n_out` tiles from sorted unique cells, `tidx` the output
+    tile of each cell (non-decreasing).  Bitpack words are OR-reduced
+    straight from the cells: no (n, T, T) int8 intermediate."""
+    T = int(tile_size)
+    loc = cells % (T * T)
+    rl, cl = loc // T, loc % T
+    if storage == "int8":
+        out = np.zeros((n_out, T, T), np.int8)
+        out[tidx, rl, cl] = 1
+        return out
+    W = packed_words(T)
+    out = np.zeros((n_out, T, W), np.uint32)
+    if cells.size:
+        # cells sorted ⇒ word indices non-decreasing; disjoint bits ⇒ OR
+        word = (tidx * T + rl) * W + cl // _BITS
+        bits = np.left_shift(np.uint32(1), (cl % _BITS).astype(np.uint32))
+        starts = np.flatnonzero(np.concatenate([[True], word[1:] != word[:-1]]))
+        out.reshape(-1)[word[starts]] = np.bitwise_or.reduceat(bits, starts)
+    return out
+
+
+def tiles_from_cells(
+    tc: TileCells,
+    n_nodes: int,
+    storage: str,
+    *,
+    select: Optional[np.ndarray] = None,
+    pad_tiles_to: Optional[int] = None,
+) -> BlockTiledGraph:
+    """A BSR tiling of the tiles `select`ed (bool per tile; None = all),
+    in their BSR order, padded by `padded_tile_count` — the full build
+    when `select` is None, the compacted dense sub-tiling of a partition
+    otherwise."""
+    T, nb = _checked_build(tc.tile_size, storage), tc.n_block_cols
+    counts = tc.counts
+    if select is None:
+        keys, cells, sel_counts = tc.keys, tc.cells, counts
+    else:
+        keys = tc.keys[select]
+        cells = tc.cells[np.repeat(select, counts)]
+        sel_counts = counts[select]
+    n = int(keys.shape[0])
+    tidx = np.repeat(np.arange(n, dtype=np.int64), sel_counts)
+    tiles = _pack_cells(cells, tidx, max(n, 1), T, storage)
+    rows = (keys // nb).astype(np.int32)
+    cols = (keys % nb).astype(np.int32)
+    if n == 0:   # an empty tiling still stores one zero tile at (0, 0)
+        rows = np.zeros(1, np.int32)
+        cols = np.zeros(1, np.int32)
+    row_starts = np.zeros(nb + 1, dtype=np.int32)
+    np.cumsum(np.bincount(rows[:n], minlength=nb), out=row_starts[1:])
+
+    # pad: zero tiles pinned to the last real block-row (monotone, no-op adds)
+    stored = tiles.shape[0]
+    target = padded_tile_count(n, pad_tiles_to)
+    if target > stored:
+        last_row = rows[n - 1] if n else np.int32(0)
+        tiles = np.concatenate(
+            [tiles, np.zeros((target - stored,) + tiles.shape[1:], tiles.dtype)]
+        )
+        rows = np.concatenate(
+            [rows, np.full(target - stored, last_row, np.int32)])
+        cols = np.concatenate([cols, np.zeros(target - stored, np.int32)])
+    return BlockTiledGraph(
+        tiles=jnp.asarray(tiles),
+        tile_rows=jnp.asarray(rows),
+        tile_cols=jnp.asarray(cols),
+        row_starts=jnp.asarray(row_starts),
+        n_tiles=n,
+        n_nodes=int(n_nodes),
+        tile_size=T,
+        n_block_rows=nb,
+        n_block_cols=nb,
+        storage=storage,
+    )
+
+
+def coo_tail(
+    tc: TileCells, dense: np.ndarray, n_padded: int
+) -> Tuple[np.ndarray, np.ndarray, int]:
+    """(sp_rows, sp_cols, sp_nnz): the cells of the tiles not `dense` as a
+    COO list in GLOBAL padded vertex ids, in cell order (tile by tile, then
+    row, then column), sentinel-padded (`n_padded`) to a power of two."""
+    T, nb = tc.tile_size, tc.n_block_cols
+    sp = tc.cells[~np.repeat(dense, tc.counts)]
+    tk, loc = sp // (T * T), sp % (T * T)
+    sp_nnz = int(sp.shape[0])
+    cap = next_pow2(max(sp_nnz, 8))
+    sp_rows = np.full(cap, n_padded, np.int32)
+    sp_cols = np.full(cap, n_padded, np.int32)
+    sp_rows[:sp_nnz] = (tk // nb) * T + loc // T
+    sp_cols[:sp_nnz] = (tk % nb) * T + loc % T
+    return sp_rows, sp_cols, sp_nnz
+
+
+def partitioned_tiling(
+    dense: BlockTiledGraph,
+    tail: Tuple[np.ndarray, np.ndarray, int],
+    threshold: int,
+    n_sparse_tiles: int,
+) -> BlockTiledGraph:
+    """The partitioned tiling the hybrid engines take: `dense` sub-tiling
+    and COO `tail` (`coo_tail`), around an empty full list."""
+    sp_rows, sp_cols, sp_nnz = tail
+    part = TilePartition(
+        dense=dense,
+        sp_rows=jnp.asarray(sp_rows),
+        sp_cols=jnp.asarray(sp_cols),
+        threshold=int(threshold),
+        n_dense_tiles=dense.n_tiles,
+        n_sparse_tiles=int(n_sparse_tiles),
+        sp_nnz=int(sp_nnz),
+    )
+    return dataclasses.replace(
+        dense,
+        tiles=jnp.zeros((0,) + dense.tiles.shape[1:], dense.tiles.dtype),
+        tile_rows=jnp.zeros(0, jnp.int32),
+        tile_cols=jnp.zeros(0, jnp.int32),
+        row_starts=jnp.zeros(0, jnp.int32),
+        n_tiles=dense.n_tiles + int(n_sparse_tiles),
+        partition=part,
+    )
+
+
+def partition_from_cells(
+    tc: TileCells, threshold: int, n_nodes: int, storage: str
+) -> BlockTiledGraph:
+    """Partitioned tiling straight from cells: pack the tiles at or above
+    `threshold`, lower the rest to the COO tail."""
+    dense = tc.counts >= int(threshold)
+    sub = tiles_from_cells(tc, n_nodes, storage, select=dense)
+    tail = coo_tail(tc, dense, sub.n_padded)
+    return partitioned_tiling(
+        sub, tail, threshold, tc.n_tiles - sub.n_tiles)
+
+
+def partition_pays(mode: str, n_tiles: int, n_sparse: int) -> bool:
+    """The hybrid policy on tile counts: 'forced' always partitions, 'auto'
+    iff there are ≥ HYBRID_AUTO_MIN_TILES non-empty tiles and the
+    sub-threshold tail is ≥ HYBRID_AUTO_MIN_SPARSE_FRAC of them, 'off'
+    never."""
+    if mode not in ("auto", "off", "forced"):
+        raise ValueError(f"unknown hybrid mode {mode!r}; valid: auto|off|forced")
+    if mode != "auto":
+        return mode == "forced"
+    return (
+        n_tiles >= HYBRID_AUTO_MIN_TILES
+        and n_sparse > 0
+        and n_sparse >= HYBRID_AUTO_MIN_SPARSE_FRAC * n_tiles
+    )
+
+
+def _stored_cells(tiled: BlockTiledGraph) -> np.ndarray:
+    """Cell keys of an unpartitioned tiling's stored tiles (host unpack)."""
+    T, nb = tiled.tile_size, tiled.n_block_cols
+    tiles = np.asarray(tiled.tiles)[: tiled.n_tiles]
+    if tiled.storage == "bitpack":
+        tiles = _host_unpack_tile_bits(tiles, T)
+    ti, rl, cl = np.nonzero(tiles)
+    rows = np.asarray(tiled.tile_rows)[ti].astype(np.int64) * T + rl
+    cols = np.asarray(tiled.tile_cols)[ti].astype(np.int64) * T + cl
+    return cell_keys(rows, cols, T, nb)
+
+
+def tiling_cells(tiled: BlockTiledGraph) -> TileCells:
+    """The cells of any tiling: its stored tiles, or a partition's dense
+    tiles and tail.  Unpacks only what is stored as tiles."""
+    T, nb = tiled.tile_size, tiled.n_block_cols
+    part = tiled.partition
+    if part is None:
+        cells = _stored_cells(tiled)
+    else:
+        tail = cell_keys(np.asarray(part.sp_rows)[: part.sp_nnz],
+                         np.asarray(part.sp_cols)[: part.sp_nnz], T, nb)
+        cells = np.concatenate([_stored_cells(part.dense), tail])
+    return TileCells.from_cells(np.unique(cells), T, nb)
+
+
+def full_tiling(tiled: BlockTiledGraph) -> BlockTiledGraph:
+    """The full BSR tile list of a tiling — itself when unpartitioned,
+    rebuilt from a partition's cells otherwise (for the routes that need
+    every tile: the sharded loop, a mixed batch)."""
+    if tiled.partition is None:
+        return tiled
+    return tiles_from_cells(tiling_cells(tiled), tiled.n_nodes, tiled.storage)
+
+
 def partition_tiles(
     tiled: BlockTiledGraph,
     threshold: int,
     *,
     nnz: np.ndarray | None = None,
 ) -> TilePartition:
-    """Classify tiles by nnz and build the hybrid split (host-side, numpy).
+    """Classify a FULL tile list's tiles by nnz and build the hybrid split
+    (host-side, numpy) — the tile-list route; plans build theirs from
+    edges (`TileCells`, `partition_from_cells`), with the same result.
 
     Deterministic in (tiles, threshold): rebuilding after a delta or a
     storage conversion yields bit-identical partitions, which keeps the
@@ -426,14 +705,15 @@ def attach_partition(
               HYBRID_AUTO_MIN_SPARSE_FRAC of them.
 
     `threshold` defaults to the roofline break-even
-    (`repro.perf.hybrid_density_threshold`).
+    (`repro.perf.hybrid_density_threshold`).  The result holds either the
+    full tile list or a partition, never both; a partitioned input is
+    re-decided from its full list (`full_tiling`).
     """
-    if mode == "off":
-        if tiled.partition is None:
-            return tiled
-        return dataclasses.replace(tiled, partition=None)
-    if mode not in ("auto", "forced"):
+    if mode not in ("auto", "off", "forced"):
         raise ValueError(f"unknown hybrid mode {mode!r}; valid: auto|off|forced")
+    tiled = full_tiling(tiled)
+    if mode == "off":
+        return tiled
     if threshold is None:
         from repro.perf.roofline import hybrid_density_threshold
 
@@ -443,16 +723,13 @@ def attach_partition(
     real = nnz[: tiled.n_tiles]
     nonempty = int(np.count_nonzero(real))
     n_sparse = int(np.count_nonzero((real > 0) & (real < thr)))
-    if mode == "auto" and (
-        nonempty < HYBRID_AUTO_MIN_TILES
-        or n_sparse == 0
-        or n_sparse < HYBRID_AUTO_MIN_SPARSE_FRAC * nonempty
-    ):
-        if tiled.partition is None:
-            return tiled
-        return dataclasses.replace(tiled, partition=None)
+    if not partition_pays(mode, nonempty, n_sparse):
+        return tiled
     part = partition_tiles(tiled, thr, nnz=nnz)
-    return dataclasses.replace(tiled, partition=part)
+    return partitioned_tiling(
+        part.dense, (part.sp_rows, part.sp_cols, part.sp_nnz), thr,
+        part.n_sparse_tiles,
+    )
 
 
 def rcm_ordering(g: Graph) -> np.ndarray:
@@ -486,22 +763,18 @@ def build_block_tiles(
 
     Steps (mirrors the paper's Listing 1 preprocessing):
       1. (optional) RCM locality reordering — beyond-paper, see rcm_ordering,
-      2. map each half-edge (u, v) to tile key (u//T, v//T),
-      3. unique keys, sorted row-major → tile index per edge,
-      4. scatter edges into dense tiles,
-      5. pad the tile list so shapes are static/shardable,
-      6. (storage='bitpack') pack each tile's columns into uint32 words.
+      2. map each half-edge (u, v) to its cell key, tile key (u//T, v//T)
+         first (`TileCells`), unique and sorted: row-major tile order,
+      3. pack each tile's cells (int8 bytes, or uint32 words for
+         storage='bitpack', straight from the cells),
+      4. pad the tile list so shapes are static/shardable.
 
     NOTE with reorder='rcm' the returned tiling indexes PERMUTED vertex ids;
     callers must map priorities/results through the same permutation (the
     MIS solution set is permutation-equivariant, so validity is unaffected —
     tests/test_tiling.py::test_rcm_mis_roundtrip).
     """
-    T = int(tile_size)
-    if T < 8 or (T & (T - 1)):
-        raise ValueError(f"tile_size must be a power of two >= 8, got {T}")
-    if storage not in STORAGES:
-        raise ValueError(f"unknown storage {storage!r}; valid: {STORAGES}")
+    T = _checked_build(tile_size, storage)
     s = np.asarray(g.senders)[: g.n_edges].astype(np.int64)
     r = np.asarray(g.receivers)[: g.n_edges].astype(np.int64)
     if reorder == "rcm":
@@ -509,56 +782,10 @@ def build_block_tiles(
         inv = np.empty_like(perm)
         inv[perm] = np.arange(g.n_nodes)
         s, r = inv[s], inv[r]
-        order = np.lexsort((r, s))
-        s, r = s[order], r[order]
     nb = -(-g.n_nodes // T)  # ceil
-    tr, tc = s // T, r // T
-    key = tr * nb + tc
-    uniq, inv = np.unique(key, return_inverse=True)
-    n_tiles = int(uniq.shape[0])
-
-    tiles = np.zeros((max(n_tiles, 1), T, T), dtype=np.int8)
-    tiles[inv, s % T, r % T] = 1
-    tile_rows = (uniq // nb).astype(np.int32)
-    tile_cols = (uniq % nb).astype(np.int32)
-    if n_tiles == 0:
-        tile_rows = np.zeros(1, dtype=np.int32)
-        tile_cols = np.zeros(1, dtype=np.int32)
-        n_tiles = 0
-
-    # row_starts: CSR over block-rows (tiles are already row-major sorted)
-    counts = np.bincount(tile_rows[: max(n_tiles, 1)] if n_tiles else [], minlength=nb)
-    row_starts = np.zeros(nb + 1, dtype=np.int32)
-    np.cumsum(counts, out=row_starts[1:])
-
-    # pad: zero tiles pinned to the last real block-row (monotone, no-op adds)
-    stored = tiles.shape[0]
-    target = padded_tile_count(n_tiles, pad_tiles_to)
-    if target > stored:
-        last_row = tile_rows[-1] if n_tiles else 0
-        tiles = np.concatenate(
-            [tiles, np.zeros((target - stored, T, T), dtype=np.int8)], axis=0
-        )
-        tile_rows = np.concatenate(
-            [tile_rows, np.full(target - stored, last_row, dtype=np.int32)]
-        )
-        tile_cols = np.concatenate(
-            [tile_cols, np.zeros(target - stored, dtype=np.int32)]
-        )
-
-    if storage == "bitpack":
-        tiles = pack_tile_bits(tiles)
-    return BlockTiledGraph(
-        tiles=jnp.asarray(tiles),
-        tile_rows=jnp.asarray(tile_rows),
-        tile_cols=jnp.asarray(tile_cols),
-        row_starts=jnp.asarray(row_starts),
-        n_tiles=n_tiles,
-        n_nodes=g.n_nodes,
-        tile_size=T,
-        n_block_rows=int(nb),
-        n_block_cols=int(nb),
-        storage=storage,
+    return tiles_from_cells(
+        TileCells.from_edges(s, r, T, nb), g.n_nodes, storage,
+        pad_tiles_to=pad_tiles_to,
     )
 
 

@@ -46,6 +46,8 @@ import numpy as np
 from repro.core.engine import (
     SegmentEngine,
     TiledPallasEngine,
+    _covered_rows,
+    _covered_vertices,
     get_engine,
     resolve_frontier,
     tile_spmv,
@@ -56,6 +58,7 @@ from repro.core.luby import MISResult
 from repro.core.tc_mis import _tc_mis_impl
 from repro.core.tiling import (
     BlockTiledGraph,
+    gather_frontier_bits,
     pack_frontier_words,
     pack_vertex_vector,
     tiles_as_words,
@@ -97,6 +100,16 @@ def _covered(config, g: Graph, tiled: BlockTiledGraph, in_mis0) -> jnp.ndarray:
         from repro.core.spmv import neighbor_any_segment
 
         return neighbor_any_segment(g, in_mis0[:n])
+    part = tiled.partition
+    if part is not None:
+        # hybrid: the dense sub-tiling on the same substrate (its rows with
+        # no tile masked off, as in the round body), OR the COO tail
+        x = pack_vertex_vector(in_mis0[:n].astype(jnp.int32), tiled)
+        hits = jax.ops.segment_max(
+            x[part.sp_cols], part.sp_rows, num_segments=tiled.n_padded + 1
+        )[:n] > 0
+        dense = _covered(config, g, part.dense, in_mis0)
+        return hits | (dense & _covered_vertices(part.dense)[:n])
     if isinstance(engine, TiledPallasEngine):   # incl. the fused subclass
         from repro.kernels.ops import tc_spmv
 
@@ -117,6 +130,17 @@ def _covered_bits(config, engine, tiled: BlockTiledGraph, in_mis_words) -> jnp.n
     seed-set SpMV, on the engine's own bitwise phase-② substrate.  Only
     tile-schedule engines reach here (`resolve_frontier` never says bitwise
     for the segment engine)."""
+    part = tiled.partition
+    if part is not None:
+        T = tiled.tile_size
+        bit = gather_frontier_bits(in_mis_words, part.sp_cols, T)
+        hit = jax.ops.segment_max(
+            bit.astype(jnp.uint32), part.sp_rows,
+            num_segments=tiled.n_padded + 1,
+        )[:-1]
+        dense = _covered_bits(config, engine, part.dense, in_mis_words)
+        return pack_frontier_words(hit, T) | jnp.where(
+            _covered_rows(part.dense)[:, None], dense, jnp.uint32(0))
     if isinstance(engine, TiledPallasEngine):   # incl. the fused subclass
         from repro.kernels.ops import tc_spmv_bits
 

@@ -24,6 +24,14 @@ free of the O(E) edge scatter.  The result is BIT-EXACT with a from-scratch
 which is both the correctness oracle of the test suite and what lets
 patched plans share cache/bucket machinery with built ones.
 
+A partitioned tiling (DESIGN.md §16) holds no full tile list, only its
+dense sub-tiling and COO tail.  Its patch works on cells, the form sized
+by entries (`core.tiling.TileCells`): the partition's cells, less the
+removed half-edges, plus the added ones, reclassified at the same
+threshold by `partition_from_cells` — the very build a plan of the mutated
+graph runs, so the result is bit-exact with it.  Only the dense tiles
+unpack; no sub-threshold tile is ever built.
+
 `apply_graph_delta` is the edge-list twin: the mutated `Graph` re-enters
 `from_edges` canonicalisation, so a patched graph is indistinguishable —
 content hash included — from the same graph loaded fresh.
@@ -38,9 +46,12 @@ import numpy as np
 
 from repro.core.tiling import (
     BlockTiledGraph,
+    TileCells,
+    cell_keys,
     packed_words,
     padded_tile_count,
-    partition_tiles,
+    partition_from_cells,
+    tiling_cells,
 )
 from repro.dyngraph.delta import EdgeDelta, _pair_keys
 from repro.graphs.graph import Graph, from_edges
@@ -115,20 +126,23 @@ def _edit_tiles(
         tiles[tidx, rloc, cloc] = 1 if set_bit else 0
 
 
-def _repartition(
-    old: BlockTiledGraph, out: BlockTiledGraph
-) -> BlockTiledGraph:
-    """Hybrid reclassification after a tile edit (DESIGN.md §16): a delta
-    can push a tile across the nnz threshold in either direction, and the
-    compacted dense partition holds COPIES of the edited tiles — so a
-    partitioned input rebuilds its partition, at the same threshold, over
-    the mutated tile list.  Deterministic (`partition_tiles`), hence still
-    bit-exact with partitioning a from-scratch rebuild.  Plan-level 'auto'
-    gate re-evaluation is the caller's concern (`api.plan.patch_plan`)."""
-    if old.partition is None:
-        return out
-    return dataclasses.replace(
-        out, partition=partition_tiles(out, old.partition.threshold)
+def _patch_partition(tiled: BlockTiledGraph, delta: EdgeDelta) -> BlockTiledGraph:
+    """A partitioned tiling's patch, on its cells (see module docstring)."""
+    T, nb = tiled.tile_size, tiled.n_block_cols
+    cells = tiling_cells(tiled).cells
+    rem = cell_keys(*_half_edges(delta.remove), T, nb)
+    pos = np.searchsorted(cells, rem)
+    hit = pos < cells.shape[0]
+    hit[hit] = cells[pos[hit]] == rem[hit]
+    cells = np.delete(cells, pos[hit])
+    add = np.unique(cell_keys(*_half_edges(delta.add), T, nb))
+    pos = np.searchsorted(cells, add)
+    present = pos < cells.shape[0]
+    present[present] = cells[pos[present]] == add[present]
+    cells = np.insert(cells, pos[~present], add[~present])
+    return partition_from_cells(
+        TileCells.from_cells(cells, T, nb), tiled.partition.threshold,
+        tiled.n_nodes, tiled.storage,
     )
 
 
@@ -147,10 +161,13 @@ def apply_delta(tiled: BlockTiledGraph, delta: EdgeDelta) -> BlockTiledGraph:
     run by `Plan.apply_delta` on the same batch); a remove aimed at an
     absent edge is a silent no-op bit-clear here, so callers composing the
     two must apply the SAME canonical delta to both representations.
+    A partitioned tiling patches its partition (`_patch_partition`).
     """
     delta.check_bounds(tiled.n_nodes)
     if delta.is_empty:
         return tiled
+    if tiled.partition is not None:
+        return _patch_partition(tiled, delta)
     T = tiled.tile_size
     nbc = tiled.n_block_cols
     nt = tiled.n_tiles
@@ -179,14 +196,10 @@ def apply_delta(tiled: BlockTiledGraph, delta: EdgeDelta) -> BlockTiledGraph:
         drained = touched[~stored[touched].any(axis=(1, 2))] \
             if touched.size else touched
         if drained.size == 0:
-            return _repartition(
-                tiled, dataclasses.replace(tiled, tiles=jnp.asarray(stored))
-            )
+            return dataclasses.replace(tiled, tiles=jnp.asarray(stored))
         keep = np.ones(nt, bool)
         keep[drained] = False
-        return _repartition(
-            tiled, _rebuild_index(tiled, stored[:nt][keep], tile_keys[keep])
-        )
+        return _rebuild_index(tiled, stored[:nt][keep], tile_keys[keep])
 
     # ---- structural path: merge new (zero) tiles into the sorted list ---
     merged_keys = np.union1d(tile_keys, new_keys)
@@ -211,7 +224,7 @@ def apply_delta(tiled: BlockTiledGraph, delta: EdgeDelta) -> BlockTiledGraph:
         keep = np.ones(n_merged, bool)
         keep[drained] = False
         merged, merged_keys = merged[keep], merged_keys[keep]
-    return _repartition(tiled, _rebuild_index(tiled, merged, merged_keys))
+    return _rebuild_index(tiled, merged, merged_keys)
 
 
 def _rebuild_index(

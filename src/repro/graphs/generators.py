@@ -11,11 +11,12 @@ distribution shape and |E|/|V| — at any scale:
   G5 web-Google        web crawl        -> web_like (clustered power-law)
   G6 web-BerkStan      dense web crawl  -> web_like (higher m)
   G7 soc-LiveJournal1  social           -> preferential_attachment (m≈7)
-  G8 kron_g500-logn21  Kronecker        -> rmat (Graph500 a,b,c,d)
+  G8 kron_g500-logn21  Kronecker        -> rmat (Graph500 a,b,c,d, edge
+                                            factor 48)
 
 Wall-clock benchmarks run the *reduced* scale (CPU-tractable).  The
-vectorised generators (G2 `grid2d`, G3 `delaunay_like`) also build the
-*full* published |V| as host arrays — the chip runs solve them; the others
+vectorised generators (G2 `grid2d`, G3 `delaunay_like`, G8 `rmat`) also
+build the *full* published |V| as host arrays — the chip runs solve them; the others
 loop in Python per vertex (or go through networkx), so their full scale
 exists only as dry-run shape specs.  Generators are numpy, deterministic in
 ``seed``.
@@ -63,7 +64,15 @@ def rmat(
     c: float = 0.19,
     seed: int = 0,
 ) -> Graph:
-    """R-MAT / Kronecker generator with Graph500 defaults (kron_g500 stand-in)."""
+    """R-MAT / Kronecker generator with Graph500 defaults (kron_g500 stand-in).
+
+    `edge_factor · 2^scale` samples, each bit level drawn from the
+    a/b/c/d quadrant split; vertex ids then permuted, self-loops dropped
+    and duplicates merged (`from_edges`), as the Graph500 generator's
+    output is before SuiteSparse stored kron_g500-logn*.  Duplicates are
+    common on the hubs, so the edge count lands well under the samples:
+    the published kron_g500-logn21 (91,040,932 edges at scale 21) takes
+    edge factor 48."""
     n = 1 << scale
     m = n * edge_factor
     rng = np.random.default_rng(seed)
@@ -226,7 +235,8 @@ GRAPH_SUITE: Dict[str, GraphSpec] = {
         GraphSpec("soc-LiveJournal1", "G7", 4_847_571, 42_851_237, 24_000,
                   lambda n, seed: preferential_attachment(n, m=7, seed=seed)),
         GraphSpec("kron_g500-logn21", "G8", 2_097_152, 91_040_932, 16_384,
-                  lambda n, seed: rmat(int(np.log2(n)), edge_factor=16, seed=seed)),
+                  lambda n, seed: rmat(int(np.log2(n)), edge_factor=48, seed=seed),
+                  vectorised=True),
     ]
 }
 
@@ -234,7 +244,7 @@ GRAPH_SUITE: Dict[str, GraphSpec] = {
 def generate(paper_id: str, *, scale: str = "reduced", seed: int = 0) -> Graph:
     """Materialise one of the paper's graphs.  ``scale`` is 'reduced' or
     'full'; 'full' builds the published |V| for the vectorised generators
-    (G2, G3) and refuses the ones that loop in Python."""
+    (G2, G3, G8) and refuses the ones that loop in Python."""
     spec = GRAPH_SUITE[paper_id]
     if scale == "reduced":
         return spec.reduced(seed)

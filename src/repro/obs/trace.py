@@ -16,8 +16,10 @@ Span taxonomy (DESIGN.md §14) — names are dotted, layer-first:
     solver.solve            one front-door call
       solver.plan           plan-cache lookup / tiling build
         plan.key            content hash (recorded on a cache miss only)
-        plan.tiles          BSR tile scatter (`build_block_tiles`)
-        plan.partition      hybrid dense/sparse split (`attach_partition`)
+        plan.tiles          cells and tile counts from the edges, packed
+                            tiles (the full list, or the hybrid dense
+                            sub-tiling)
+        plan.tail           hybrid COO tail from the sub-threshold cells
       solver.pack           block-diagonal batch packing
       solver.compile        cold-path lower().compile() (AOT; cache misses only)
       solver.execute        compiled-program dispatch + block_until_ready

@@ -32,8 +32,9 @@ The packing is block-diagonal BSR concatenation of cached `TilePlan`s:
   (`build_csr`, `to_networkx`, `is_valid_mis`) on `batch.g` — validate
   per member on its plan graph, as the service does.
 
-Tile lists concatenate from the plan cache — a batch never re-tiles its
-members, it offsets their cached tiles.
+Tile lists concatenate from the plan cache — a batch offsets its members'
+cached tiles (or their hybrid partitions); it re-tiles only a partitioned
+member of a pack that cannot stay hybrid, whose full list it rebuilds.
 """
 from __future__ import annotations
 
@@ -48,9 +49,11 @@ from repro.core.heuristics import Priorities, make_priorities
 from repro.core.spmv import _NEG
 from repro.core.tiling import (
     BlockTiledGraph,
+    full_tiling,
     next_pow2,
     packed_words,
-    partition_tiles,
+    padded_tile_count,
+    partitioned_tiling,
 )
 from repro.graphs.graph import Graph
 # module-level code with no layer instance to own metrics records into the
@@ -221,11 +224,23 @@ def pack_batch(
     alive0 = np.zeros(n_total, dtype=bool)
     col_gate = np.zeros(bucket.n_blocks, dtype=np.int32)
 
+    # Hybrid routing survives batching only when it is coherent across the
+    # whole pack: every member partitioned, all at one threshold.  Then the
+    # members' dense sub-tilings and COO tails concatenate (offset like
+    # everything else) — the same partition a from-scratch plan of the
+    # packed graph would get, since both lists are in block order.  Any
+    # other pack runs dense-only on the members' full tile lists.
+    parts = [p.tiled.partition for p in plans]
+    thr = None if parts[0] is None else parts[0].threshold
+    hybrid = thr is not None and all(
+        pt is not None and pt.threshold == thr for pt in parts)
+
     src_parts: List[np.ndarray] = []
     dst_parts: List[np.ndarray] = []
     tile_parts: List[np.ndarray] = []
     row_parts: List[np.ndarray] = []
     col_parts: List[np.ndarray] = []
+    sp_parts: List[Tuple[np.ndarray, np.ndarray]] = []
 
     boff = 0
     for plan, (sel_np, res_np) in zip(plans, pris):
@@ -242,6 +257,13 @@ def pack_batch(
 
         src_parts.append(np.asarray(g.senders)[: g.n_edges].astype(np.int64) + voff)
         dst_parts.append(np.asarray(g.receivers)[: g.n_edges].astype(np.int64) + voff)
+        if hybrid:
+            pt = t.partition
+            sp_parts.append((np.asarray(pt.sp_rows)[: pt.sp_nnz] + voff,
+                             np.asarray(pt.sp_cols)[: pt.sp_nnz] + voff))
+            t = pt.dense
+        else:
+            t = full_tiling(t)
         if t.n_tiles:
             tile_parts.append(np.asarray(t.tiles)[: t.n_tiles])
             row_parts.append(np.asarray(t.tile_rows)[: t.n_tiles] + boff)
@@ -280,7 +302,8 @@ def pack_batch(
         rows = np.zeros(0, dtype=np.int32)
         cols = np.zeros(0, dtype=np.int32)
     n_real_tiles = int(tiles.shape[0])
-    n_pad_tiles = bucket.n_tiles_pad - n_real_tiles
+    n_stored = padded_tile_count(n_real_tiles) if hybrid else bucket.n_tiles_pad
+    n_pad_tiles = n_stored - n_real_tiles
     last_row = np.int32(rows[-1]) if n_real_tiles else np.int32(0)
     tiles = np.concatenate(
         [tiles, np.zeros((n_pad_tiles,) + tiles.shape[1:], tiles.dtype)]
@@ -297,12 +320,14 @@ def pack_batch(
     # nothing, and counting them "covered" only routes that row through the
     # kernel epilogue it already takes (zero real tiles ⇒ the zero tile
     # computes exactly the trivial n_c=0 rule the wrapper would patch in).
+    # A hybrid pack's tiles are its dense sub-tiling, padded as a
+    # partition's; the declared count moves to the tiling around it.
     batch_tiled = BlockTiledGraph(
         tiles=jnp.asarray(tiles),
         tile_rows=jnp.asarray(rows),
         tile_cols=jnp.asarray(cols),
         row_starts=jnp.asarray(row_starts),
-        n_tiles=bucket.n_tiles_pad,
+        n_tiles=n_real_tiles if hybrid else bucket.n_tiles_pad,
         n_nodes=n_total,
         tile_size=T,
         n_block_rows=bucket.n_blocks,
@@ -310,19 +335,20 @@ def pack_batch(
         storage=storage,
     )
 
-    # Hybrid routing survives batching only when it is coherent across the
-    # whole pack: every member partitioned, all at one threshold.  The batch
-    # partition is REBUILT over the packed tile list (padding tiles are
-    # all-zero, so they land in neither compacted list) rather than
-    # offset-concatenated — `partition_tiles` is deterministic, so this is
-    # the same partition a from-scratch plan of the packed graph would get.
-    parts = [p.tiled.partition for p in plans]
-    if parts and all(pt is not None for pt in parts):
-        thr = parts[0].threshold
-        if all(pt.threshold == thr for pt in parts):
-            batch_tiled = dataclasses.replace(
-                batch_tiled, partition=partition_tiles(batch_tiled, thr)
-            )
+    if hybrid:
+        sp_r = np.concatenate([r for r, _ in sp_parts])
+        sp_c = np.concatenate([c for _, c in sp_parts])
+        sp_nnz = int(sp_r.shape[0])
+        cap = next_pow2(max(sp_nnz, 8))
+        tail = (np.concatenate([sp_r, np.full(cap - sp_nnz, n_total)]),
+                np.concatenate([sp_c, np.full(cap - sp_nnz, n_total)]))
+        tail = tuple(x.astype(np.int32) for x in tail) + (sp_nnz,)
+        n_sparse = sum(pt.n_sparse_tiles for pt in parts)
+        batch_tiled = dataclasses.replace(
+            partitioned_tiling(batch_tiled, tail, thr, n_sparse),
+            n_tiles=bucket.n_tiles_pad,
+        )
+        n_real_tiles += n_sparse
 
     priorities = Priorities(
         select=jnp.asarray(sel),

@@ -27,6 +27,7 @@ from repro.api.plan import (
 from repro.core.tiling import (
     attach_partition,
     build_block_tiles,
+    full_tiling,
     partition_tiles,
     tile_nnz,
 )
@@ -223,7 +224,9 @@ def test_apply_delta_reclassifies_across_threshold():
     g = erdos_renyi(64, avg_deg=3.0, seed=9)
     tiled = attach_partition(
         build_block_tiles(g, tile_size=T), mode="forced", threshold=thr)
-    nnz0 = int(np.asarray(tile_nnz(tiled))[0])
+    # a partitioned tiling holds no full tile list: count tile 0 on the
+    # full list its partition rebuilds
+    nnz0 = int(np.asarray(tile_nnz(full_tiling(tiled)))[0])
 
     # add intra-tile-0 edges until its nnz (2 per undirected edge) crosses
     have = set()
@@ -236,7 +239,7 @@ def test_apply_delta_reclassifies_across_threshold():
     delta = EdgeDelta.make([u for u, _ in adds], [v for _, v in adds], [], [])
     out = apply_delta(tiled, delta)
 
-    nnz1 = int(np.asarray(tile_nnz(out))[0])
+    nnz1 = int(np.asarray(tile_nnz(full_tiling(out)))[0])
     assert nnz0 < thr <= nnz1        # the crossing actually happened
     assert out.partition is not None
     assert out.partition.threshold == thr

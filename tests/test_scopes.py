@@ -203,7 +203,7 @@ def test_plan_miss_records_its_stages_inside_solver_plan():
     Solver(_plan_opts()).plan(_graph(), trace=tr)
     spans = {s.name: s for s in tr.spans}
     assert set(spans) == {"solver.plan", "plan.key", "plan.tiles",
-                          "plan.partition"}
+                          "plan.tail"}
     outer = spans.pop("solver.plan")
     assert outer.depth == 0 and all(s.depth == 1 for s in spans.values())
     assert sum(s.dur_ms for s in spans.values()) <= outer.dur_ms

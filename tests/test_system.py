@@ -100,6 +100,28 @@ def test_generate_builds_vectorised_graphs_at_full_scale():
         generate("G2", scale="half")
 
 
+def test_g8_builds_at_full_scale_as_graph500_does(monkeypatch):
+    """G8 is vectorised: `generate("G8", scale="full")` builds the
+    published 2^21 vertices (the spec alone is checked here, not the
+    91 M-edge build) with Graph500's edge factor 48."""
+    import dataclasses
+
+    from repro.graphs import generators
+    from repro.graphs.generators import GRAPH_SUITE, generate, rmat
+
+    spec = GRAPH_SUITE["G8"]
+    assert spec.vectorised and spec.n_full == 1 << 21
+    small = spec.make(1 << 10, 5)
+    ref = rmat(10, edge_factor=48, seed=5)
+    assert small.n_edges == ref.n_edges > 40 * (1 << 10)
+    calls = []
+    fake = dataclasses.replace(
+        spec, make=lambda n, seed: calls.append((n, seed)) or "G8 graph")
+    monkeypatch.setitem(generators.GRAPH_SUITE, "G8", fake)
+    assert generate("G8", scale="full", seed=3) == "G8 graph"
+    assert calls == [(1 << 21, 3)]
+
+
 def test_compile_cache_honours_env_else_fixed_checkout_path(monkeypatch):
     """The entry points' compile-cache helper: the env variable wins and
     nothing is set; otherwise the fixed `.jax_cache/` of the checkout.
