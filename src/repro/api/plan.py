@@ -91,7 +91,11 @@ _PLAN_STAT_KEYS = ("mem_hits", "disk_hits", "misses", "evicted_stale")
 # tail, and the meta record gains the dense tile count and the tail's real
 # entry count.  An unpartitioned entry keeps the v3 arrays (dense count
 # -1, no tail arrays).
-_PLAN_VERSION = 4
+#
+# v5: the COO tail's capacity is `tail_capacity(sp_nnz)` (a multiple of
+# 1024 from 1024 entries up), no longer the next power of two; a v4 tail
+# would still solve, but stream up to twice the entries it needs.
+_PLAN_VERSION = 5
 # n_nodes, n_edges, n_tiles, tile_size, nbr, nbc, version, storage,
 # hybrid mode, hybrid threshold, dense tiles (-1: unpartitioned), tail nnz
 _META_LEN = 12

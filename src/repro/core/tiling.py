@@ -153,6 +153,22 @@ def next_pow2(x: int) -> int:
     return 1 << max(int(x) - 1, 0).bit_length()
 
 
+# one (8, 128) int32 tile: the COO tail's capacity quantum
+TAIL_QUANTUM = 1024
+
+
+def tail_capacity(n: int) -> int:
+    """Padded length of a COO tail of `n` real entries: `n` rounded up to
+    a multiple of `TAIL_QUANTUM` (under 0.1% of padding above 1 M
+    entries), or the next power of two ≥ 8 below one quantum.  THE single
+    definition — both partition routes and the delta path must agree on
+    it, or their tails stop being bit-identical."""
+    n = int(n)
+    if n < TAIL_QUANTUM:
+        return next_pow2(max(n, 8))
+    return -(-n // TAIL_QUANTUM) * TAIL_QUANTUM
+
+
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass(frozen=True)
 class BlockTiledGraph:
@@ -291,10 +307,10 @@ class TilePartition:
                  sparse nnz (tile row axis: the SpMV scatter target).
       sp_cols:   (sp_pad,) int32 — GLOBAL padded input-vertex id per
                  sparse nnz (tile column axis: the gather source).
-                 Both padded to a power of two with the sentinel id
-                 `n_padded`; segment consumers use `num_segments =
-                 n_padded + 1` and slice the sentinel row off, exactly
-                 like the Graph sentinel-edge convention.
+                 Both padded to `tail_capacity(sp_nnz)` with the
+                 sentinel id `n_padded`; segment consumers use
+                 `num_segments = n_padded + 1` and slice the sentinel row
+                 off, exactly like the Graph sentinel-edge convention.
       threshold: static — nnz cut: dense iff nnz >= threshold.
       n_dense_tiles / n_sparse_tiles: static — real tiles per class.
       sp_nnz:    static — real (unpadded) sparse-tail edge count.
@@ -493,12 +509,12 @@ def coo_tail(
 ) -> Tuple[np.ndarray, np.ndarray, int]:
     """(sp_rows, sp_cols, sp_nnz): the cells of the tiles not `dense` as a
     COO list in GLOBAL padded vertex ids, in cell order (tile by tile, then
-    row, then column), sentinel-padded (`n_padded`) to a power of two."""
+    row, then column), sentinel-padded (`n_padded`) to `tail_capacity`."""
     T, nb = tc.tile_size, tc.n_block_cols
     sp = tc.cells[~np.repeat(dense, tc.counts)]
     tk, loc = sp // (T * T), sp % (T * T)
     sp_nnz = int(sp.shape[0])
-    cap = next_pow2(max(sp_nnz, 8))
+    cap = tail_capacity(sp_nnz)
     sp_rows = np.full(cap, n_padded, np.int32)
     sp_cols = np.full(cap, n_padded, np.int32)
     sp_rows[:sp_nnz] = (tk // nb) * T + loc // T
@@ -671,7 +687,7 @@ def partition_tiles(
     v = rows_h[sparse_idx][t_i].astype(np.int64) * T + r_i
     u = cols_h[sparse_idx][t_i].astype(np.int64) * T + c_i
     sp_nnz = int(v.shape[0])
-    cap = next_pow2(max(sp_nnz, 8))
+    cap = tail_capacity(sp_nnz)
     sentinel = np.int32(tiled.n_padded)
     sp_rows = np.full(cap, sentinel, np.int32)
     sp_cols = np.full(cap, sentinel, np.int32)

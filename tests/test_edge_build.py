@@ -23,6 +23,7 @@ from repro.core.tiling import (
     build_block_tiles,
     full_tiling,
     partition_tiles,
+    tail_capacity,
 )
 from repro.dyngraph import apply_graph_delta, random_delta
 from repro.graphs.generators import erdos_renyi, grid2d, rmat
@@ -95,7 +96,8 @@ def test_edge_build_matches_the_tile_list_partition(name, mode, thr, storage):
         (po.n_dense_tiles, po.n_sparse_tiles, po.sp_nnz)
     np.testing.assert_array_equal(_pairs(pn.sp_rows, pn.sp_cols, pn.sp_nnz),
                                   _pairs(po.sp_rows, po.sp_cols, po.sp_nnz))
-    assert pn.sp_rows.shape == po.sp_rows.shape
+    assert pn.sp_rows.shape == po.sp_rows.shape \
+        == (tail_capacity(pn.sp_nnz),)
     # no device array holds the full tile list
     assert new.tiles.shape[0] == new.tile_rows.shape[0] == 0
     assert new.n_tiles == pn.n_dense_tiles + pn.n_sparse_tiles
