@@ -4,7 +4,7 @@ Every execution path of the system — the paper-faithful CC baseline, the jnp
 tile oracle, the Pallas SpMV kernel, and the fused phase-②+③ kernel — is a
 `RoundEngine`: an object that knows how to run one MIS round (DESIGN.md §4).
 The driver (`core.tc_mis`) is engine-agnostic; it owns only the convergence
-loop.  Benchmarks, examples and future backends (GPU Pallas) select engines
+loop.  The API, examples and future backends (GPU Pallas) select engines
 from the registry instead of hard-coding call sites — kernel selection is a
 pluggable policy over one tiled schedule, the way BLEST/HC-SpMM treat their
 kernel zoos.  (Bit-packed masks — once a forward reference here — are now a
@@ -142,8 +142,7 @@ def tile_neighbor_max(
 
     Storage dispatch goes through `dense_tile_mask`, not `dense_tiles`: the
     packed form bit-extracts straight to the bool mask the `where` needs,
-    skipping the int8 materialisation that made bitpack LOSE to int8 here
-    (733 vs 673 µs at T=64 in the pre-fix BENCH_core.json)."""
+    with no int8 intermediate."""
     T = tile_size
     mask = dense_tile_mask(tiles, T)
     gathered = pm.reshape(-1, T)[tile_cols]                  # (nt, T)
@@ -342,6 +341,20 @@ class MISRoundState(NamedTuple):
     rnd: jnp.ndarray      # int32 — () global, or (n_padded,) per-vertex
 
 
+class RoundFacts(NamedTuple):
+    """What a round body computed on its way that the telemetry row reads.
+
+    Every body returns it beside the new state; `step` drops it, so the
+    telemetry-off program holds no op it did not hold before (DESIGN.md
+    §14), and `step_with_stats` folds it into a row without a second pass.
+    """
+    cand: jnp.ndarray               # (n_padded,) bool, or (nbc, W) uint32 words
+    flags: Optional[jnp.ndarray]    # (nbc,) col flags; None = no tile schedule
+    tiled: BlockTiledGraph          # the tiling the skip counts walk (the
+                                    # dense partition on hybrid routes)
+    n_sparse_tiles: int             # COO-tail tiles; 0 off hybrid
+
+
 @dataclasses.dataclass(frozen=True)
 class EngineContext:
     """Immutable per-run bundle an engine closes over: the graph in both
@@ -427,14 +440,14 @@ def _count(mask: jnp.ndarray) -> jnp.ndarray:
     return jnp.sum(mask.astype(jnp.int32))
 
 
-def _tiles_skipped(ctx: EngineContext, flags: Optional[jnp.ndarray]) -> jnp.ndarray:
-    """Tiles gated off this round by the empty-C col_flags skip: every tile
-    whose block column carries flag 0.  Engines without flags (segment) skip
-    nothing — 0."""
+def _tiles_skipped(tiled, flags: Optional[jnp.ndarray]) -> jnp.ndarray:
+    """Tiles of `tiled` gated off this round by the empty-C col_flags skip:
+    every tile whose block column carries flag 0.  Engines without flags
+    (segment) skip nothing — 0."""
     if flags is None:
         return jnp.int32(0)
-    n_tiles = int(ctx.tiled.tile_cols.shape[0])
-    return jnp.int32(n_tiles) - jnp.sum(flags[ctx.tiled.tile_cols].astype(jnp.int32))
+    n_tiles = int(tiled.tile_cols.shape[0])
+    return jnp.int32(n_tiles) - jnp.sum(flags[tiled.tile_cols].astype(jnp.int32))
 
 
 def _telemetry_row(
@@ -452,14 +465,14 @@ def _telemetry_row(
 
 
 def _tiles_routed_dense(
-    ctx: EngineContext, skipped: jnp.ndarray, flags: Optional[jnp.ndarray]
+    tiled, skipped: jnp.ndarray, flags: Optional[jnp.ndarray]
 ) -> jnp.ndarray:
     """Tiles actually dispatched on the dense path this round: the stored
     list minus the flag-gated ones.  Engines with no tile schedule (flags
     None) route zero tiles."""
     if flags is None:
         return jnp.int32(0)
-    return jnp.int32(int(ctx.tiled.tile_cols.shape[0])) - skipped
+    return jnp.int32(int(tiled.tile_cols.shape[0])) - skipped
 
 
 def _covered_rows(tiled) -> jnp.ndarray:
@@ -488,14 +501,15 @@ class RoundEngine:
 
     Subclasses implement `_nbr_max` (phase ① substrate) and either
     `phase2_counts` (split engines) or `fused_step` (fused engines,
-    `fused = True`).  `step` — the single round body every driver uses —
-    is shared; `col_flags` is the per-round metadata hook.
+    `fused = True`).  `step` — the round every driver runs — is shared;
+    `col_flags` is the per-round metadata hook.
 
     Tile-schedule engines additionally advertise `supports_bitwise` and
     implement the packed-frontier round body (`step_bits` et al., DESIGN.md
     §13): state rides as (nbc, W) uint32 words, phase ② is the popcount
-    SpMV, phase ① the sorted-priority clz scan.  `step` dispatches on the
-    resolved `ctx.frontier`.
+    SpMV, phase ① the sorted-priority clz scan.  `round_body` picks one
+    body per route from the resolved `ctx.frontier` and the partition;
+    `step` and `step_with_stats` both run that one body.
     """
 
     name: str = "abstract"
@@ -577,67 +591,50 @@ class RoundEngine:
     # -- bitwise round body (packed-frontier engines only) -----------------
     def step_bits(
         self, ctx: EngineContext, pri, state: MISRoundState
-    ) -> MISRoundState:
+    ) -> Tuple[MISRoundState, RoundFacts]:
         raise NotImplementedError(
             f"{self.name} has no packed-frontier round body "
             f"(supports_bitwise={self.supports_bitwise})"
         )
 
-    # -- the round body ----------------------------------------------------
+    # -- the round bodies --------------------------------------------------
+    def round_body(self, ctx: EngineContext):
+        """The body of this run's route — dense or packed frontier, whole
+        tiling or hybrid partition.  Each returns `(state, RoundFacts)`."""
+        hybrid = self.supports_hybrid and ctx.tiled.partition is not None
+        if ctx.frontier == "bitwise":
+            return self.step_bits_hybrid if hybrid else self.step_bits
+        return self.step_hybrid if hybrid else self.step_dense
+
     def step(
         self, ctx: EngineContext, pri, state: MISRoundState
     ) -> MISRoundState:
-        if self.supports_hybrid and ctx.tiled.partition is not None:
-            if ctx.frontier == "bitwise":
-                return self.step_bits_hybrid(ctx, pri, state)
-            return self.step_hybrid(ctx, pri, state)
-        if ctx.frontier == "bitwise":
-            return self.step_bits(ctx, pri, state)
-        with jax.named_scope(SCOPE_P1):
-            cand = self.phase1_candidates(ctx, pri, state.alive)
-        with jax.named_scope(SCOPE_P2):
-            flags = self.col_flags(ctx, cand, state.alive)
-        with jax.named_scope(SCOPE_P3):
-            inc = round_increment(state)
-        if self.fused:
-            with jax.named_scope(SCOPE_P2):
-                new_alive, mis_add = self.fused_step(ctx, cand, state.alive, flags)
-            with jax.named_scope(SCOPE_P3):
-                return MISRoundState(
-                    alive=new_alive,
-                    in_mis=state.in_mis | mis_add,
-                    rnd=state.rnd + inc,
-                )
-        with jax.named_scope(SCOPE_P2):
-            n_c = self.phase2_counts(ctx, cand, state.alive, flags)
-        with jax.named_scope(SCOPE_P3):
-            return phase3_update(state, cand, n_c, inc)
-
-    # -- the instrumented round body (telemetry runs only) -----------------
-    def _step_bits_with_stats(
-        self, ctx: EngineContext, pri, state: MISRoundState
-    ) -> Tuple[MISRoundState, jnp.ndarray]:
-        raise NotImplementedError(
-            f"{self.name} has no packed-frontier round body "
-            f"(supports_bitwise={self.supports_bitwise})"
-        )
+        return self.round_body(ctx)(ctx, pri, state)[0]
 
     def step_with_stats(
         self, ctx: EngineContext, pri, state: MISRoundState
     ) -> Tuple[MISRoundState, jnp.ndarray]:
-        """`step` plus a (TELEMETRY_COLS,) int32 telemetry row — the same
-        round body with six extra reductions (no extra SpMVs, no host
-        callbacks).  Kept separate from `step` so the telemetry-off program
-        is the byte-exact pre-telemetry jaxpr (DESIGN.md §14's zero-cost
-        guarantee)."""
-        if self.supports_hybrid and ctx.tiled.partition is not None:
-            if ctx.frontier == "bitwise":
-                return self._step_bits_hybrid_with_stats(ctx, pri, state)
-            return self._step_hybrid_with_stats(ctx, pri, state)
-        if ctx.frontier == "bitwise":
-            return self._step_bits_with_stats(ctx, pri, state)
+        """`step` plus a (TELEMETRY_COLS,) int32 telemetry row: the same
+        body, once, with six reductions over what it already computed (no
+        extra SpMVs, no host callbacks).  Packed frontiers count by word
+        popcount — the frontier never densifies."""
+        new, facts = self.round_body(ctx)(ctx, pri, state)
+        count = _popcount_words if ctx.frontier == "bitwise" else _count
         with jax.named_scope(SCOPE_P3):   # telemetry counts: ③ bookkeeping
-            alive_count = _count(state.alive)
+            skipped = _tiles_skipped(facts.tiled, facts.flags)
+            row = _telemetry_row(
+                count(state.alive),
+                count(facts.cand),
+                count(new.in_mis) - count(state.in_mis),
+                skipped,
+                _tiles_routed_dense(facts.tiled, skipped, facts.flags),
+                jnp.int32(facts.n_sparse_tiles),
+            )
+        return new, row
+
+    def step_dense(
+        self, ctx: EngineContext, pri, state: MISRoundState
+    ) -> Tuple[MISRoundState, RoundFacts]:
         with jax.named_scope(SCOPE_P1):
             cand = self.phase1_candidates(ctx, pri, state.alive)
         with jax.named_scope(SCOPE_P2):
@@ -658,17 +655,7 @@ class RoundEngine:
                 n_c = self.phase2_counts(ctx, cand, state.alive, flags)
             with jax.named_scope(SCOPE_P3):
                 new = phase3_update(state, cand, n_c, inc)
-        with jax.named_scope(SCOPE_P3):
-            skipped = _tiles_skipped(ctx, flags)
-            row = _telemetry_row(
-                alive_count,
-                _count(cand),
-                _count(new.in_mis) - _count(state.in_mis),
-                skipped,
-                _tiles_routed_dense(ctx, skipped, flags),
-                jnp.int32(0),
-            )
-        return new, row
+        return new, RoundFacts(cand, flags, ctx.tiled, 0)
 
 
 # --------------------------------------------------------------------------
@@ -800,27 +787,37 @@ class _TiledEngine(RoundEngine):
             t.n_block_rows, t.tile_size,
         )
 
-    def phase1_candidates_bits(self, ctx, pri, alive_words) -> jnp.ndarray:
+    def phase1_candidates_bits(
+        self, ctx, pri, alive_words, dctx=None
+    ) -> jnp.ndarray:
         """① on packed frontiers.  Priorities stay dense (they are values,
         not frontiers); the select/pending/candidate SETS stay packed.  The
         padded-slot divergence between substrates (segment pads Max_Np with
         0, tiled floors at _NEG) is erased by the `& alive_words` /
-        `& pending` guards — padded alive bits are always 0."""
+        `& pending` guards — padded alive bits are always 0.
+
+        With `dctx` (hybrid routes) the tiled Max_Np merges the dense
+        partition's scan with the COO tail; the bitwise setup artefacts
+        (`ctx.bits`) are then built over the dense partition
+        (`make_bitwise_context(partition.dense, ...)`), so the sorted-tile
+        scan only walks dense tiles."""
         T = ctx.tiled.tile_size
         b = ctx.bits
-        if ctx.cfg.phase1 != "tiled":
-            max_np = _segment_nbr_max_bits_oracle(ctx, pri.select, alive_words)
-        else:
-            max_np = self._nbr_max_bits(ctx, b.select, b.select_planes, alive_words)
+
+        def max_np(st, planes, p, mask_words):
+            if ctx.cfg.phase1 != "tiled":
+                return _segment_nbr_max_bits_oracle(ctx, p, mask_words)
+            if dctx is None:
+                return self._nbr_max_bits(ctx, st, planes, mask_words)
+            return self._hybrid_nbr_max_bits(ctx, dctx, st, planes, p, mask_words)
+
+        mx = max_np(b.select, b.select_planes, pri.select, alive_words)
         if pri.resolve is None:
-            return pack_frontier_words(pri.select > max_np, T) & alive_words
+            return pack_frontier_words(pri.select > mx, T) & alive_words
         # H3: conflicts resolved on the pending set before C is finalised.
-        pending = pack_frontier_words(pri.select >= max_np, T) & alive_words
-        if ctx.cfg.phase1 != "tiled":
-            max_res = _segment_nbr_max_bits_oracle(ctx, pri.resolve, pending)
-        else:
-            max_res = self._nbr_max_bits(ctx, b.resolve, b.resolve_planes, pending)
-        return pack_frontier_words(pri.resolve > max_res, T) & pending
+        pending = pack_frontier_words(pri.select >= mx, T) & alive_words
+        mx = max_np(b.resolve, b.resolve_planes, pri.resolve, pending)
+        return pack_frontier_words(pri.resolve > mx, T) & pending
 
     @_tile
     def col_flags_bits(self, ctx, cand_words) -> jnp.ndarray:
@@ -839,40 +836,14 @@ class _TiledEngine(RoundEngine):
         """②+③ fused on words.  Returns (new_alive_words, mis_add_words)."""
         raise NotImplementedError(f"{self.name} is a split engine")
 
-    def step_bits(self, ctx, pri, state: MISRoundState) -> MISRoundState:
+    def step_bits(
+        self, ctx, pri, state: MISRoundState
+    ) -> Tuple[MISRoundState, RoundFacts]:
         with jax.named_scope(SCOPE_P1):
             cand_w = self.phase1_candidates_bits(ctx, pri, state.alive)
         with jax.named_scope(SCOPE_P2):
             flags = self.col_flags_bits(ctx, cand_w)
         inc = round_increment(state)   # scalar: bitwise excludes member_rounds
-        if self.fused:
-            with jax.named_scope(SCOPE_P2):
-                new_alive, mis_add = self.fused_step_bits(
-                    ctx, cand_w, state.alive, flags
-                )
-            with jax.named_scope(SCOPE_P3):
-                return MISRoundState(
-                    alive=new_alive,
-                    in_mis=state.in_mis | mis_add,
-                    rnd=state.rnd + inc,
-                )
-        with jax.named_scope(SCOPE_P2):
-            hit_w = self.phase2_hits(ctx, cand_w, state.alive, flags)
-        with jax.named_scope(SCOPE_P3):
-            return phase3_update_bits(state, cand_w, hit_w, inc)
-
-    def _step_bits_with_stats(
-        self, ctx, pri, state: MISRoundState
-    ) -> Tuple[MISRoundState, jnp.ndarray]:
-        """`step_bits` + telemetry row; the counts are word popcounts
-        (`jax.lax.population_count`) — the frontier never densifies."""
-        with jax.named_scope(SCOPE_P3):   # telemetry counts: ③ bookkeeping
-            alive_count = _popcount_words(state.alive)
-        with jax.named_scope(SCOPE_P1):
-            cand_w = self.phase1_candidates_bits(ctx, pri, state.alive)
-        with jax.named_scope(SCOPE_P2):
-            flags = self.col_flags_bits(ctx, cand_w)
-        inc = round_increment(state)
         if self.fused:
             with jax.named_scope(SCOPE_P2):
                 new_alive, mis_add = self.fused_step_bits(
@@ -889,17 +860,7 @@ class _TiledEngine(RoundEngine):
                 hit_w = self.phase2_hits(ctx, cand_w, state.alive, flags)
             with jax.named_scope(SCOPE_P3):
                 new = phase3_update_bits(state, cand_w, hit_w, inc)
-        with jax.named_scope(SCOPE_P3):
-            skipped = _tiles_skipped(ctx, flags)
-            row = _telemetry_row(
-                alive_count,
-                _popcount_words(cand_w),
-                _popcount_words(new.in_mis) - _popcount_words(state.in_mis),
-                skipped,
-                _tiles_routed_dense(ctx, skipped, flags),
-                jnp.int32(0),
-            )
-        return new, row
+        return new, RoundFacts(cand_w, flags, ctx.tiled, 0)
 
     # -- hybrid round bodies (DESIGN.md §16) -------------------------------
     #
@@ -971,26 +932,11 @@ class _TiledEngine(RoundEngine):
         max_res = self._hybrid_nbr_max(ctx, dctx, pri.resolve, pending)
         return pending & (pri.resolve > max_res)
 
-    def step_hybrid(self, ctx, pri, state: MISRoundState) -> MISRoundState:
-        dctx = dataclasses.replace(ctx, tiled=ctx.tiled.partition.dense)
-        with jax.named_scope(SCOPE_P1):
-            cand = self._hybrid_candidates(ctx, dctx, pri, state.alive)
-        with jax.named_scope(SCOPE_P2):
-            flags = self.col_flags(dctx, cand, state.alive)
-        with jax.named_scope(SCOPE_P3):
-            inc = round_increment(state)
-        with jax.named_scope(SCOPE_P2):
-            n_c = self._dense_phase2(dctx, cand, state.alive, flags)
-            n_c = n_c + self._sparse_counts(ctx, cand)
-        with jax.named_scope(SCOPE_P3):
-            return phase3_update(state, cand, n_c, inc)
-
-    def _step_hybrid_with_stats(
+    def step_hybrid(
         self, ctx, pri, state: MISRoundState
-    ) -> Tuple[MISRoundState, jnp.ndarray]:
-        dctx = dataclasses.replace(ctx, tiled=ctx.tiled.partition.dense)
-        with jax.named_scope(SCOPE_P3):   # telemetry counts: ③ bookkeeping
-            alive_count = _count(state.alive)
+    ) -> Tuple[MISRoundState, RoundFacts]:
+        part = ctx.tiled.partition
+        dctx = dataclasses.replace(ctx, tiled=part.dense)
         with jax.named_scope(SCOPE_P1):
             cand = self._hybrid_candidates(ctx, dctx, pri, state.alive)
         with jax.named_scope(SCOPE_P2):
@@ -1002,16 +948,7 @@ class _TiledEngine(RoundEngine):
             n_c = n_c + self._sparse_counts(ctx, cand)
         with jax.named_scope(SCOPE_P3):
             new = phase3_update(state, cand, n_c, inc)
-            skipped = _tiles_skipped(dctx, flags)
-            row = _telemetry_row(
-                alive_count,
-                _count(cand),
-                _count(new.in_mis) - _count(state.in_mis),
-                skipped,
-                _tiles_routed_dense(dctx, skipped, flags),
-                jnp.int32(ctx.tiled.partition.n_sparse_tiles),
-            )
-        return new, row
+        return new, RoundFacts(cand, flags, part.dense, part.n_sparse_tiles)
 
     # -- hybrid, packed frontiers ------------------------------------------
 
@@ -1063,51 +1000,14 @@ class _TiledEngine(RoundEngine):
         dense_mx = self._dense_nbr_max_bits(dctx, st, planes, mask_words)
         return jnp.maximum(dense_mx, self._sparse_nbr_max_bits(ctx, p, mask_words))
 
-    def _hybrid_candidates_bits(self, ctx, dctx, pri, alive_words) -> jnp.ndarray:
-        """`phase1_candidates_bits` with the merged Max_Np.  The bitwise
-        setup artefacts (`ctx.bits`) are built over the DENSE PARTITION in
-        hybrid runs (`make_bitwise_context(partition.dense, ...)`), so the
-        sorted-tile scan only walks dense tiles."""
-        T = ctx.tiled.tile_size
-        b = ctx.bits
-        if ctx.cfg.phase1 != "tiled":
-            max_np = _segment_nbr_max_bits_oracle(ctx, pri.select, alive_words)
-        else:
-            max_np = self._hybrid_nbr_max_bits(
-                ctx, dctx, b.select, b.select_planes, pri.select, alive_words
-            )
-        if pri.resolve is None:
-            return pack_frontier_words(pri.select > max_np, T) & alive_words
-        pending = pack_frontier_words(pri.select >= max_np, T) & alive_words
-        if ctx.cfg.phase1 != "tiled":
-            max_res = _segment_nbr_max_bits_oracle(ctx, pri.resolve, pending)
-        else:
-            max_res = self._hybrid_nbr_max_bits(
-                ctx, dctx, b.resolve, b.resolve_planes, pri.resolve, pending
-            )
-        return pack_frontier_words(pri.resolve > max_res, T) & pending
-
-    def step_bits_hybrid(self, ctx, pri, state: MISRoundState) -> MISRoundState:
-        dctx = dataclasses.replace(ctx, tiled=ctx.tiled.partition.dense)
-        with jax.named_scope(SCOPE_P1):
-            cand_w = self._hybrid_candidates_bits(ctx, dctx, pri, state.alive)
-        with jax.named_scope(SCOPE_P2):
-            flags = self.col_flags_bits(ctx, cand_w)
-        inc = round_increment(state)
-        with jax.named_scope(SCOPE_P2):
-            hit_w = self._dense_hits_bits(dctx, cand_w, state.alive, flags)
-            hit_w = hit_w | self._sparse_hits_bits(ctx, cand_w)
-        with jax.named_scope(SCOPE_P3):
-            return phase3_update_bits(state, cand_w, hit_w, inc)
-
-    def _step_bits_hybrid_with_stats(
+    def step_bits_hybrid(
         self, ctx, pri, state: MISRoundState
-    ) -> Tuple[MISRoundState, jnp.ndarray]:
-        dctx = dataclasses.replace(ctx, tiled=ctx.tiled.partition.dense)
-        with jax.named_scope(SCOPE_P3):   # telemetry counts: ③ bookkeeping
-            alive_count = _popcount_words(state.alive)
+    ) -> Tuple[MISRoundState, RoundFacts]:
+        # flags come off the full context, skip counts off the dense one
+        part = ctx.tiled.partition
+        dctx = dataclasses.replace(ctx, tiled=part.dense)
         with jax.named_scope(SCOPE_P1):
-            cand_w = self._hybrid_candidates_bits(ctx, dctx, pri, state.alive)
+            cand_w = self.phase1_candidates_bits(ctx, pri, state.alive, dctx)
         with jax.named_scope(SCOPE_P2):
             flags = self.col_flags_bits(ctx, cand_w)
         inc = round_increment(state)
@@ -1116,16 +1016,7 @@ class _TiledEngine(RoundEngine):
             hit_w = hit_w | self._sparse_hits_bits(ctx, cand_w)
         with jax.named_scope(SCOPE_P3):
             new = phase3_update_bits(state, cand_w, hit_w, inc)
-            skipped = _tiles_skipped(dctx, flags)
-            row = _telemetry_row(
-                alive_count,
-                _popcount_words(cand_w),
-                _popcount_words(new.in_mis) - _popcount_words(state.in_mis),
-                skipped,
-                _tiles_routed_dense(dctx, skipped, flags),
-                jnp.int32(ctx.tiled.partition.n_sparse_tiles),
-            )
-        return new, row
+        return new, RoundFacts(cand_w, flags, part.dense, part.n_sparse_tiles)
 
 
 class TiledRefEngine(_TiledEngine):

@@ -1,8 +1,8 @@
 """Drift observability for long-horizon mutation streams (DESIGN.md §17).
 
-PR 5 froze the RCM permutation at epoch 0, so tile locality decays under
-sustained churn — BENCH_dyngraph already shows repair losing to cold at
-1-5% deltas.  The ROADMAP's re-anchoring item needs a *signal* before a
+The RCM permutation is frozen at epoch 0, so tile locality decays under
+sustained churn, and repair can lose to a cold solve at 1-5% deltas.  The
+ROADMAP's re-anchoring item needs a *signal* before a
 policy can exist; this module is that signal.  Three gauges, all recorded
 at the eager patch seam (`api.plan.patch_plan` — the one funnel every
 actual patch event passes through, cached hits excluded so an epoch is

@@ -1,10 +1,9 @@
 """JAX's persistent compilation cache, set once per entry point.
 
-Each command-line entry point (`chip_smoke.py`, `python -m repro.serve_mis`,
-`benchmarks/run.py`) calls `enable_compile_cache()` before its first
-compile, so a second run of the same program on the same machine reads its
-compiled programs back instead of compiling again.  Library code and tests
-never call it.
+Each command-line entry point (`chip_smoke.py`, `python -m repro.serve_mis`)
+calls `enable_compile_cache()` before its first compile, so a second run
+of the same program on the same machine reads its compiled programs back
+instead of compiling again.  Library code and tests never call it.
 
 Where `JAX_COMPILATION_CACHE_DIR` is set, JAX already reads it and this
 helper sets nothing.  Otherwise the cache lives at the fixed `.jax_cache/`

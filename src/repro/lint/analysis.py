@@ -292,7 +292,7 @@ def parse_module(
 def find_root(path: pathlib.Path) -> pathlib.Path:
     """Package root for module naming: the nearest ancestor named `src`
     (so `src/repro/...` parses as `repro.*` wherever the command is run
-    from), else the directory itself (tmp fixture trees, `benchmarks/`)."""
+    from), else the directory itself (tmp fixture trees, `examples/`)."""
     p = path.resolve()
     start = p if p.is_dir() else p.parent
     for d in (start, *start.parents):

@@ -63,15 +63,13 @@ SEED_FUNCTIONS = frozenset(
 SEED_ENGINE_METHODS = frozenset(
     {
         "step",
-        "step_bits",
-        "fused_step",
-        "fused_step_bits",
         "step_with_stats",
-        "_step_bits_with_stats",
+        "step_dense",
+        "step_bits",
         "step_hybrid",
         "step_bits_hybrid",
-        "_step_hybrid_with_stats",
-        "_step_bits_hybrid_with_stats",
+        "fused_step",
+        "fused_step_bits",
     }
 )
 SEED_SUFFIXES = ("_kernel",)
@@ -315,8 +313,8 @@ class CallGraph:
         suffixes = tuple(seeds.get("suffixes", ()))
         for mi in ctx.modules.values():
             # `*_kernel` suffix seeding is scoped to kernels packages so a
-            # host-side `_bench_pallas_kernel` driver in benchmarks/ does
-            # not masquerade as a device kernel ...
+            # host-side timing driver named `*_kernel` outside kernels/
+            # does not masquerade as a device kernel ...
             kernels_pkg = "kernels" in mi.name.split(".")
             for fi in mi.functions.values():
                 if fi.name in fn_names and fi.class_name is None:

@@ -1,20 +1,16 @@
 """repro.obs — observability for the TC-MIS stack (DESIGN.md §14, §17).
 
-Five legs, importable independently:
+Four legs, importable independently:
 
 * `rounds`   — on-device round-telemetry buffer layout + host `RoundTrace`
                (numpy-only; `core.engine` imports its column constants)
 * `trace`    — `Trace` / `trace_span` span tracing + JSONL export
 * `metrics`  — `MetricsRegistry` counters/gauges/fixed-bucket histograms
                (p50/p95/p99) + the process-wide `REGISTRY`
-* `bench`    — stamped bench snapshots, the append-only `BENCH_history/`
-               store, and the `bench-diff` regression gate
 * `promtext` — Prometheus text exposition over a metrics snapshot
 
-`python -m repro.obs report trace.jsonl` renders the JSONL stream;
-`python -m repro.obs bench-diff <base> <head>` gates perf regressions.
+`python -m repro.obs report trace.jsonl` renders the JSONL stream.
 """
-from .bench import append_history, bench_env, diff, load_records, stamp, write_bench
 from .metrics import (
     DEFAULT_BUCKETS,
     QUANTILES,
@@ -41,12 +37,6 @@ __all__ = [
     "DEFAULT_BUCKETS",
     "QUANTILES",
     "REGISTRY",
-    "append_history",
-    "bench_env",
-    "diff",
-    "load_records",
-    "stamp",
-    "write_bench",
     "to_promtext",
     "write_promtext",
     "Counter",

@@ -1,6 +1,5 @@
-"""`python -m repro.obs report <trace.jsonl>` renders telemetry;
-`python -m repro.obs bench-diff <base> <head>` gates perf regressions —
-see report.py / bench.py."""
+"""`python -m repro.obs report <trace.jsonl>` renders telemetry — see
+report.py."""
 import sys
 
 from .report import main

@@ -6,20 +6,13 @@ Three record kinds land in one JSONL stream (`JsonlWriter`):
     {"kind": "rounds",  "rounds": R, "alive": [...], ...}
     {"kind": "metrics", "metrics": {...}}
 
-plus bench-history records (no ``kind`` — `repro.obs.bench` schema with
-``key``/``metric``/``value_us``), so ``report`` pointed at a
-``BENCH_history/*.jsonl`` file renders a bench section too.
-
 The report renders each in order: trace records as an indented span tree
 with durations, rounds records as a per-round table plus a sparkline of
 the alive series, metrics records as a name → value table whose histogram
-entries form the *health* section (count/mean/p50/p95/p99), bench records
-as one timing line each.  ``--json`` swaps the human rendering for one
-machine-readable document.  Exit code 2 when the file holds no renderable
-records — the CI smoke step relies on that to catch an empty pipe.
-
-``python -m repro.obs bench-diff <base> <head>`` (the CI regression gate)
-dispatches to `repro.obs.bench`; see there for thresholds and exit codes.
+entries form the *health* section (count/mean/p50/p95/p99).  ``--json``
+swaps the human rendering for one machine-readable document.  Exit code 2
+when the file holds no renderable records — the CI smoke step relies on
+that to catch an empty pipe.
 """
 from __future__ import annotations
 
@@ -104,30 +97,16 @@ def render_metrics(d: Dict, out) -> None:
         out.write(f"  {name:<44} {val}\n")
 
 
-def render_bench(d: Dict, out) -> None:
-    """One bench-history record → one timing line."""
-    out.write(
-        f"bench {d.get('key', '?')} [{d.get('metric', '?')}]"
-        f" {d.get('value_us', 0.0)}us"
-        f"  @{d.get('git_sha', '?')} {d.get('timestamp', '?')}\n"
-    )
-
-
-def _classify(d: Dict) -> str:
-    kind = d.get("kind")
-    if kind in ("trace", "rounds", "metrics"):
-        return kind
-    if kind is None and "metric" in d and "value_us" in d:
-        return "bench"
-    return "unknown"
-
-
 _RENDERERS = {
     "trace": render_trace,
     "rounds": render_rounds,
     "metrics": render_metrics,
-    "bench": render_bench,
 }
+
+
+def _classify(d: Dict) -> str:
+    kind = d.get("kind")
+    return kind if kind in _RENDERERS else "unknown"
 
 
 def _load(path: str, out) -> List[Dict]:
@@ -191,28 +170,16 @@ class _NullOut:
 
 
 def main(argv=None) -> int:
-    argv = list(sys.argv[1:] if argv is None else argv)
-    if argv and argv[0] == "bench-diff":
-        # the regression gate has its own argparse (thresholds, --json):
-        # hand the remaining argv straight over so its --help stays whole
-        from . import bench
-
-        return bench.main(argv[1:])
-
     p = argparse.ArgumentParser(
         prog="python -m repro.obs",
         description="render repro.obs JSONL telemetry (trace tree, "
-                    "per-round series, metrics/health tables, bench "
-                    "history); `bench-diff` compares two history files",
+                    "per-round series, metrics/health tables)",
     )
     sub = p.add_subparsers(dest="cmd", required=True)
     rp = sub.add_parser("report", help="render a JSONL telemetry file")
     rp.add_argument("path", help="JSONL file written by the service / solver")
     rp.add_argument("--json", action="store_true",
                     help="emit a machine-readable JSON digest instead")
-    sub.add_parser("bench-diff",
-                   help="compare two bench-history files (see bench-diff "
-                        "--help); exit 1 on regression")
     args = p.parse_args(argv)
 
     if args.cmd == "report":

@@ -22,9 +22,8 @@ are sized for.
 The same hardware constants drive the hybrid tile-routing threshold
 (DESIGN.md §16): a dense tile pays a fixed cost regardless of occupancy,
 a segment-path edge pays a per-nnz cost, and the break-even nnz between
-the two is ``hybrid_density_threshold``.  Historically this lived in
-``benchmarks/roofline.py``; it moved into ``src/repro`` so the planner can
-import it — the benchmarks module is now a re-export shim.
+the two is ``hybrid_density_threshold``, which the planner reads at plan
+time.
 """
 from __future__ import annotations
 

@@ -402,99 +402,6 @@ def test_histogram_merge_across_registries():
 
 
 # --------------------------------------------------------------------------
-# §17: bench history + bench-diff
-# --------------------------------------------------------------------------
-
-def test_write_bench_stamps_and_appends_history(tmp_path):
-    from repro.obs.bench import load_records, write_bench
-
-    hist = str(tmp_path / "hist")
-    doc = dict(bench="t", backend="fake", results=[
-        dict(op="a", n=4, us_per_call=5.0, rounds=3),
-        dict(op="b", n=4, solve_ms=2.0, mis_size=7),
-    ])
-    out = write_bench(doc, str(tmp_path / "snap.json"), history_dir=hist)
-    # stamp fills the header but never overwrites the bench's own fields
-    assert out["schema_version"] == 1 and out["backend"] == "fake"
-    assert out["git_sha"] and out["timestamp"] and out["jax_version"]
-    snap = json.loads((tmp_path / "snap.json").read_text())
-    assert snap["bench"] == "t" and snap["git_sha"] == out["git_sha"]
-    recs = load_records(hist)
-    assert len(recs) == 2
-    by_metric = {r["metric"]: r for r in recs}
-    # values normalised to µs; outcome fields stay out of the identity key
-    assert by_metric["us_per_call"]["value_us"] == 5.0
-    assert by_metric["solve_ms"]["value_us"] == 2000.0
-    assert "rounds" not in by_metric["us_per_call"]["key"]
-    assert "op=a" in by_metric["us_per_call"]["key"]
-    # append-only: a second write grows the file
-    write_bench(doc, str(tmp_path / "snap.json"), history_dir=hist)
-    assert len(load_records(hist)) == 4
-    # empty history dir string disables the append, snapshot still written
-    write_bench(doc, str(tmp_path / "snap2.json"), history_dir="")
-    assert (tmp_path / "snap2.json").exists()
-
-
-def _bench_records(value_us, metric="us_per_call", key="bench=t op=a", k=1):
-    return [dict(schema=1, bench="t", key=key, metric=metric,
-                 value_us=v) for v in ([value_us] * k)]
-
-
-def test_bench_diff_verdicts_and_bars():
-    from repro.obs.bench import diff
-
-    base = _bench_records(1000.0)
-    # small drift: inside both bars -> same
-    assert diff(base, _bench_records(1100.0))["status"] == "ok"
-    # 2.5x slowdown: both bars trip -> regression
-    rep = diff(base, _bench_records(2500.0))
-    assert rep["status"] == "regression"
-    assert rep["regressions"][0]["ratio"] == 2.5
-    # mirrored improvement: reported, never failing
-    rep = diff(base, _bench_records(300.0))
-    assert rep["status"] == "ok" and len(rep["improvements"]) == 1
-    # micro-kernel jitter: 1.9x relative but under the 200us floor -> same
-    rep = diff(_bench_records(100.0), _bench_records(190.0))
-    assert rep["status"] == "ok" and not rep["regressions"]
-    # slow op drifting a few percent: over the floor, under the bar -> same
-    rep = diff(_bench_records(100000.0), _bench_records(110000.0))
-    assert rep["status"] == "ok" and not rep["regressions"]
-    # median-of-k: one noisy outlier run must not gate
-    noisy = (_bench_records(1000.0) + _bench_records(1000.0)
-             + _bench_records(5000.0))
-    rep = diff(noisy, _bench_records(1010.0))
-    assert rep["status"] == "ok"
-    assert rep["rows"][0]["base_us"] == 1000.0      # the median, not the max
-    # disjoint keys must fail loudly, not pass vacuously
-    rep = diff(base, _bench_records(1000.0, key="bench=t op=OTHER"))
-    assert rep["status"] == "no-overlap"
-
-
-def test_bench_diff_cli_exit_codes(tmp_path, capsys):
-    from repro.obs.bench import main as bench_main
-
-    def _write(name, records):
-        p = tmp_path / name
-        p.write_text("".join(json.dumps(r) + "\n" for r in records))
-        return str(p)
-
-    base = _write("base.jsonl", _bench_records(1000.0))
-    same = _write("same.jsonl", _bench_records(1050.0))
-    slow = _write("slow.jsonl", _bench_records(2000.0))
-    other = _write("other.jsonl", _bench_records(1000.0, key="bench=u op=z"))
-    assert bench_main([base, same]) == 0
-    assert bench_main([base, slow]) == 1           # synthetic 2x slowdown
-    assert bench_main([base, other]) == 2          # mis-pointed baseline
-    # the report CLI front door dispatches the subcommand too
-    assert report_main(["bench-diff", base, same]) == 0
-    assert report_main(["bench-diff", base, slow, "--json"]) == 1
-    out = capsys.readouterr().out
-    assert '"status": "regression"' in out
-    # raising the relative bar clears the 2x verdict
-    assert bench_main([base, slow, "--rel-bar", "1.5"]) == 0
-
-
-# --------------------------------------------------------------------------
 # §17: Prometheus text exposition
 # --------------------------------------------------------------------------
 
@@ -635,11 +542,3 @@ def test_report_handles_degenerate_traces_and_json(tmp_path, capsys):
     assert d["n_records"] == 1 and d["counts"] == {"rounds": 1}
     doc = report_json(str(path))
     assert doc["records"][0]["summary"]["rounds"] == 1
-    # bench-history records render through the report CLI too
-    hist = tmp_path / "hist.jsonl"
-    hist.write_text("".join(
-        json.dumps(r) + "\n" for r in _bench_records(123.0)
-    ))
-    assert report_main(["report", str(hist)]) == 0
-    out = capsys.readouterr().out
-    assert "us_per_call" in out

@@ -2,7 +2,7 @@
 import numpy as np
 import pytest
 
-from benchmarks.roofline import (
+from repro.perf.roofline import (
     HBM_BW,
     ICI_BW,
     PEAK_FLOPS,
