@@ -62,6 +62,14 @@ _SEEN_SIGNATURE_CAP = 4096     # compile-stat signature set bound (FIFO)
 _AOT_PROGRAM_CACHE = 16        # AOT-compiled programs kept for traced runs
 
 
+def _fetch(result: MISResult):
+    """(in_mis, rounds, converged, stream_share) as host values, in one
+    device→host transfer."""
+    return jax.device_get((
+        result.in_mis, result.rounds, result.converged, result.stream_share,
+    ))
+
+
 @dataclasses.dataclass(frozen=True)
 class SolveResult:
     """One graph's solution, in ORIGINAL vertex numbering.
@@ -431,9 +439,10 @@ class Solver:
         trace: Optional[Trace] = None,
     ) -> SolveResult:
         with trace_span(trace, "solver.fetch"):
-            in_mis_plan = np.asarray(result.in_mis).astype(bool)
-            in_mis = plan.to_original(in_mis_plan).astype(bool)
-            rounds, converged = int(result.rounds), bool(result.converged)
+            in_mis_plan, rounds, converged, share = _fetch(result)
+            in_mis = plan.to_original(in_mis_plan.astype(bool)).astype(bool)
+            rounds, converged = int(rounds), bool(converged)
+            stats = dict(stats, stream_share=self._note_stream_share(share))
         return SolveResult(
             in_mis=in_mis,
             rounds=rounds,
@@ -443,6 +452,14 @@ class Solver:
             stats=stats,
             telemetry=telemetry,
         )
+
+    def _note_stream_share(self, share) -> float:
+        """The solve's `stream_share` (`core.compaction`), fetched: stream
+        entries its rounds read over what the full streams would have read;
+        also the `solve.stream_share` gauge."""
+        share = float(share)
+        self.metrics.gauge("solve.stream_share").set(share)
+        return share
 
     @staticmethod
     def _partition_sig(tiled):
@@ -634,7 +651,8 @@ class Solver:
         self.metrics.counter("solver.solves").inc(len(plans))
         self.metrics.histogram("solver.batch_ms").observe(timing["solve_ms"])
         self._note_attribution(batch.tiled, rt, timing["solve_ms"])
-        converged = bool(result.converged)
+        in_mis, rounds, converged, share = _fetch(result)
+        converged = bool(converged)
 
         # attribution (DESIGN.md §14): ONE dispatch served the whole bucket,
         # so each member's `solve_ms` is its 1/batch share, with the shared
@@ -648,10 +666,11 @@ class Solver:
             solve_ms=round(batch_ms / len(plans), 3),
             batch_ms=batch_ms, bucket=sig,
             compile=compile_stat, batch_size=len(plans), **timing,
+            stream_share=self._note_stream_share(share),
         )
         out = []
         for plan, mis, rnd in zip(
-            plans, batch.unpack(result.in_mis), batch.unpack(result.rounds)
+            plans, batch.unpack(in_mis), batch.unpack(rounds)
         ):
             in_mis_plan = np.asarray(mis).astype(bool)
             out.append(SolveResult(

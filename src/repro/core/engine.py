@@ -45,6 +45,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.compaction import EDGES, TAIL, stream_width
 from repro.core.tiling import (
     BlockTiledGraph,
     dense_tile_mask,
@@ -68,6 +69,7 @@ from repro.obs.rounds import (
     COL_ALIVE,
     COL_FRONTIER,
     COL_SELECTED,
+    COL_STREAM,
     COL_TILES_DENSE,
     COL_TILES_SKIPPED,
     COL_TILES_SPARSE,
@@ -451,7 +453,7 @@ def _tiles_skipped(tiled, flags: Optional[jnp.ndarray]) -> jnp.ndarray:
 
 
 def _telemetry_row(
-    alive, frontier, selected, skipped, tiles_dense, tiles_sparse
+    alive, frontier, selected, skipped, tiles_dense, tiles_sparse, stream
 ) -> jnp.ndarray:
     """(TELEMETRY_COLS,) int32 row in the obs.rounds column layout."""
     vals = [None] * TELEMETRY_COLS
@@ -461,6 +463,7 @@ def _telemetry_row(
     vals[COL_TILES_SKIPPED] = skipped
     vals[COL_TILES_DENSE] = tiles_dense
     vals[COL_TILES_SPARSE] = tiles_sparse
+    vals[COL_STREAM] = stream
     return jnp.stack([jnp.asarray(v, jnp.int32) for v in vals])
 
 
@@ -522,6 +525,13 @@ class RoundEngine:
     # wants the (n_bits, nbc, W) plane stacks built at setup — only the
     # Pallas engines, whose bitwise phase ① can run the plane-scan kernel
     plane_kernel_nbr_max: bool = False
+
+    # -- the per-half-edge streams (the compaction ladder, DESIGN.md §18) --
+    def streams(self, ctx: EngineContext) -> Tuple[str, ...]:
+        """The streams this route's round body reads once per half-edge:
+        "edges" (`ctx.g`'s edge list) and "tail" (the hybrid COO tail).
+        The round loop compacts them to the live half-edges between rounds."""
+        return ()
 
     # -- phase ① ----------------------------------------------------------
     def _nbr_max(
@@ -616,8 +626,9 @@ class RoundEngine:
     ) -> Tuple[MISRoundState, jnp.ndarray]:
         """`step` plus a (TELEMETRY_COLS,) int32 telemetry row: the same
         body, once, with six reductions over what it already computed (no
-        extra SpMVs, no host callbacks).  Packed frontiers count by word
-        popcount — the frontier never densifies."""
+        extra SpMVs, no host callbacks) and the static stream width it
+        read.  Packed frontiers count by word popcount — the frontier
+        never densifies."""
         new, facts = self.round_body(ctx)(ctx, pri, state)
         count = _popcount_words if ctx.frontier == "bitwise" else _count
         with jax.named_scope(SCOPE_P3):   # telemetry counts: ③ bookkeeping
@@ -629,6 +640,7 @@ class RoundEngine:
                 skipped,
                 _tiles_routed_dense(facts.tiled, skipped, facts.flags),
                 jnp.int32(facts.n_sparse_tiles),
+                jnp.int32(stream_width(self, ctx)),
             )
         return new, row
 
@@ -714,6 +726,9 @@ class SegmentEngine(RoundEngine):
 
     name = "segment"
 
+    def streams(self, ctx):
+        return (EDGES,)
+
     def _nbr_max(self, ctx, p, mask):
         return _segment_nbr_max(ctx, p, mask)
 
@@ -757,6 +772,12 @@ class _TiledEngine(RoundEngine):
 
     supports_bitwise = True
     supports_hybrid = True
+
+    def streams(self, ctx):
+        """① reads the edge list unless it runs on tiles; the hybrid tail
+        is read by ② always, and by the tiled ①."""
+        edges = () if ctx.cfg.phase1 == "tiled" else (EDGES,)
+        return edges + ((TAIL,) if ctx.tiled.partition is not None else ())
 
     @_tile
     def _tiled_nbr_max(self, ctx, p, mask) -> jnp.ndarray:

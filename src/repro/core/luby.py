@@ -6,7 +6,7 @@ is one XLA program.
 """
 from __future__ import annotations
 
-from typing import NamedTuple
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -19,6 +19,10 @@ class MISResult(NamedTuple):
     in_mis: jnp.ndarray   # (n,) bool
     rounds: jnp.ndarray   # int32 — rounds to convergence
     converged: jnp.ndarray  # bool — False iff max_rounds hit
+    # float32 — stream entries the round loop read over what the full
+    # streams would have read in the same rounds (core.compaction); None
+    # where no ladder ran
+    stream_share: Optional[jnp.ndarray] = None
 
 
 def luby_mis(g: Graph, key: jax.Array, *, max_rounds: int = 1024) -> MISResult:

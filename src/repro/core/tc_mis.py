@@ -29,11 +29,14 @@ as thin deprecated shims for pre-API callers.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import warnings
 
 import jax
 import jax.numpy as jnp
 
+from repro.core import compaction
+from repro.core.compaction import plan_ladder
 from repro.core.engine import (
     EngineContext,
     MISRoundState,
@@ -52,7 +55,7 @@ from repro.core.tiling import (
 )
 from repro.graphs.graph import Graph
 from repro.obs.rounds import TELEMETRY_COLS, TELEMETRY_FILL
-from repro.obs.trace import SCOPE_INIT, SCOPE_P3, SCOPE_RESULT
+from repro.obs.trace import SCOPE_COMPACT, SCOPE_INIT, SCOPE_P3, SCOPE_RESULT
 
 # back-compat alias: the round state now lives with the engine layer
 TCMISState = MISRoundState
@@ -173,7 +176,9 @@ def _setup(
     return engine, ctx, pri, state0
 
 
-def _result(final: MISRoundState, g: Graph, tiled: BlockTiledGraph) -> MISResult:
+def _result(
+    final: MISRoundState, g: Graph, tiled: BlockTiledGraph, stream_share
+) -> MISResult:
     """Run epilogue — and, for bitwise runs, THE single sanctioned unpack
     site on the solve path: packed `in_mis` words densify here, after the
     convergence loop, never inside it (tools/ci_guards.py allowlists this
@@ -186,6 +191,7 @@ def _result(final: MISRoundState, g: Graph, tiled: BlockTiledGraph) -> MISResult
         in_mis=in_mis[: g.n_nodes],
         rounds=rounds,
         converged=~jnp.any(final.alive),
+        stream_share=stream_share,
     )
 
 
@@ -201,7 +207,7 @@ def _tc_mis_impl(
     member_rounds: bool = False,
     in_mis0: jnp.ndarray | None = None,
 ) -> MISResult:
-    """Run TC-MIS to convergence inside one `lax.while_loop`.
+    """Run TC-MIS to convergence inside `lax.while_loop`s.
 
     The production driver behind `repro.api.Solver.solve`/`solve_many`; the
     whole function is jit-compatible with `config` static, which is how the
@@ -211,38 +217,38 @@ def _tc_mis_impl(
     global round count.  `alive0`+`in_mis0` together are the warm-start
     seam (`repro.dyngraph.repair`): an already-converged warm state runs
     ZERO rounds — the while_loop condition fails on entry.
+
+    Where the route reads a per-half-edge stream longer than the ladder's
+    floor, the rounds run on the compaction ladder (`core.compaction`,
+    DESIGN.md §18): one loop per rung, each carrying the live mask of the
+    streams it compacts on exit.  Otherwise the one loop carries the state
+    alone.  Answers are the same either way.
+
+    Telemetry run (SolveOptions.telemetry; the deprecated TCMISConfig never
+    sets it): the loops also carry a fixed-shape (max_rounds, K) int32
+    buffer, round r writes row r via `engine.step_with_stats`, and the
+    return becomes (result, buffer) — ONE device→host transfer when the
+    caller materialises the buffer at the epilogue (RoundTrace.from_buffer).
+    The flag is static under jit, so the telemetry-off program holds no
+    value of the buffer (DESIGN.md §14).
     """
     with jax.named_scope(SCOPE_INIT):
         engine, ctx, pri, state0 = _setup(
             g, tiled, key, config, priorities, alive0, col_gate, member_rounds,
             in_mis0,
         )
+    telemetry = bool(getattr(config, "telemetry", False))
 
     def cond(state: MISRoundState):
         return jnp.any(state.alive) & (jnp.max(state.rnd) < config.max_rounds)
 
-    if not getattr(config, "telemetry", False):
-        final = jax.lax.while_loop(
-            cond, lambda s: engine.step(ctx, pri, s), state0
-        )
-        with jax.named_scope(SCOPE_RESULT):
-            return _result(final, g, tiled)
-
-    # Telemetry run (SolveOptions.telemetry; the deprecated TCMISConfig
-    # never sets it): the loop carries a fixed-shape (max_rounds, K) int32
-    # buffer, round r writes row r via `engine.step_with_stats`, and the
-    # return becomes (result, buffer) — ONE device→host transfer when the
-    # caller materialises the buffer at the epilogue (RoundTrace.from_buffer).
-    # The flag is static under jit, so the telemetry-off program above stays
-    # the byte-exact pre-telemetry while_loop (DESIGN.md §14).
-    with jax.named_scope(SCOPE_INIT):
-        buf0 = jnp.full(
-            (int(config.max_rounds), TELEMETRY_COLS), TELEMETRY_FILL, jnp.int32
-        )
-
-    def body(carry):
+    def advance(c, carry):
+        """One round on the context `c` of a rung: (state,) or, with
+        telemetry, (state, buffer)."""
+        if not telemetry:
+            return (engine.step(c, pri, carry[0]),)
         s, buf = carry
-        new, row = engine.step_with_stats(ctx, pri, s)
+        new, row = engine.step_with_stats(c, pri, s)
         # max(rnd) is the current round index in BOTH counting modes: a
         # scalar rnd counts rounds directly, and in member_rounds mode every
         # currently-alive vertex has incremented in every prior round (alive
@@ -251,11 +257,108 @@ def _tc_mis_impl(
         with jax.named_scope(SCOPE_P3):
             return new, buf.at[jnp.max(s.rnd)].set(row)
 
-    final, buf = jax.lax.while_loop(
-        lambda c: cond(c[0]), body, (state0, buf0)
+    carry = (state0,)
+    if telemetry:
+        with jax.named_scope(SCOPE_INIT):
+            carry += (jnp.full(
+                (int(config.max_rounds), TELEMETRY_COLS), TELEMETRY_FILL,
+                jnp.int32,
+            ),)
+
+    carry, share = _run_ladder(
+        plan_ladder(engine, ctx), engine, ctx, pri, carry, cond, advance,
+        warm=in_mis0 is not None,
     )
     with jax.named_scope(SCOPE_RESULT):
-        return _result(final, g, tiled), buf
+        result = _result(carry[0], g, tiled, share)
+    return (result, carry[1]) if telemetry else result
+
+
+def _run_ladder(ladder, engine, ctx, pri, carry, cond, advance, *, warm: bool):
+    """The rounds on the compaction ladder (DESIGN.md §18).  Rung k runs
+    while `cond` holds and some stream's live entries exceed its capacity
+    at rung k+1, or while a vertex whose priority is at or below the mask
+    value `_NEG` is alive; those streams are then compacted into rung k+1
+    and the state carries on.  The last rung runs to convergence; a ladder
+    of one rung is the single loop, carrying the state alone.
+
+    The second condition keeps the answers exact: a dropped half-edge has
+    a dead end, and a dead sender gives ①'s max `_NEG` at its receiver,
+    which only a priority at or below `_NEG` can tell from no neighbour at
+    all (H3's `resolve` rank of a hub, -deg·n - id).  Once no such vertex
+    is alive, no comparison of the round can see the dropped entries.
+
+    `warm` (the repair seam's `in_mis0` was given) computes the live masks
+    before rung 0, so a warm start, whose dead vertices hold edges, enters
+    the rung its live entries fit.  Otherwise every half-edge is live at
+    round 0 — the batcher's `alive0` kills only padding slots, which hold
+    no edge — so rung 0 starts from an all-true mask and runs round 0
+    first.  Returns the final carry and the stream share, from the round
+    count at each rung exit: no round reduces anything for it."""
+    streams = compaction.full_streams(ctx)
+    rungs = ladder.rungs
+    widths, exits = [], []
+    masks = low = None
+    for k, caps in enumerate(rungs):
+        c = compaction.bind(ctx, streams)
+        widths.append(compaction.stream_width(engine, c))
+        if k + 1 == len(rungs):
+            carry = jax.lax.while_loop(
+                lambda cr: cond(cr[0]), lambda cr: advance(c, cr), carry
+            )
+            exits.append(jnp.max(carry[0].rnd))
+            break
+        nxt = rungs[k + 1]
+        shrink = tuple(s for s in caps if nxt[s] < caps[s])
+        if masks is None:
+            with jax.named_scope(SCOPE_COMPACT):
+                low = compaction.low_priority(pri, carry[0].alive, ctx.tiled)
+                masks = compaction.live_masks(
+                    streams, shrink, carry[0].alive, ctx.tiled
+                ) if warm else tuple(
+                    jnp.ones((caps[s],), jnp.bool_) for s in shrink
+                )
+
+        def rung_cond(cr, shrink=shrink, nxt=nxt):
+            over = [jnp.sum(m, dtype=jnp.int32) > nxt[s]
+                    for s, m in zip(shrink, cr[-1])]
+            over.append(jnp.any((cr[0].alive & low) != 0))
+            return cond(cr[0]) & functools.reduce(jnp.logical_or, over)
+
+        def rung_body(cr, c=c, cur=dict(streams), shrink=shrink):
+            out = advance(c, cr[:-1])
+            with jax.named_scope(SCOPE_COMPACT):
+                live = compaction.live_masks(cur, shrink, out[0].alive, c.tiled)
+            return (*out, live)
+
+        *carry, masks = jax.lax.while_loop(rung_cond, rung_body, (*carry, masks))
+        carry = tuple(carry)
+        exits.append(jnp.max(carry[0].rnd))
+        with jax.named_scope(SCOPE_COMPACT):
+            counts = [jnp.sum(m, dtype=jnp.int32) for m in masks]
+            for s, m in zip(shrink, masks):
+                streams[s] = compaction.compact(
+                    m, streams[s], nxt[s], compaction.sentinel(s, ctx)
+                )
+            if ladder.shared:
+                streams[compaction.TAIL] = compaction.shared_tail(
+                    streams[compaction.EDGES], ctx
+                )
+            # the compacted entries are the live ones, in the first slots
+            after = rungs[k + 2] if k + 2 < len(rungs) else nxt
+            masks = tuple(
+                jnp.arange(nxt[s], dtype=jnp.int32) < n
+                for s, n in zip(shrink, counts) if after[s] < nxt[s]
+            )
+    with jax.named_scope(SCOPE_RESULT):
+        rounds = exits[-1]
+        read = sum(
+            (done - before) * jnp.float32(w)
+            for done, before, w in zip(exits, [jnp.int32(0)] + exits[:-1], widths)
+        )
+        full = rounds * jnp.float32(widths[0])
+        share = jnp.where(full > 0, read / jnp.maximum(full, 1.0), 1.0)
+    return carry, share.astype(jnp.float32)
 
 
 # --------------------------------------------------------------------------

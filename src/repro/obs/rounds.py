@@ -3,7 +3,8 @@
 The device side (DESIGN.md §14): when `SolveOptions.telemetry` is on,
 `_tc_mis_impl` threads a fixed-shape ``(max_rounds, TELEMETRY_COLS)`` int32
 buffer through the round `while_loop`.  Each executed round r writes row r
-with six cheap reductions over state the round body already holds —
+with six cheap reductions over state the round body already holds, and
+the static width of the streams it read —
 no extra SpMVs, no host callbacks, ONE device→host transfer at the
 epilogue:
 
@@ -18,6 +19,10 @@ epilogue:
                               skipped)
     col 5  COL_TILES_SPARSE   tiles routed through the COO/segment tail
                               (0 outside hybrid)
+    col 6  COL_STREAM         per-half-edge stream entries the round read:
+                              the edge list and tail capacities of its
+                              rung of the compaction ladder (DESIGN.md §18;
+                              0 on a route that reads no stream)
 
 Rows past the executed round count stay at the fill value −1, which is how
 `RoundTrace.from_buffer` distinguishes "round never ran" from a legitimate
@@ -35,13 +40,14 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
-TELEMETRY_COLS = 6
+TELEMETRY_COLS = 7
 COL_ALIVE = 0
 COL_FRONTIER = 1
 COL_SELECTED = 2
 COL_TILES_SKIPPED = 3
 COL_TILES_DENSE = 4
 COL_TILES_SPARSE = 5
+COL_STREAM = 6
 
 # rows beyond the executed rounds keep this fill; col 0 (alive) is never
 # negative for an executed round, so it doubles as the row-validity mark
@@ -49,7 +55,7 @@ TELEMETRY_FILL = -1
 
 COLUMN_NAMES = (
     "alive", "frontier", "selected", "tiles_skipped",
-    "tiles_dense", "tiles_sparse",
+    "tiles_dense", "tiles_sparse", "stream",
 )
 
 
@@ -68,6 +74,7 @@ class RoundTrace:
     tiles_skipped: List[int]
     tiles_dense: List[int] = field(default_factory=list)
     tiles_sparse: List[int] = field(default_factory=list)
+    stream: List[int] = field(default_factory=list)
     tiles_total: int = 0
     meta: Dict[str, object] = field(default_factory=dict)
 
@@ -102,6 +109,7 @@ class RoundTrace:
             tiles_skipped=[int(v) for v in used[:, COL_TILES_SKIPPED]],
             tiles_dense=[int(v) for v in used[:, COL_TILES_DENSE]],
             tiles_sparse=[int(v) for v in used[:, COL_TILES_SPARSE]],
+            stream=[int(v) for v in used[:, COL_STREAM]],
             tiles_total=int(tiles_total),
             meta=dict(meta or {}),
         )
@@ -117,6 +125,7 @@ class RoundTrace:
             tiles_skipped=list(self.tiles_skipped),
             tiles_dense=list(self.tiles_dense),
             tiles_sparse=list(self.tiles_sparse),
+            stream=list(self.stream),
             tiles_total=self.tiles_total,
             meta=dict(self.meta),
         )
@@ -131,6 +140,7 @@ class RoundTrace:
             tiles_skipped=[int(v) for v in d["tiles_skipped"]],
             tiles_dense=[int(v) for v in d.get("tiles_dense", [])],
             tiles_sparse=[int(v) for v in d.get("tiles_sparse", [])],
+            stream=[int(v) for v in d.get("stream", [])],
             tiles_total=int(d.get("tiles_total", 0)),
             meta=dict(d.get("meta", {})),
         )

@@ -48,12 +48,14 @@ from typing import Dict, List, Optional
 from jax.profiler import TraceAnnotation
 
 # Named scopes of the solve program (core.tc_mis, core.engine): the round
-# body's three phases, and the code outside the round loop.
+# body's three phases, the code outside the round loop, and the compaction
+# ladder's masks and moves (core.compaction).
 SCOPE_INIT = "mis.init"         # priorities, packed structures, state₀
 SCOPE_P1 = "mis.p1"             # ① candidates (both H3 Max_Np passes)
 SCOPE_P2 = "mis.p2"             # ② hits / counts (fused: the ②+③ kernel)
 SCOPE_P3 = "mis.p3"             # ③ state update / merge
 SCOPE_RESULT = "mis.result"     # epilogue: unpack, slice
+SCOPE_COMPACT = "mis.compact"   # live-stream masks and their compaction
 # Path sub-scopes inside a phase: which substrate ran the op.
 PATH_EDGE = "edge"              # per-edge gather/scatter (edge list, COO tail)
 PATH_TILE = "tile"              # the tile schedule (jnp or Pallas)

@@ -242,3 +242,33 @@ def test_profiler_capture_holds_the_solve_spans(tmp_path):
             for line in plane.lines for e in line.events}
     assert {"solver.solve", "solver.plan", "solver.execute",
             "solver.fetch"} <= host
+
+
+def test_ladder_rungs_and_compaction_carry_their_scopes():
+    """On a graph whose edge list ladders (DESIGN.md §18) every rung's body
+    op lies under a phase scope or `mis.compact` (its live mask), and the
+    compaction between rungs is named `mis.compact` too."""
+    from repro.graphs.generators import grid2d
+
+    solver = Solver(SolveOptions(engine="segment"))
+    plan = solver.plan(grid2d(100, 100, seed=2))
+    scopes = solver.program_scopes(plan)
+    assert "mis.compact" in set(scopes.values())
+    hlo = solver._jit_single.lower(
+        plan.g, plan.tiled, jax.random.key(0)).compile().as_text()
+    lines = hlo.splitlines()
+    bodies = {re.search(r"body=%([^\s,]+)", line).group(1) for line in lines
+              if " while(" in line and "mis." not in line.split("op_name=")[-1]}
+    assert len(bodies) >= 2
+    for body in bodies:
+        start = next(i for i, line in enumerate(lines)
+                     if line.startswith(f"%{body} "))
+        for line in lines[start + 1:]:
+            if line.startswith("}"):
+                break
+            m = re.match(r"^\s*(?:ROOT )?%([^\s=]+) = (.*)$", line)
+            op = re.search(r"(?<![\w.\-])([a-z][a-z0-9\-]*)\(", m.group(2))
+            if op.group(1) in FREE:
+                continue
+            assert scopes[m.group(1)].split("/")[0] in {
+                "mis.p1", "mis.p2", "mis.p3", "mis.compact"}, line

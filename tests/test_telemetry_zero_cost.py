@@ -3,7 +3,10 @@ program: with `telemetry=False` the solve holds no value of the round
 buffer's shape and its round loop carries exactly the `MISRoundState`
 leaves; with `telemetry=True` the buffer rides in that carry.  One case per
 engine × route (dense or packed frontier, whole tiling or hybrid
-partition).  Traces only — nothing compiles."""
+partition), on a graph too small for the compaction ladder and on one
+whose streams ladder (DESIGN.md §18): there every rung loop carries the
+state, the buffer only with telemetry on, and the live masks only on the
+rungs that compact.  Traces only — nothing compiles."""
 import jax
 import numpy as np
 import pytest
@@ -26,17 +29,22 @@ CASES = [(e, "dense", "off") for e in engine_names()] + [
 ]
 
 
-def _graph():
+def _graph(n=160, m=320):
     """Sparse random edges plus one dense cluster: at T=16 and a hybrid
     threshold of 24, a forced partition keeps dense tiles and a sparse
     tail both."""
     rng = np.random.default_rng(7)
-    u = rng.integers(0, 160, 320)
-    v = rng.integers(0, 160, 320)
+    u = rng.integers(0, n, m)
+    v = rng.integers(0, n, m)
     cu, cv = np.triu_indices(24, 1)
     u, v = np.concatenate([u, cu]), np.concatenate([v, cv])
     keep = u != v
-    return from_edges(u[keep], v[keep], 160)
+    return from_edges(u[keep], v[keep], n)
+
+
+def _laddered_graph():
+    """The same shape with streams well past the ladder's floor."""
+    return _graph(n=6000, m=12_000)
 
 
 def _eqns(jaxpr):
@@ -54,8 +62,8 @@ def _sig(aval):
     return tuple(aval.shape), str(aval.dtype)
 
 
-def _trace(engine, frontier, hybrid, telemetry):
-    """(shapes of every value in the traced solve, the round loop's carry,
+def _trace_loops(engine, frontier, hybrid, telemetry, graph):
+    """(shapes of every value in the traced solve, each round loop's carry,
     the `MISRoundState` leaves) as (shape, dtype) signatures."""
     opts = SolveOptions(
         engine=engine, frontier=frontier, hybrid=hybrid, phase1="tiled",
@@ -63,7 +71,7 @@ def _trace(engine, frontier, hybrid, telemetry):
         tile_size=16, hybrid_threshold=24, placement="local",
         max_rounds=MAX_ROUNDS, telemetry=telemetry,
     )
-    plan = Solver(opts).plan(_graph())
+    plan = Solver(opts).plan(graph)
     part = plan.tiled.partition
     if hybrid == "forced":
         assert part.n_dense_tiles > 0 and part.n_sparse_tiles > 0
@@ -73,16 +81,25 @@ def _trace(engine, frontier, hybrid, telemetry):
     state = jax.eval_shape(lambda g, t, k: _setup(g, t, k, opts)[3], *args)
     jaxpr = jax.make_jaxpr(lambda g, t, k: _tc_mis_impl(g, t, k, opts))(*args).jaxpr
     loops = [e for e in jaxpr.eqns if e.primitive.name == "while"]
-    assert len(loops) == 1
-    n_consts = loops[0].params["cond_nconsts"] + loops[0].params["body_nconsts"]
-    carry = [_sig(v.aval) for v in loops[0].invars[n_consts:]]
+    carries = []
+    for loop in loops:
+        n_consts = loop.params["cond_nconsts"] + loop.params["body_nconsts"]
+        carries.append([_sig(v.aval) for v in loop.invars[n_consts:]])
     shapes = {
         tuple(v.aval.shape)
         for eqn in _eqns(jaxpr)
         for v in (*eqn.invars, *eqn.outvars)
         if hasattr(v.aval, "shape")
     }
-    return shapes, carry, [_sig(x) for x in jax.tree.leaves(state)]
+    return shapes, carries, [_sig(x) for x in jax.tree.leaves(state)]
+
+
+def _trace(engine, frontier, hybrid, telemetry):
+    """`_trace_loops` on the small graph, whose solve is one round loop."""
+    shapes, carries, state = _trace_loops(
+        engine, frontier, hybrid, telemetry, _graph())
+    assert len(carries) == 1
+    return shapes, carries[0], state
 
 
 @pytest.mark.parametrize("engine,frontier,hybrid", CASES)
@@ -97,3 +114,25 @@ def test_telemetry_off_traces_no_buffer(engine, frontier, hybrid):
     assert state_on == state
     assert carry == state + [buf]
     assert buf[0] in shapes   # the walk reaches the values it looks through
+
+
+@pytest.mark.parametrize("engine,frontier,hybrid", CASES)
+def test_every_rung_loop_keeps_the_contract(engine, frontier, hybrid):
+    """A route that reads a per-half-edge stream runs one loop per rung;
+    each carries the state first, then the buffer only with telemetry on,
+    then the live masks — (capacity,) bools — only on a rung that
+    compacts, which every rung but the last does."""
+    buf = ((MAX_ROUNDS, TELEMETRY_COLS), "int32")
+    streams = engine == "segment" or hybrid == "forced"
+    graph = _laddered_graph()
+    for telemetry in (False, True):
+        shapes, carries, state = _trace_loops(
+            engine, frontier, hybrid, telemetry, graph)
+        assert (len(carries) > 1) == streams
+        extra = [buf] if telemetry else []
+        for k, carry in enumerate(carries):
+            assert carry[: len(state) + len(extra)] == state + extra
+            masks = carry[len(state) + len(extra):]
+            assert bool(masks) == (k + 1 < len(carries))
+            assert all(len(m[0]) == 1 and m[1] == "bool" for m in masks)
+        assert (buf[0] in shapes) == telemetry
