@@ -45,14 +45,14 @@ import numpy as np
 from repro.api.options import SolveOptions
 from repro.api.plan import Plan, PlanCache, choose_tile_size, resolve_storage
 from repro.core.engine import get_engine, resolve_frontier
-from repro.core.heuristics import make_priorities
+from repro.core.heuristics import PACKED, make_priorities
 from repro.core.luby import MISResult
 from repro.core.tc_mis import _tc_mis_impl
 from repro.core.tiling import full_tiling
 from repro.graphs.graph import Graph
 from repro.obs.metrics import MetricsRegistry
 from repro.obs.rounds import RoundTrace
-from repro.obs.trace import Trace, hlo_scopes, trace_span
+from repro.obs.trace import SCOPE_PRIORITIES, Trace, hlo_scopes, trace_span
 from repro.perf.roofline import device_peaks, round_cost_attribution
 
 GraphLike = Union[Graph, Plan]
@@ -60,6 +60,8 @@ GraphLike = Union[Graph, Plan]
 _DIST_PROGRAM_CACHE = 16       # shard_map closures kept per Solver (LRU)
 _SEEN_SIGNATURE_CAP = 4096     # compile-stat signature set bound (FIFO)
 _AOT_PROGRAM_CACHE = 16        # AOT-compiled programs kept for traced runs
+_PACK_CACHE_ENTRIES = 8        # packed batch structures kept per Solver (LRU)
+_PACK_CACHE_BYTES = 2 << 30    # ...and the device bytes they may hold
 
 
 def _fetch(result: MISResult):
@@ -124,11 +126,15 @@ class Solver:
             max_mem_entries=options.plan_cache_entries,
         )
         self._base_key = jax.random.key(options.seed)
-        # host-side per-member priority cache for the batcher (sound per
-        # Solver: one base key, one heuristic, and ONLY default request_keys
-        # — solve_many bypasses it when the caller supplies custom keys,
-        # since entries are keyed by plan content alone)
+        # host-side per-member priority cache for the batcher's host
+        # heuristics (sound per Solver: one base key, one heuristic, and
+        # ONLY default request_keys — solve_many bypasses it when the caller
+        # supplies custom keys, since entries are keyed by plan content alone)
         self._priority_cache: Dict = {}
+        # packed batch structures by (member plan keys, storage), LRU and
+        # bounded by entries and device bytes: a restart of the same members
+        # under fresh keys rebuilds and copies nothing key-independent
+        self._packs: "OrderedDict[tuple, object]" = OrderedDict()
         # bounded FIFO set behind the `compile: reused|compiled` stat (note:
         # jax's own jit cache still grows per distinct static shape — a
         # stream of unboundedly many distinct single-graph shapes should
@@ -143,7 +149,8 @@ class Solver:
         self._aot: "OrderedDict[tuple, object]" = OrderedDict()
         # the metrics registry behind the legacy `stats` view (repro.obs)
         self.metrics = MetricsRegistry("solver")
-        for k in ("solver.solves", "solver.batches", "solver.compiles"):
+        for k in ("solver.solves", "solver.batches", "solver.compiles",
+                  "solver.pack_cache.hits", "solver.pack_cache.misses"):
             self.metrics.counter(k)
         # the two compiled-program seams: jax's jit cache keys on the packed
         # containers' static shape buckets, so a steady request mix converges
@@ -151,13 +158,22 @@ class Solver:
         self._jit_single = jax.jit(
             lambda g, tiled, key: _tc_mis_impl(g, tiled, key, options)
         )
-        self._jit_packed = jax.jit(
-            lambda g, tiled, pri, alive0, gate: _tc_mis_impl(
+        # the packed program takes the stacked member keys and builds H3's
+        # priorities itself (`core.heuristics.PACKED`); other heuristics
+        # arrive as packed priority vectors
+        packed_priorities = PACKED.get(options.heuristic)
+
+        def packed(g, tiled, alive0, gate, pri, slots):
+            if packed_priorities is not None:
+                with jax.named_scope(SCOPE_PRIORITIES):
+                    pri = packed_priorities(pri, slots)
+            return _tc_mis_impl(
                 g, tiled, self._base_key, options,
                 priorities=pri, alive0=alive0, col_gate=gate,
                 member_rounds=True,
             )
-        )
+
+        self._jit_packed = jax.jit(packed)
         # the warm-start (delta-repair) program; built on the first
         # `update` — repro.dyngraph imports the serving layer, so the seam
         # resolves lazily rather than at api-import time
@@ -297,7 +313,9 @@ class Solver:
         local-routed members pack block-diagonally (grouped by tile size,
         since a batch must share T) into ONE dispatch each; sharded-routed
         members peel off to their own shard_map dispatch.  Results keep the
-        input order.
+        input order.  `keys` may be one stacked key array; a batch of the
+        same member plans as a recent call takes its packed structure from
+        the pack cache (`_pack`), so best-of-K restarts move only keys.
         """
         with trace_span(trace, "solver.plan"):
             plans = [self._plan(g, trace) for g in graphs]
@@ -327,8 +345,11 @@ class Solver:
                 i = idxs[0]
                 out[i] = self._solve_local(plans[i], keys[i], trace)
                 continue
+            # a stacked key array of the whole input passes on unsliced
+            whole = isinstance(keys, jax.Array) and len(idxs) == len(plans)
             solved = self._solve_batched(
-                [plans[i] for i in idxs], [keys[i] for i in idxs],
+                [plans[i] for i in idxs],
+                keys if whole else [keys[i] for i in idxs],
                 use_priority_cache=default_keys, trace=trace,
             )
             for i, r in zip(idxs, solved):
@@ -619,6 +640,62 @@ class Solver:
             compile=compile_stat, batch_size=1, **timing,
         ), telemetry=rt, trace=trace)
 
+    def _pack(self, plans: Sequence[Plan], trace: Optional[Trace]):
+        """The packed structure of `plans`, from the pack cache or built
+        under a `pack.structure` span; sets the `batch.edge_fill` and
+        `batch.tile_fill` gauges."""
+        from repro.serve_mis.batcher import pack_batch
+
+        key = (tuple(p.key for p in plans), plans[0].tiled.storage)
+        batch = self._packs.get(key)
+        if batch is not None:
+            self._packs.move_to_end(key)
+            self.metrics.counter("solver.pack_cache.hits").inc()
+        else:
+            self.metrics.counter("solver.pack_cache.misses").inc()
+            with trace_span(trace, "pack.structure"):
+                batch = pack_batch(plans)
+            self._packs[key] = batch
+            while self._packs and (
+                len(self._packs) > _PACK_CACHE_ENTRIES
+                or sum(b.device_bytes for b in self._packs.values())
+                > _PACK_CACHE_BYTES
+            ):
+                self._packs.popitem(last=False)
+        self.metrics.gauge("batch.edge_fill").set(batch.edge_fill)
+        self.metrics.gauge("batch.tile_fill").set(batch.tile_fill)
+        return batch
+
+    def _packed_args(self, plans, keys, use_priority_cache, trace):
+        """(structure, arguments of the packed program) for one call."""
+        from repro.serve_mis.batcher import stack_keys
+
+        with trace_span(trace, "solver.pack", batch_size=len(plans)):
+            batch = self._pack(plans, trace)
+            if self.options.heuristic in PACKED:
+                pri = stack_keys(keys, batch.bucket.n_members)
+            else:
+                pri = batch.priorities(
+                    plans, keys, self.options.heuristic,
+                    priority_cache=self._priority_cache
+                    if use_priority_cache else None,
+                )
+        return batch, (batch.g, batch.tiled, batch.alive0, batch.col_gate,
+                       pri, batch.slots)
+
+    def batch_program_scopes(self, graphs: Iterable[GraphLike]) -> Dict[str, str]:
+        """`{op name: "mis.*" scope path}` for the packed program that
+        `solve_many(graphs)` runs, when its members pack into one batch:
+        `program_scopes` for a batch.  A cache load after a warm call under
+        a persistent compile cache, as there."""
+        plans = [self.plan(g) for g in graphs]
+        if len(plans) < 2 or any(self.route(p) != "local" for p in plans) or \
+                len({(p.tile_size, p.tiled.storage) for p in plans}) > 1:
+            raise ValueError("the graphs do not pack into one local batch")
+        keys = [self.request_key(p) for p in plans]
+        _, args = self._packed_args(plans, keys, True, None)
+        return hlo_scopes(self._jit_packed.lower(*args).compile().as_text())
+
     def _solve_batched(
         self,
         plans: Sequence[Plan],
@@ -626,23 +703,14 @@ class Solver:
         use_priority_cache: bool = True,
         trace: Optional[Trace] = None,
     ) -> List[SolveResult]:
-        from repro.serve_mis.batcher import pack_batch
-
-        with trace_span(trace, "solver.pack", batch_size=len(plans)):
-            batch = pack_batch(
-                plans, keys, self.options.heuristic,
-                priority_cache=self._priority_cache if use_priority_cache
-                else None,
-            )
+        batch, args = self._packed_args(plans, keys, use_priority_cache, trace)
         sig = batch.signature()
         compile_stat = self._note_signature(sig)
         self.metrics.counter("solver.batches").inc()
         self.metrics.histogram("solver.batch_size").observe(len(plans))
 
         out_raw, timing = self._dispatch(
-            self._jit_packed, sig, compile_stat, trace,
-            batch.g, batch.tiled, batch.priorities, batch.alive0,
-            batch.col_gate,
+            self._jit_packed, sig, compile_stat, trace, *args
         )
         result, rt = self._split_telemetry(
             out_raw, batch.g, batch.tiled, scope="batch",

@@ -22,14 +22,25 @@ actually lives — in the *resolution order of ties of the quantised priority*:
 
 ECL-MIS itself uses Eq. 1 at its native ~8-bit discretisation with hashed tie
 break, which is what `ecl_priorities` provides.
+
+Packed batches (serve_mis.batcher) give every member the priorities of its
+solo solve.  H3's are a per-vertex function of the member's key, size and
+degrees — its random draw is prefix-consistent, element i the same whatever
+the length drawn — so `h3_priorities_packed` builds every member's at once
+inside the packed program (`PACKED`).  H1, H2 and ECL draw a permutation,
+whose prefix is no smaller permutation; their members' priorities are
+built one by one on the host (`make_priorities`).
 """
 from __future__ import annotations
 
-import dataclasses
 from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax.extend.random import threefry2x32_p
+
+from repro.core.spmv import _NEG
 
 # low-bit budget for the distinctness permutation: supports |V| < 2^23 ≈ 8.4M,
 # which covers the paper's whole suite (max 4.85M vertices).
@@ -53,6 +64,12 @@ def _perm(key: jax.Array, n: int) -> jnp.ndarray:
     return jax.random.permutation(key, jnp.arange(n, dtype=jnp.int32))
 
 
+def _eq1_levels(deg_f, dbar, eps, bits: int) -> jnp.ndarray:
+    p = dbar / (dbar + deg_f - eps)
+    levels = (1 << bits) - 1
+    return jnp.clip((p * levels).astype(jnp.int32), 0, levels)
+
+
 def eq1_quantized(
     deg: jnp.ndarray, key: jax.Array, bits: int
 ) -> jnp.ndarray:
@@ -60,9 +77,7 @@ def eq1_quantized(
     deg_f = deg.astype(jnp.float32)
     dbar = jnp.mean(deg_f)
     eps = jax.random.uniform(key, deg.shape, minval=0.0, maxval=1.0)
-    p = dbar / (dbar + deg_f - eps)
-    levels = (1 << bits) - 1
-    return jnp.clip((p * levels).astype(jnp.int32), 0, levels)
+    return _eq1_levels(deg_f, dbar, eps, bits)
 
 
 def h1_priorities(key: jax.Array, n: int, deg: jnp.ndarray) -> Priorities:
@@ -89,9 +104,70 @@ def h3_priorities(key: jax.Array, n: int, deg: jnp.ndarray) -> Priorities:
     q = eq1_quantized(deg, kq, bits=8)
     # ordered resolution: lower degree wins, then lower id — encode as a
     # strictly decreasing function so "larger key wins" stays the convention.
-    n_arr = jnp.int32(n)
-    rank = (-deg.astype(jnp.int32)) * n_arr - jnp.arange(n, dtype=jnp.int32)
+    rank = _h3_rank(deg, jnp.int32(n), jnp.arange(n, dtype=jnp.int32))
     return Priorities(select=(q << _LOW_BITS), resolve=rank)
+
+
+def _h3_rank(deg, n, ids) -> jnp.ndarray:
+    return (-deg.astype(jnp.int32)) * n - ids
+
+
+class MemberSlots(NamedTuple):
+    """Where each slot of a packed vertex vector sits in its member graph:
+    the key-independent inputs of `h3_priorities_packed`.
+
+    member: (n,) int32 — the slot's member index, -1 in padding slots
+    local:  (n,) int32 — the slot's vertex id within its member
+    deg:    (n,) int32 — the slot's degree in its member graph
+    sizes:  (M,) int32 — each member's vertex count (0 past the last)
+    """
+    member: jnp.ndarray
+    local: jnp.ndarray
+    deg: jnp.ndarray
+    sizes: jnp.ndarray
+
+
+def _uniform_at(key_data: jnp.ndarray, index: jnp.ndarray) -> jnp.ndarray:
+    """`jax.random.uniform(key, (n,))[index]` for each row of `key_data`
+    (threefry key words) and its `index`, for any n > index.  Under the
+    partitionable threefry (jax's default) element i of a draw hashes the
+    counter (0, i) alone, whatever n; its float is the one `uniform` makes
+    of the 32 bits, in [0, 1)."""
+    if not jax.config.jax_threefry_partitionable:
+        raise ValueError("packed H3 priorities need jax_threefry_partitionable, "
+                         "under which a draw's prefix does not depend on its length")
+    b1, b2 = threefry2x32_p.bind(
+        key_data[:, 0], key_data[:, 1],
+        jnp.zeros(index.shape, jnp.uint32), index.astype(jnp.uint32),
+    )
+    bits = (b1 ^ b2) >> np.uint32(32 - 23) | np.uint32(0x3F800000)
+    return jax.lax.bitcast_convert_type(bits, jnp.float32) - jnp.float32(1.0)
+
+
+def h3_priorities_packed(keys: jax.Array, slots: MemberSlots) -> Priorities:
+    """H3 priorities of every member of a packed batch, member m under
+    `keys[m]`: each slot holds exactly what `h3_priorities(keys[m], n_m,
+    deg_m)` gives its vertex, and padding slots hold `_NEG`.
+
+    Bit-identical to the solo construction where a member's degree sum
+    (its half-edge count) is below 2^24: d̄ is then an exact float32 sum
+    in either order of summation."""
+    real = slots.member >= 0
+    m = jnp.maximum(slots.member, 0)
+    kq = jax.vmap(lambda k: jax.random.split(k)[0])(keys)
+    if "threefry" not in str(jax.random.key_impl(kq)):
+        raise ValueError("packed H3 priorities need threefry keys")
+    eps = _uniform_at(jax.random.key_data(kq)[m], slots.local)
+    total = jax.ops.segment_sum(jnp.where(real, slots.deg, 0), m,
+                                num_segments=slots.sizes.shape[0])
+    dbar = total.astype(jnp.float32) / slots.sizes.astype(jnp.float32)
+    q = _eq1_levels(slots.deg.astype(jnp.float32), dbar[m], eps, bits=8)
+    rank = _h3_rank(slots.deg, slots.sizes[m], slots.local)
+    neg = jnp.int32(_NEG)
+    return Priorities(
+        select=jnp.where(real, q << _LOW_BITS, neg),
+        resolve=jnp.where(real, rank, neg),
+    )
 
 
 def ecl_priorities(key: jax.Array, n: int, deg: jnp.ndarray) -> Priorities:
@@ -107,6 +183,10 @@ HEURISTICS = {
     "h3": h3_priorities,
     "ecl": ecl_priorities,
 }
+
+
+# heuristics whose packed-batch priorities the packed program builds itself
+PACKED = {"h3": h3_priorities_packed}
 
 
 def make_priorities(

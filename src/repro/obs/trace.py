@@ -20,7 +20,10 @@ Span taxonomy (DESIGN.md §14) — names are dotted, layer-first:
                             tiles (the full list, or the hybrid dense
                             sub-tiling)
         plan.tail           hybrid COO tail from the sub-threshold cells
-      solver.pack           block-diagonal batch packing
+      solver.pack           block-diagonal batch packing: the cached
+                            structure and the call's priorities or keys
+        pack.structure      the key-independent structure (pack-cache
+                            miss only)
       solver.compile        cold-path lower().compile() (AOT; cache misses only)
       solver.execute        compiled-program dispatch + block_until_ready
       solver.fetch          device→host copy of the answer, un-permute
@@ -56,6 +59,7 @@ SCOPE_P2 = "mis.p2"             # ② hits / counts (fused: the ②+③ kernel)
 SCOPE_P3 = "mis.p3"             # ③ state update / merge
 SCOPE_RESULT = "mis.result"     # epilogue: unpack, slice
 SCOPE_COMPACT = "mis.compact"   # live-stream masks and their compaction
+SCOPE_PRIORITIES = "mis.priorities"  # a packed batch's member priorities
 # Path sub-scopes inside a phase: which substrate ran the op.
 PATH_EDGE = "edge"              # per-edge gather/scatter (edge list, COO tail)
 PATH_TILE = "tile"              # the tile schedule (jnp or Pallas)

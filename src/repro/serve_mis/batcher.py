@@ -9,10 +9,10 @@ The packing is block-diagonal BSR concatenation of cached `TilePlan`s:
   graphs** — the batch adjacency is exactly block-diagonal and each
   member's neighbourhood structure is untouched;
 * priorities are computed **per member** from its own key and degree
-  statistics (Eq. 1's d̄ is a per-graph mean), then placed at the member's
-  offset.  Zero cross-graph edges + per-graph priorities ⇒ each slot's
-  round dynamics are bit-identical to a solo `tc_mis` run of that member,
-  so the packed solve provably returns every member's solo MIS;
+  statistics (Eq. 1's d̄ is a per-graph mean), at the member's offset.
+  Zero cross-graph edges + per-graph priorities ⇒ each slot's round
+  dynamics are bit-identical to a solo `tc_mis` run of that member, so
+  the packed solve provably returns every member's solo MIS;
 * padding-slot vertices start **dead** (`alive0`) — they never join the
   set, never cost a round — and the static `col_gate` pins their block
   columns inactive for the engine's empty-C tile skip (core.engine);
@@ -35,6 +35,15 @@ The packing is block-diagonal BSR concatenation of cached `TilePlan`s:
 Tile lists concatenate from the plan cache — a batch offsets its members'
 cached tiles (or their hybrid partitions); it re-tiles only a partitioned
 member of a pack that cannot stay hybrid, whose full list it rebuilds.
+
+The pack is in two parts (DESIGN.md §19).  `pack_batch` builds the
+key-independent structure — graph, tiling, `alive0`, `col_gate`, offsets,
+and the `MemberSlots` the packed program builds H3 priorities from — which
+`repro.api.Solver` caches per set of member plans, so a restart under
+fresh keys rebuilds and copies none of it.  `PackedBatch.priorities`
+gives a call's priorities: H3's from the stacked member keys by the same
+function the packed program runs (`core.heuristics.PACKED`), the other
+heuristics' member by member on the host.
 """
 from __future__ import annotations
 
@@ -45,7 +54,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.heuristics import Priorities, make_priorities
+from repro.core.heuristics import (
+    PACKED,
+    MemberSlots,
+    Priorities,
+    make_priorities,
+)
 from repro.core.spmv import _NEG
 from repro.core.tiling import (
     BlockTiledGraph,
@@ -69,6 +83,7 @@ class Bucket(NamedTuple):
     n_tiles_pad: int   # padded stored-tile count
     e_pad: int         # padded half-edge count
     storage: str = "int8"   # tile storage format (members must agree)
+    n_members: int = 2      # padded member count: the stacked keys' length
 
 
 def bucket_for(plans: Sequence[TilePlan], tile_size: int) -> Bucket:
@@ -83,6 +98,7 @@ def bucket_for(plans: Sequence[TilePlan], tile_size: int) -> Bucket:
         n_tiles_pad=next_pow2(max(tiles, 8)),
         e_pad=next_pow2(max(edges, 8)),
         storage=plans[0].tiled.storage if plans else "int8",
+        n_members=next_pow2(max(len(plans), 2)),
     )
 
 
@@ -97,9 +113,10 @@ def request_key(base_key: jax.Array, plan: TilePlan) -> jax.Array:
     return jax.random.fold_in(base_key, int(plan.graph_key[:8], 16) & 0x7FFFFFFF)
 
 
-# host-side (select, resolve) per plan content hash — see pack_batch.
-# Bounded FIFO: priority vectors are small next to plans, but a production
-# stream of distinct graphs must not grow host memory without limit.
+# host-side (select, resolve) per plan content hash — see
+# `PackedBatch.priorities`.  Bounded FIFO: priority vectors are small next
+# to plans, but a production stream of distinct graphs must not grow host
+# memory without limit.
 PriorityCache = Dict[str, Tuple[np.ndarray, Optional[np.ndarray]]]
 PRIORITY_CACHE_CAP = 4096
 
@@ -110,14 +127,15 @@ def _member_priorities(
     heuristic: str,
     cache: Optional[PriorityCache],
 ) -> Tuple[np.ndarray, Optional[np.ndarray]]:
-    """Priorities for one member, as host arrays ready to place in a slot.
+    """Priorities for one member, as host arrays ready to place in a slot
+    (the heuristics outside `core.heuristics.PACKED`).
 
     Priorities are a pure function of (plan content, heuristic, key), and
     with `request_key` the key itself is content-derived — so a warm-path
     batch of already-seen graphs skips the per-member `degrees()` dispatch
     and priority construction entirely via `cache` (keyed by plan content
     hash; callers mixing base keys or heuristics must use separate caches,
-    as `MISService` does by owning one cache per service instance).
+    as `Solver` does by owning one cache per solver).
     """
     if cache is not None and plan.key in cache:
         obs_metrics.counter("batcher.priority_cache.hits").inc()
@@ -136,14 +154,30 @@ def _member_priorities(
     return entry
 
 
+def stack_keys(keys, n_members: int) -> jax.Array:
+    """The members' keys as one (n_members,) key array, padded with the
+    first key: a stacked key array passes through without a dispatch per
+    member."""
+    if not isinstance(keys, jax.Array):
+        keys = jnp.stack(list(keys))
+    if keys.shape[0] < n_members:
+        keys = jnp.concatenate(
+            [keys, jnp.broadcast_to(keys[:1], (n_members - keys.shape[0],))])
+    return keys
+
+
+_packed_jit = {name: jax.jit(fn) for name, fn in PACKED.items()}
+
+
 @dataclasses.dataclass(frozen=True)
 class PackedBatch:
-    """A block-diagonal batch, ready for one `tc_mis` dispatch."""
+    """The key-independent part of a block-diagonal batch, ready for any
+    number of `tc_mis` dispatches under fresh keys."""
     g: Graph                    # block-diagonal graph, n_nodes = n_blocks*T
     tiled: BlockTiledGraph
-    priorities: Priorities      # (n_nodes,), _NEG in padding slots
     alive0: jnp.ndarray         # (n_nodes,) bool, False in padding slots
     col_gate: jnp.ndarray       # (n_blocks,) int32 real-vertex occupancy
+    slots: MemberSlots          # per-slot member, local id and degree
     offsets: Tuple[int, ...]    # member vertex offsets (multiples of T)
     sizes: Tuple[int, ...]      # member real vertex counts
     bucket: Bucket
@@ -154,6 +188,25 @@ class PackedBatch:
     def n_graphs(self) -> int:
         return len(self.sizes)
 
+    @property
+    def device_bytes(self) -> int:
+        """Bytes of the device arrays the structure holds."""
+        return sum(
+            int(x.size) * x.dtype.itemsize for x in jax.tree_util.tree_leaves(
+                (self.g, self.tiled, self.alive0, self.col_gate, self.slots))
+        )
+
+    @property
+    def edge_fill(self) -> float:
+        """Real over padded half-edges: the share of the edge stream that
+        is work."""
+        return self.n_real_edges / self.bucket.e_pad
+
+    @property
+    def tile_fill(self) -> float:
+        """Real over declared (padded) tiles."""
+        return self.n_real_tiles / self.bucket.n_tiles_pad
+
     def signature(self) -> str:
         """Shape-class id: batches with equal signatures reuse one compile.
 
@@ -163,7 +216,6 @@ class PackedBatch:
         differing only there must not claim one compiled program.  The
         storage stays the terminal component (callers key on it)."""
         b = self.bucket
-        resolve = "r" if self.priorities.resolve is not None else "-"
         part = self.tiled.partition
         hy = "" if part is None else (
             f".h{part.threshold}:{int(part.dense.tiles.shape[0])}"
@@ -171,7 +223,38 @@ class PackedBatch:
         )
         return (
             f"T{b.tile_size}.b{b.n_blocks}.t{b.n_tiles_pad}.e{b.e_pad}"
-            f".{resolve}{hy}.{b.storage}"
+            f".m{b.n_members}{hy}.{b.storage}"
+        )
+
+    def priorities(
+        self,
+        plans: Sequence[TilePlan],
+        keys: Sequence[jax.Array],
+        heuristic: str,
+        *,
+        priority_cache: Optional[PriorityCache] = None,
+    ) -> Priorities:
+        """Every member's priorities under its key, at its offset, `_NEG`
+        in padding slots.  H3 (`core.heuristics.PACKED`) builds them on the
+        device from the stacked keys, as the packed program does; the
+        others member by member on the host, through `priority_cache`."""
+        if len(keys) != self.n_graphs:
+            raise ValueError(f"{self.n_graphs} members but {len(keys)} keys")
+        if heuristic in _packed_jit:
+            return _packed_jit[heuristic](
+                stack_keys(keys, self.bucket.n_members), self.slots)
+        pris = [_member_priorities(p, k, heuristic, priority_cache)
+                for p, k in zip(plans, keys)]
+        neg = int(_NEG)
+        sel = np.full(self.g.n_nodes, neg, dtype=np.int32)
+        res = None if pris[0][1] is None else np.full_like(sel, neg)
+        for off, (s, r) in zip(self.offsets, pris):
+            sel[off : off + s.shape[0]] = s
+            if res is not None:
+                res[off : off + r.shape[0]] = r
+        return Priorities(
+            select=jnp.asarray(sel),
+            resolve=None if res is None else jnp.asarray(res),
         )
 
     def unpack(self, x) -> List[np.ndarray]:
@@ -182,17 +265,13 @@ class PackedBatch:
 
 def pack_batch(
     plans: Sequence[TilePlan],
-    keys: Sequence[jax.Array],
-    heuristic: str,
     *,
     bucket: Optional[Bucket] = None,
-    priority_cache: Optional[PriorityCache] = None,
 ) -> PackedBatch:
-    """Concatenate cached per-graph plans into one block-diagonal batch."""
+    """Concatenate cached per-graph plans into one block-diagonal batch
+    structure (no priorities: `PackedBatch.priorities`)."""
     if not plans:
         raise ValueError("pack_batch needs at least one plan")
-    if len(keys) != len(plans):
-        raise ValueError(f"{len(plans)} plans but {len(keys)} keys")
     T = plans[0].tiled.tile_size
     if any(p.tiled.tile_size != T for p in plans):
         raise ValueError("all plans in a batch must share tile_size")
@@ -204,25 +283,18 @@ def pack_batch(
     need = bucket_for(plans, T)
     if (need.n_blocks > bucket.n_blocks or need.n_tiles_pad > bucket.n_tiles_pad
             or need.e_pad > bucket.e_pad or bucket.tile_size != T
-            or bucket.storage != storage):
+            or bucket.storage != storage
+            or need.n_members > bucket.n_members):
         raise ValueError(f"batch needs {need}, bucket {bucket} too small")
 
     n_total = bucket.n_blocks * T
-    neg = int(_NEG)
-
-    # per-member priorities: each member's OWN key and degree statistics
-    pris = [
-        _member_priorities(p, key, heuristic, priority_cache)
-        for p, key in zip(plans, keys)
-    ]
-    has_resolve = pris[0][1] is not None
 
     offsets: List[int] = []
     sizes: List[int] = []
-    sel = np.full(n_total, neg, dtype=np.int32)
-    res = np.full(n_total, neg, dtype=np.int32) if has_resolve else None
     alive0 = np.zeros(n_total, dtype=bool)
     col_gate = np.zeros(bucket.n_blocks, dtype=np.int32)
+    member = np.full(n_total, -1, dtype=np.int32)
+    local = np.zeros(n_total, dtype=np.int32)
 
     # Hybrid routing survives batching only when it is coherent across the
     # whole pack: every member partitioned, all at one threshold.  Then the
@@ -243,16 +315,15 @@ def pack_batch(
     sp_parts: List[Tuple[np.ndarray, np.ndarray]] = []
 
     boff = 0
-    for plan, (sel_np, res_np) in zip(plans, pris):
+    for j, plan in enumerate(plans):
         g, t = plan.g, plan.tiled
         voff = boff * T
         offsets.append(voff)
         sizes.append(g.n_nodes)
 
-        sel[voff : voff + g.n_nodes] = sel_np
-        if has_resolve:
-            res[voff : voff + g.n_nodes] = res_np
         alive0[voff : voff + g.n_nodes] = True
+        member[voff : voff + g.n_nodes] = j
+        local[voff : voff + g.n_nodes] = np.arange(g.n_nodes, dtype=np.int32)
         col_gate[boff : boff + plan.n_blocks] = 1
 
         src_parts.append(np.asarray(g.senders)[: g.n_edges].astype(np.int64) + voff)
@@ -277,6 +348,9 @@ def pack_batch(
     s = np.concatenate(src_parts) if src_parts else np.zeros(0, np.int64)
     r = np.concatenate(dst_parts) if dst_parts else np.zeros(0, np.int64)
     n_real_edges = int(s.shape[0])
+    # each slot's degree in its member graph (`Graph.degrees`): the
+    # block-diagonal packing keeps every member's half-edges, offset
+    deg = np.bincount(r, minlength=n_total).astype(np.int32)
     pad = np.full(bucket.e_pad - n_real_edges, n_total, dtype=np.int64)
     batch_g = Graph(
         senders=jnp.asarray(np.concatenate([s, pad]).astype(np.int32)),
@@ -350,16 +424,17 @@ def pack_batch(
         )
         n_real_tiles += n_sparse
 
-    priorities = Priorities(
-        select=jnp.asarray(sel),
-        resolve=jnp.asarray(res) if has_resolve else None,
-    )
+    member_sizes = np.zeros(bucket.n_members, dtype=np.int32)
+    member_sizes[: len(sizes)] = sizes
     return PackedBatch(
         g=batch_g,
         tiled=batch_tiled,
-        priorities=priorities,
         alive0=jnp.asarray(alive0),
         col_gate=jnp.asarray(col_gate),
+        slots=MemberSlots(
+            member=jnp.asarray(member), local=jnp.asarray(local),
+            deg=jnp.asarray(deg), sizes=jnp.asarray(member_sizes),
+        ),
         offsets=tuple(offsets),
         sizes=tuple(sizes),
         bucket=bucket,

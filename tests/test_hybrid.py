@@ -318,15 +318,13 @@ def test_batched_hybrid_bit_identical_and_signed():
     s = Solver(options=SolveOptions(engine="tiled_ref", tile_size=32,
                                     hybrid="forced", hybrid_threshold=8))
     plans = [s.plan(g) for g in graphs]
-    keys = [jax.random.key(0)] * len(plans)
-    pb = pack_batch(plans, keys, heuristic=s.options.heuristic)
+    pb = pack_batch(plans)
     assert pb.tiled.partition is not None
     assert ".h8:" in pb.signature()
 
     s_off = Solver(options=SolveOptions(engine="tiled_ref", tile_size=32,
                                         hybrid="off"))
-    pb_off = pack_batch([s_off.plan(g) for g in graphs], keys,
-                        heuristic=s.options.heuristic)
+    pb_off = pack_batch([s_off.plan(g) for g in graphs])
     assert pb_off.tiled.partition is None
     assert ".h" not in pb_off.signature()
     assert pb.signature() != pb_off.signature()
@@ -339,8 +337,7 @@ def test_batched_mixed_modes_falls_back_dense():
     s_o = Solver(options=SolveOptions(engine="tiled_ref", tile_size=32,
                                       hybrid="off"))
     plans = [s_h.plan(graphs[0]), s_o.plan(graphs[1])]
-    pb = pack_batch(plans, [jax.random.key(0)] * 2,
-                    heuristic=s_h.options.heuristic)
+    pb = pack_batch(plans)
     assert pb.tiled.partition is None       # incoherent pack -> dense-only
 
 

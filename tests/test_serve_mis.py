@@ -218,11 +218,12 @@ def _solo_vs_packed(graphs, backend, tile_size, heuristic="h3"):
     plans = [cache.plan(g)[0] for g in graphs]
     base = jax.random.key(7)
     keys = [request_key(base, p) for p in plans]
-    batch = pack_batch(plans, keys, heuristic)
+    batch = pack_batch(plans)
     cfg = TCMISConfig(heuristic=heuristic, backend=backend)
     res = tc_mis(
         batch.g, batch.tiled, base, cfg,
-        priorities=batch.priorities, alive0=batch.alive0, col_gate=batch.col_gate,
+        priorities=batch.priorities(plans, keys, heuristic),
+        alive0=batch.alive0, col_gate=batch.col_gate,
     )
     assert bool(res.converged)
     slices = batch.unpack(res.in_mis)
@@ -271,9 +272,8 @@ def test_bucket_rounding_is_stable_across_similar_batches():
     a = [cache.plan(g)[0] for g in _hetero_graphs(8, seed=0)]
     b = [cache.plan(g)[0] for g in _hetero_graphs(8, seed=3)]
     assert bucket_for(a, 16) == bucket_for(b, 16)
-    base = jax.random.key(0)
-    pa = pack_batch(a, [request_key(base, p) for p in a], "h3")
-    pb = pack_batch(b, [request_key(base, p) for p in b], "h3")
+    pa = pack_batch(a)
+    pb = pack_batch(b)
     assert pa.n_real_edges != pb.n_real_edges  # genuinely different content
     assert (pa.g.n_nodes, pa.g.n_edges, pa.g.e_pad) == (
         pb.g.n_nodes, pb.g.n_edges, pb.g.e_pad)
@@ -287,7 +287,7 @@ def test_packed_batch_rejects_mixed_tile_sizes():
     p8 = PlanCache(tile_size=8).plan(g)[0]
     p16 = PlanCache(tile_size=16).plan(g)[0]
     with pytest.raises(ValueError, match="tile_size"):
-        pack_batch([p8, p16], [jax.random.key(0)] * 2, "h3")
+        pack_batch([p8, p16])
 
 
 @settings(max_examples=5, deadline=None)
